@@ -234,7 +234,7 @@ def _measured_lines() -> List[str]:
         o = paged_decode_attention(
             q.reshape(B * K1, H, hd), k_pages, v_pages,
             jnp.repeat(tables, K1, axis=0), lengths.reshape(-1), impl="interpret",
-        ).reshape(B, K1, F).astype(jnp.float32)
+        ).reshape(B, K1, H, hd).astype(jnp.float32)
         logits = fused_target_logits(o, w, block_v=256, v_true=V)
         return spec_verify(logits, toks, nd, impl="interpret", block_v=256)
 

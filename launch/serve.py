@@ -49,28 +49,42 @@ terminal fleet dashboard polls it::
     PYTHONPATH=src python launch/serve.py --dashboard 127.0.0.1:9100
     python -m repro.obs.dashboard 127.0.0.1:9100        # equivalent
 
-``--backend spec --shards N`` swaps in the real fused NAV verifier with
-its target forward sharded across an N-device mesh
-(``ShardedSpecVerifyBackend``): paged KV pages partitioned on the head
-axis, one ``shard_map`` launch per dispatch.  On a CPU-only host the
-process forces ``--xla_force_host_platform_device_count=N`` so the mesh
-exists; the wire protocol and every client stay oblivious to N::
+``--backend spec`` swaps in the real fused NAV verifier over a paged KV
+pool, with a seeded synthetic target at the widths of ``--arch`` (default
+``granite-3-2b``: 32 query heads, 8 KV heads, head_dim 64, vocab 49155, a
+40-layer pool of 16-token pages).  ``--impl`` names the kernel and is never
+changed behind your back: ``pallas`` (default) is the compiled TPU kernel,
+``interpret`` runs it under the CPU interpreter, ``ref`` is the pure-JAX
+oracle.  ``--shards N`` shards the target forward across an N-device mesh
+(``ShardedSpecVerifyBackend``: head-parallel pages, one ``shard_map``
+launch per dispatch, XLA code rather than Pallas, so it takes ``--impl
+ref``).  On a CPU host, export
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` first so the mesh
+has N devices; the wire protocol and every client stay oblivious to N::
 
     PYTHONPATH=src python launch/serve.py --listen 127.0.0.1:7421 \\
-        --backend spec --shards 4 --sessions 1
+        --backend spec --sessions 1                      # one TPU chip
+    XLA_FLAGS=--xla_force_host_platform_device_count=4 \\
+    PYTHONPATH=src python launch/serve.py --listen 127.0.0.1:7421 \\
+        --backend spec --impl ref --shards 4 --sessions 1
+
+JAX's persistent compile cache goes where ``JAX_COMPILATION_CACHE_DIR``
+points, or else to ``.jax_cache/`` in the checkout
+(``repro.launch.compile_cache``).
 """
 
 from __future__ import annotations
 
 import argparse
-import os
+import math
 import sys
 from pathlib import Path
 from typing import Tuple
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 
-from repro.runtime import (  # noqa: E402 (path bootstrap above)
+from repro.launch.compile_cache import place_compile_cache  # noqa: E402 (path bootstrap above)
+from repro.runtime import (  # noqa: E402
     SYSTEM_CLOCK,
     ChannelConfig,
     CloudVerifier,
@@ -88,6 +102,12 @@ from repro.runtime import (  # noqa: E402 (path bootstrap above)
     SyntheticDraft,
     connect_transport,
 )
+from repro.runtime.server import IMPLS  # noqa: E402
+
+
+# Spec backend pool: 512 pages of 16 tokens hold 8k tokens — a few sessions
+# of 1-2k context; at granite-3-2b widths that is 1.3 GB of fp32 KV.
+KV_BLOCKS, PAGE_SIZE = 512, 16
 
 
 def _host_port(spec: str) -> Tuple[str, int]:
@@ -161,37 +181,42 @@ def _make_backend(args):
 
 
 def _spec_backend(args):
-    """The real fused NAV verifier, sharded over ``--shards`` devices.
+    """The real fused NAV verifier at the widths of ``--arch``.
 
-    A tensor-mode paged KV pool (partitioned per shard on the head axis) and
-    a seeded deterministic target (queries + LM head) drive
-    ``ShardedSpecVerifyBackend`` — one sharded ``shard_map`` launch per
-    dispatch, with the dispatcher (and the wire protocol) oblivious to the
-    shard count.  ``--shards 1`` degenerates to a single-device mesh and is
-    bit-identical to the unsharded ``SpecVerifyBackend``.
+    A tensor-mode paged KV pool (``n_layers`` deep, ``KV_BLOCKS`` pages of
+    ``PAGE_SIZE`` tokens) and a seeded deterministic target
+    (per-position queries + LM head ``[n_heads * head_dim, vocab]``) drive
+    ``SpecVerifyBackend(fused=True, impl=--impl)`` — one fused Pallas
+    launch per dispatch — or, with ``--shards N > 1``,
+    ``ShardedSpecVerifyBackend`` over N devices.  The dispatcher and the
+    wire protocol are oblivious to both choices.
     """
     import jax
     import numpy as np
 
+    from repro.configs import get_config
     from repro.models.paged_kv import PagedKVPool
-    from repro.runtime import ShardedSpecVerifyBackend
+    from repro.runtime import ShardedSpecVerifyBackend, SpecVerifyBackend
 
-    H, hd, bs, V = 2, 8, 4, 256
+    cfg = get_config(args.arch)
+    H, Hkv, hd, V = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.vocab_size
     pool = PagedKVPool(
-        num_blocks=256, block_size=bs, n_layers=1, n_kv_heads=H, head_dim=hd,
-        quantize="int8" if args.kv_quant == "int8" else None,
+        num_blocks=KV_BLOCKS, block_size=PAGE_SIZE, n_layers=cfg.n_layers,
+        n_kv_heads=Hkv, head_dim=hd, quantize="int8" if args.kv_quant == "int8" else None,
     )
     key = jax.random.PRNGKey(args.seed)
-    w = np.asarray(jax.random.normal(jax.random.fold_in(key, 77), (H * hd, V)) * 4, np.float32)
+    # Scaled by 1/sqrt(fan-in) so the logit spread does not grow with width.
+    w = jax.random.normal(jax.random.fold_in(key, 77), (H * hd, V)) * (16.0 / math.sqrt(H * hd))
 
     def query_fn(session, tokens):
         k = jax.random.fold_in(jax.random.fold_in(key, 88), session * 131 + len(tokens))
         return np.asarray(jax.random.normal(k, (len(tokens) + 1, H, hd)), np.float32)
 
-    backend = ShardedSpecVerifyBackend(
-        shards=args.shards, kv_pool=pool, query_fn=query_fn, lm_head=w,
-        impl="ref", block_v=256,
-    )
+    kw = dict(kv_pool=pool, query_fn=query_fn, lm_head=w, impl=args.impl)
+    if args.shards > 1:
+        backend = ShardedSpecVerifyBackend(shards=args.shards, **kw)
+    else:
+        backend = SpecVerifyBackend(fused=True, **kw)
     return backend, {"kv_pool": pool}
 
 
@@ -247,11 +272,15 @@ def run_router(args) -> int:
     return 0
 
 
-def run_client(args) -> int:
-    """Edge role: dial the cloud, stream ``--tokens`` tokens, print them."""
+def stream_session(args, session: int):
+    """Dial ``args.connect`` as ``session``, stream ``--tokens`` tokens, detach.
+
+    Returns ``(session id granted by the server, committed stream, client
+    stats)``; the edge role and ``chip_smoke.py`` both stream through it.
+    """
     host, port = args.connect
     transport = connect_transport(
-        host, port, session=args.session, cfg=ChannelConfig(alpha=0.001, beta=0.0001)
+        host, port, session=session, cfg=ChannelConfig(alpha=0.001, beta=0.0001)
     )
     if args.draft == "oracle":
         draft = OracleDraft(seed=args.seed)
@@ -263,11 +292,16 @@ def run_client(args) -> int:
     client.seq += 1
     transport.send(Detach(session=transport.session, seq=client.seq))
     transport.close()
-    stream = client.tokens[: args.tokens]
+    return transport.session, client.tokens[: args.tokens], stats
+
+
+def run_client(args) -> int:
+    """Edge role: dial the cloud, stream ``--tokens`` tokens, print them."""
+    session, stream, stats = stream_session(args, args.session)
     for tok in stream:
         print(tok)
     print(
-        f"# session={transport.session} rounds={stats['rounds']}"
+        f"# session={session} rounds={stats['rounds']}"
         f" accepted={stats['accepted_tokens']} failovers={stats['failovers']}"
         f" wall={stats['wall_time']:.2f}s",
         file=sys.stderr,
@@ -298,8 +332,8 @@ def run_demo(args) -> int:
         verifier.stop()
 
 
-def main(argv=None) -> int:
-    """CLI entry: ``--listen`` (cloud), ``--connect`` (edge), or helpers."""
+def build_parser() -> argparse.ArgumentParser:
+    """The launcher's command line (shared with ``chip_smoke.py``)."""
     p = argparse.ArgumentParser(description=__doc__, formatter_class=argparse.RawDescriptionHelpFormatter)
     role = p.add_mutually_exclusive_group(required=True)
     role.add_argument("--listen", type=_host_port, metavar="HOST:PORT", help="run the cloud verifier")
@@ -316,8 +350,16 @@ def main(argv=None) -> int:
     p.add_argument("--seed", type=int, default=7, help="oracle/synthetic seed (must match across roles)")
     p.add_argument("--backend", choices=("oracle", "synthetic", "spec"), default="oracle")
     p.add_argument(
+        "--arch", default="granite-3-2b",
+        help="spec backend: model config whose widths the synthetic target takes",
+    )
+    p.add_argument(
+        "--impl", choices=IMPLS, default="pallas",
+        help="spec backend: compiled TPU kernel, CPU interpreter, or pure-JAX oracle",
+    )
+    p.add_argument(
         "--shards", type=int, default=1,
-        help="spec backend: shard the target verify over N mesh devices",
+        help="spec backend: shard the target verify over N mesh devices (needs --impl ref)",
     )
     p.add_argument(
         "--kv-quant", choices=("none", "int8"), default="none",
@@ -360,16 +402,13 @@ def main(argv=None) -> int:
     p.add_argument("--nav-timeout", type=float, default=5.0, help="edge NAV timeout before failover [s]")
     p.add_argument("--batch-window", type=float, default=0.002, help="server NAV coalescing window [s]")
     p.add_argument("--verify-time", type=float, default=0.002, help="simulated target forward time [s]")
-    args = p.parse_args(argv)
-    if args.backend == "spec" and args.shards > 1:
-        # The host mesh needs N visible devices BEFORE jax initializes its
-        # backends (first jax.devices() call) — force the CPU device count
-        # here so `--shards N` works on a plain CPU host.
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                f"{flags} --xla_force_host_platform_device_count={args.shards}".strip()
-            )
+    return p
+
+
+def main(argv=None) -> int:
+    """CLI entry: ``--listen`` (cloud), ``--connect`` (edge), or helpers."""
+    args = build_parser().parse_args(argv)
+    place_compile_cache()
     if args.print_oracle is not None:
         for tok in OracleStream(args.seed).prefix(args.print_oracle):
             print(tok)

@@ -8,7 +8,9 @@ VMEM; per-lane valid lengths mask dead slots, and a sliding window bounds the
 live region for local-attention layers.
 
 Working set per step: H·hd (q) + 2·BK·H·hd (k,v) + H·BK (scores) floats —
-BK=512, H≤64, hd≤256 stays well under VMEM.
+BK=512, H≤64, hd≤256 stays well under VMEM.  The step itself is
+``tiles.attend_page``, which the fused verify kernel shares; lengths (and
+the paged entries' block tables) are scalar-prefetched.
 
 **Paged variant** (``paged_decode_attention_pallas``): the KV cache lives in
 a global page pool (``models/paged_kv.py``) instead of one contiguous buffer
@@ -42,57 +44,101 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
+from ..tiles import NEG_INF, attend_page
 
 DEFAULT_BK = 512
-NEG_INF = -1e30
 
 
-def _decode_kernel(
-    len_ref,  # [1, 1] i32 — valid KV length for this lane
-    q_ref,  # [1, H, hd]
-    k_ref,  # [1, BK, H, hd]
-    v_ref,  # [1, BK, H, hd]
-    o_ref,  # [1, H, hd]
-    m_scr,  # [H] f32
-    l_scr,  # [H] f32
-    acc_scr,  # [H, hd] f32
-    *,
+def _attend_kernel(
+    len_ref,  # [B] i32 scalar-prefetch — valid KV length per lane
+    *rest,  # [paged: bt [B, G] prefetch] q, k, v, [quant: ks/kz/vs/vz], o, m/l/acc
     sm_scale: float,
     window: int,
     bk: int,
     nk: int,
+    paged: bool,
+    quantized: bool,
 ):
-    kb = pl.program_id(1)
+    """Flash-decode over kv blocks; the flat and both paged entries share it.
 
-    @pl.when(kb == 0)
+    ``q [1, H, hd]``; ``k, v [1, bk, H, hd]`` — kv block j (flat) or physical
+    page ``bt[b, j]`` (paged, the table rides the index map).  In-VMEM
+    affine dequant for int8 pages: ``x_hat = (q + 128) * scale + zero``,
+    params broadcast over head_dim — ``PagedKVPool.dequantize_kv``.
+    """
+    q_ref, k_ref, v_ref, *rest = rest[1:] if paged else rest
+    if quantized:
+        ks_ref, kz_ref, vs_ref, vz_ref = rest[:4]
+        rest = rest[4:]
+    o_ref, m_scr, l_scr, acc_scr = rest
+    b, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32)  # [H, hd]
-    k = k_ref[0].astype(jnp.float32)  # [BK, H, hd]
-    v = v_ref[0].astype(jnp.float32)
-    s = jnp.einsum("hd,khd->hk", q, k) * sm_scale  # [H, BK]
-    length = len_ref[0, 0]
-    k_pos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)  # [1, BK]
-    valid = k_pos < length
-    valid = jnp.logical_and(valid, k_pos >= length - window)
-    s = jnp.where(valid, s, NEG_INF)
+    if quantized:
+        k = (k_ref[0].astype(jnp.float32) + 128.0) * ks_ref[0][..., None] + kz_ref[0][..., None]
+        v = (v_ref[0].astype(jnp.float32) + 128.0) * vs_ref[0][..., None] + vz_ref[0][..., None]
+    else:
+        k = k_ref[0].astype(jnp.float32)  # [bk, H, hd]
+        v = v_ref[0].astype(jnp.float32)
+    # Logical positions: block j covers [j*bk, (j+1)*bk) regardless of which
+    # physical page backs it — the table indirection is purely in the DMA.
+    k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1, 1), 0)
+    m_scr[...], l_scr[...], acc_scr[...] = attend_page(
+        q_ref[0].astype(jnp.float32), k, v, k_pos, len_ref[b],
+        m_scr[...], l_scr[...], acc_scr[...], sm_scale=sm_scale, window=window,
+    )
 
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
-    alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jnp.einsum("hk,khd->hd", p, v)
-    m_scr[...] = m_new
-
-    @pl.when(kb == nk - 1)
+    @pl.when(j == nk - 1)
     def _finalize():
-        denom = jnp.maximum(l_scr[...], 1e-30)[:, None]
+        denom = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+
+
+def _launch(q, kv, quant, lengths, block_tables, *, bk, nk, window, interpret):
+    """One ``(B, nk)`` flash-decode launch; paged when ``block_tables`` is given."""
+    B, H, hd = q.shape
+    kernel = functools.partial(
+        _attend_kernel, sm_scale=1.0 / math.sqrt(hd), window=int(window), bk=bk, nk=nk,
+        paged=block_tables is not None, quantized=quant is not None,
+    )
+    if block_tables is None:
+        prefetch = (lengths.astype(jnp.int32),)
+        kv_ix = lambda b, j, ln: (b, j, 0, 0)  # noqa: E731
+        param_ix = None
+        q_ix = lambda b, j, ln: (b, 0, 0)  # noqa: E731
+    else:
+        prefetch = (lengths.astype(jnp.int32), block_tables.astype(jnp.int32))
+        kv_ix = lambda b, j, ln, bt: (bt[b, j], 0, 0, 0)  # noqa: E731
+        param_ix = lambda b, j, ln, bt: (bt[b, j], 0, 0)  # noqa: E731
+        q_ix = lambda b, j, ln, bt: (b, 0, 0)  # noqa: E731
+    in_specs = [pl.BlockSpec((1, H, hd), q_ix)] + [pl.BlockSpec((1, bk, H, hd), kv_ix)] * 2
+    operands = [q, *kv]
+    if quant is not None:
+        in_specs += [pl.BlockSpec((1, bk, H), param_ix)] * 4
+        operands += [p.astype(jnp.float32) for p in quant]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(B, nk),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, H, hd), q_ix),
+        scratch_shapes=[
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, hd), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(*prefetch, *operands)
 
 
 def decode_attention_pallas(
@@ -109,78 +155,15 @@ def decode_attention_pallas(
     bk = min(block_k, S)
     if S % bk:
         raise ValueError(f"S={S} must be divisible by block_k={bk}")
-    nk = S // bk
-    sm_scale = 1.0 / math.sqrt(hd)
-    kernel = functools.partial(_decode_kernel, sm_scale=sm_scale, window=int(window), bk=bk, nk=nk)
-    return pl.pallas_call(
-        kernel,
-        grid=(B, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, H, hd), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, bk, H, hd), lambda b, j: (b, j, 0, 0)),
-            pl.BlockSpec((1, bk, H, hd), lambda b, j: (b, j, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, H, hd), lambda b, j: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H, hd), jnp.float32),
-        ],
-        compiler_params=CompilerParams(dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(lengths.reshape(B, 1).astype(jnp.int32), q, k_cache, v_cache)
+    return _launch(
+        q, (k_cache, v_cache), None, lengths, None,
+        bk=bk, nk=S // bk, window=window, interpret=interpret,
+    )
 
 
-def _paged_decode_kernel(
-    bt_ref,  # [B, G] i32 scalar-prefetch — physical page id per logical page
-    len_ref,  # [B] i32 scalar-prefetch — valid KV length per lane
-    q_ref,  # [1, H, hd]
-    k_ref,  # [1, bs, H, hd] — physical page bt[b, g]
-    v_ref,  # [1, bs, H, hd]
-    o_ref,  # [1, H, hd]
-    m_scr,  # [H] f32
-    l_scr,  # [H] f32
-    acc_scr,  # [H, hd] f32
-    *,
-    sm_scale: float,
-    window: int,
-    bs: int,
-    ng: int,
-):
-    b, g = pl.program_id(0), pl.program_id(1)
-
-    @pl.when(g == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[0].astype(jnp.float32)  # [H, hd]
-    k = k_ref[0].astype(jnp.float32)  # [bs, H, hd]
-    v = v_ref[0].astype(jnp.float32)
-    s = jnp.einsum("hd,khd->hk", q, k) * sm_scale  # [H, bs]
-    length = len_ref[b]
-    # Logical positions: page g covers [g*bs, (g+1)*bs) regardless of which
-    # physical page backs it — the table indirection is purely in the DMA.
-    k_pos = g * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    valid = k_pos < length
-    valid = jnp.logical_and(valid, k_pos >= length - window)
-    s = jnp.where(valid, s, NEG_INF)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
-    alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jnp.einsum("hk,khd->hd", p, v)
-    m_scr[...] = m_new
-
-    @pl.when(g == ng - 1)
-    def _finalize():
-        denom = jnp.maximum(l_scr[...], 1e-30)[:, None]
-        o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+def _check_pages(q, k_pages):
+    if k_pages.shape[2] != q.shape[1]:
+        raise ValueError(f"pages must be GQA-expanded: {k_pages.shape[2]} heads vs {q.shape[1]} queries")
 
 
 def paged_decode_attention_pallas(
@@ -199,91 +182,11 @@ def paged_decode_attention_pallas(
     ``block_tables[b, g]`` — the BlockSpec index map reads the prefetched
     table, so the DMA engine chases the indirection, not the kernel body.
     """
-    B, H, hd = q.shape
-    P, bs, Hk, _ = k_pages.shape
-    if Hk != H:
-        raise ValueError(f"pages must be GQA-expanded: {Hk} heads vs {H} queries")
-    G = block_tables.shape[1]
-    sm_scale = 1.0 / math.sqrt(hd)
-    kernel = functools.partial(
-        _paged_decode_kernel, sm_scale=sm_scale, window=int(window), bs=bs, ng=G
+    _check_pages(q, k_pages)
+    return _launch(
+        q, (k_pages, v_pages), None, lengths, block_tables,
+        bk=k_pages.shape[1], nk=block_tables.shape[1], window=window, interpret=interpret,
     )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block_tables, lengths
-        grid=(B, G),
-        in_specs=[
-            pl.BlockSpec((1, H, hd), lambda b, g, bt, ln: (b, 0, 0)),
-            pl.BlockSpec((1, bs, H, hd), lambda b, g, bt, ln: (bt[b, g], 0, 0, 0)),
-            pl.BlockSpec((1, bs, H, hd), lambda b, g, bt, ln: (bt[b, g], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, H, hd), lambda b, g, bt, ln: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H, hd), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
-        compiler_params=CompilerParams(dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), q, k_pages, v_pages)
-
-
-def _paged_decode_q8_kernel(
-    bt_ref,  # [B, G] i32 scalar-prefetch
-    len_ref,  # [B] i32 scalar-prefetch
-    q_ref,  # [1, H, hd]
-    k_ref,  # [1, bs, H, hd] int8 — physical page bt[b, g]
-    v_ref,  # [1, bs, H, hd] int8
-    ks_ref,  # [1, bs, H] f32 scale
-    kz_ref,  # [1, bs, H] f32 zero
-    vs_ref,  # [1, bs, H] f32
-    vz_ref,  # [1, bs, H] f32
-    o_ref,  # [1, H, hd]
-    m_scr,  # [H] f32
-    l_scr,  # [H] f32
-    acc_scr,  # [H, hd] f32
-    *,
-    sm_scale: float,
-    window: int,
-    bs: int,
-    ng: int,
-):
-    b, g = pl.program_id(0), pl.program_id(1)
-
-    @pl.when(g == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[0].astype(jnp.float32)  # [H, hd]
-    # In-VMEM affine dequant: x_hat = (int8 + 128) * scale + zero, params
-    # broadcast over head_dim.  Matches PagedKVPool.dequantize_kv exactly.
-    k = (k_ref[0].astype(jnp.float32) + 128.0) * ks_ref[0][..., None] + kz_ref[0][..., None]
-    v = (v_ref[0].astype(jnp.float32) + 128.0) * vs_ref[0][..., None] + vz_ref[0][..., None]
-    s = jnp.einsum("hd,khd->hk", q, k) * sm_scale  # [H, bs]
-    length = len_ref[b]
-    k_pos = g * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    valid = k_pos < length
-    valid = jnp.logical_and(valid, k_pos >= length - window)
-    s = jnp.where(valid, s, NEG_INF)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
-    alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jnp.einsum("hk,khd->hd", p, v)
-    m_scr[...] = m_new
-
-    @pl.when(g == ng - 1)
-    def _finalize():
-        denom = jnp.maximum(l_scr[...], 1e-30)[:, None]
-        o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def paged_decode_attention_q8_pallas(
@@ -306,52 +209,10 @@ def paged_decode_attention_q8_pallas(
     four quant-param planes ride the identical ``bt[b, g]`` index map so a
     page's payload and parameters always arrive together.
     """
-    B, H, hd = q.shape
-    P, bs, Hk, _ = k_pages.shape
-    if Hk != H:
-        raise ValueError(f"pages must be GQA-expanded: {Hk} heads vs {H} queries")
+    _check_pages(q, k_pages)
     if k_pages.dtype != jnp.int8:
         raise TypeError(f"q8 entry needs int8 pages, got {k_pages.dtype}")
-    G = block_tables.shape[1]
-    sm_scale = 1.0 / math.sqrt(hd)
-    kernel = functools.partial(
-        _paged_decode_q8_kernel, sm_scale=sm_scale, window=int(window), bs=bs, ng=G
-    )
-    page_spec = pl.BlockSpec((1, bs, H, hd), lambda b, g, bt, ln: (bt[b, g], 0, 0, 0))
-    param_spec = pl.BlockSpec((1, bs, H), lambda b, g, bt, ln: (bt[b, g], 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block_tables, lengths
-        grid=(B, G),
-        in_specs=[
-            pl.BlockSpec((1, H, hd), lambda b, g, bt, ln: (b, 0, 0)),
-            page_spec,
-            page_spec,
-            param_spec,
-            param_spec,
-            param_spec,
-            param_spec,
-        ],
-        out_specs=pl.BlockSpec((1, H, hd), lambda b, g, bt, ln: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H, hd), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
-        compiler_params=CompilerParams(dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(
-        block_tables.astype(jnp.int32),
-        lengths.astype(jnp.int32),
-        q,
-        k_pages,
-        v_pages,
-        k_scale.astype(jnp.float32),
-        k_zero.astype(jnp.float32),
-        v_scale.astype(jnp.float32),
-        v_zero.astype(jnp.float32),
+    return _launch(
+        q, (k_pages, v_pages), (k_scale, k_zero, v_scale, v_zero), lengths, block_tables,
+        bk=k_pages.shape[1], nk=block_tables.shape[1], window=window, interpret=interpret,
     )

@@ -41,7 +41,7 @@ def decode_attention(
     lengths: jax.Array,  # [B]
     *,
     window: int = 1 << 30,
-    impl: str = "interpret",
+    impl: str,
     block_k: int = 512,
 ) -> jax.Array:
     """Single-position decode attention over a flat contiguous KV cache."""
@@ -98,7 +98,7 @@ def paged_decode_attention(
     lengths: jax.Array,  # [B]
     *,
     window: int = 1 << 30,
-    impl: str = "interpret",
+    impl: str,
     bucket: bool = True,
     quant=None,  # (k_scale, k_zero, v_scale, v_zero), each [P, bs, Hkv] f32
     pad_page_id: int = 0,

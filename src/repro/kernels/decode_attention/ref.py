@@ -14,6 +14,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..tiles import HIGHEST
+
 
 def decode_attention_ref(
     q: jax.Array,  # [B, H, hd]
@@ -24,13 +26,16 @@ def decode_attention_ref(
     window: int = 1 << 30,
 ) -> jax.Array:
     B, S, H, hd = k_cache.shape
-    s = jnp.einsum("bhd,bkhd->bhk", q.astype(jnp.float32), k_cache.astype(jnp.float32))
+    s = jnp.einsum(
+        "bhd,bkhd->bhk", q.astype(jnp.float32), k_cache.astype(jnp.float32), precision=HIGHEST
+    )
     s = s / math.sqrt(hd)
     k_pos = jnp.arange(S)[None, :]
     valid = jnp.logical_and(k_pos < lengths[:, None], k_pos >= lengths[:, None] - window)
     s = jnp.where(valid[:, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhk,bkhd->bhd", p, v_cache.astype(jnp.float32)).astype(q.dtype)
+    o = jnp.einsum("bhk,bkhd->bhd", p, v_cache.astype(jnp.float32), precision=HIGHEST)
+    return o.astype(q.dtype)
 
 
 def paged_gather(pages: jax.Array, block_tables: jax.Array) -> jax.Array:

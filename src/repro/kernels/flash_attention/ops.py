@@ -27,7 +27,7 @@ def flash_attention(
     window: int = 1 << 30,
     softcap: float = 0.0,
     causal: bool = True,
-    impl: str = "interpret",  # 'pallas' (TPU) | 'interpret' (CPU check) | 'ref'
+    impl: str,  # 'pallas' (TPU) | 'interpret' (CPU check) | 'ref'
     block_q: int = 128,
     block_k: int = 128,
 ) -> jax.Array:

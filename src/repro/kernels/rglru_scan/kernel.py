@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
 
 DEFAULT_BT = 256
 DEFAULT_BD = 512
@@ -80,7 +79,7 @@ def rglru_scan_pallas(
         out_specs=pl.BlockSpec((1, bt, bd), lambda i, d, t: (i, t, d)),
         out_shape=jax.ShapeDtypeStruct((B, T, D), a.dtype),
         scratch_shapes=[pltpu.VMEM((bd,), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -16,7 +16,7 @@ def rglru_scan(
     b: jax.Array,
     h0: jax.Array,  # [B, D]
     *,
-    impl: str = "interpret",
+    impl: str,
     block_t: int = 256,
     block_d: int = 512,
 ) -> jax.Array:

@@ -10,8 +10,11 @@ This kernel fuses all three into ONE pass over the vocabulary:
     final block → n_accepted, correction token, draft-token log-probs.
 
 Grid: (B, num_vocab_blocks), vocab dimension "arbitrary" (sequential) with
-running state in VMEM scratch.  K+1 ≤ 16 positions; vocab blocks of 2048
-keep the [K+1, BV] score tile ≤ 128 KB in VMEM.
+running state in VMEM scratch, one ``[K+1, 1]`` column per quantity.  Draft
+tokens arrive as a ``[B, K+1, 1]`` column and ``n_drafted`` by scalar
+prefetch; results leave lane-packed (``[B, 1, 128]`` int32: n_accepted,
+correction) plus a ``[B, K+1, 1]`` log-prob column — block shapes the TPU
+compiler accepts (see ``docs/kernels.md``, "TPU layout rules").
 
 Padding invariants (relied on by ``ops.spec_verify_batched``, which packs
 ragged multi-session requests into one rectangular launch):
@@ -34,12 +37,13 @@ is ONE kernel launch instead of attention-launch-then-verify-launch.  Grid
 ``(B, G + NV)``: steps ``t < G`` stream physical page ``bt[b, t]`` and
 advance K+1 online-softmax states (one per query position, causal
 per-position lengths), step ``t == G-1`` finalizes attention into a
-``[K1, F]`` VMEM tile, and steps ``t >= G`` stream LM-head tiles
-``W[:, (t-G)*bv : ...]``, form the logits tile in-VMEM (masking padded
-vocab ids to ``NEG_INF``), and run the UNMODIFIED ``_verify_kernel`` update
-on it.  Because every op/shape matches the unfused kernels exactly — same
-``einsum`` tiles, same output-dtype round-trip, same blocked ``jnp.dot``,
-same scan — the fused launch is bit-exact vs the
+``[K1, H, hd]`` VMEM tile, and steps ``t >= G`` stream LM-head tiles
+``W[:, :, (t-G)*bv : ...]`` (the head viewed as ``[H, hd, Vp]``), form the
+logits tile in-VMEM (masking padded vocab ids to ``NEG_INF``), and run the
+same ``_scan_update`` as ``_verify_kernel`` on it.  Because every op/shape
+matches the unfused kernels exactly — the same ``tiles.attend_page`` step,
+same output-dtype round-trip, same ``tiles.lm_head_tile`` products, same
+scan — the fused launch is bit-exact vs the
 ``paged_decode_attention`` → projection → ``spec_verify`` composition
 (``tests/test_spec_verify_fused.py``).  The int8 variant dequantizes pages
 in-VMEM exactly like ``paged_decode_attention_q8_pallas``.
@@ -66,76 +70,137 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
+from ..tiles import HIGHEST, NEG_INF, attend_page, lm_head_tile
 
-DEFAULT_BV = 2048
-NEG_INF = -1e30
+DEFAULT_BV = 512
+_BIG = 2**30
+_SEMANTICS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+
+
+def _scan_init(m_scr, arg_scr, lse_scr, tok_scr):
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    arg_scr[...] = jnp.zeros_like(arg_scr)
+    lse_scr[...] = jnp.zeros_like(lse_scr)
+    tok_scr[...] = jnp.full_like(tok_scr, NEG_INF)
+
+
+def _scan_rows(s, ids, m_scr, arg_scr, lse_scr):
+    """Fold one ``[R, bv]`` logits tile into the running (max, argmax, sum exp).
+
+    Every array is 2-D with rows on sublanes; reductions keep the lane axis.
+    """
+    blk_max = jnp.max(s, axis=-1, keepdims=True)  # [R, 1]
+    blk_arg = jnp.min(jnp.where(s == blk_max, ids, _BIG), axis=-1, keepdims=True)
+    m_prev = m_scr[...]
+    m_new = jnp.maximum(m_prev, blk_max)
+    lse_scr[...] = lse_scr[...] * jnp.exp(m_prev - m_new) + jnp.sum(
+        jnp.exp(s - m_new), axis=-1, keepdims=True
+    )
+    arg_scr[...] = jnp.where(blk_max > m_prev, blk_arg, arg_scr[...])
+    m_scr[...] = m_new
+
+
+def _gather_tokens(s, ids, tok, tok_scr):
+    """Keep each row's logit of token ``tok [R, 1]`` when this tile holds it."""
+    hit = ids == tok  # [R, bv]
+    gathered = jnp.sum(jnp.where(hit, s, 0.0), axis=-1, keepdims=True)
+    found = jnp.max(hit.astype(jnp.int32), axis=-1, keepdims=True) > 0
+    tok_scr[...] = jnp.where(found, gathered, tok_scr[...])
+
+
+def _scan_update(s, ids, tok, m_scr, arg_scr, lse_scr, tok_scr):
+    """The chain scan's per-tile step: running row state + draft-token logits."""
+    _scan_rows(s, ids, m_scr, arg_scr, lse_scr)
+    _gather_tokens(s, ids, tok, tok_scr)
+
+
+def _lanes(*values):
+    """Pack ``(1, 1)`` int32 values into lanes 0.. of a ``(1, 128)`` row."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    out = jnp.zeros((1, 128), jnp.int32)
+    for i, v in enumerate(values):
+        out = jnp.where(lane == i, v, out)
+    return out
+
+
+def _chain_finalize(n_d, tok, m_scr, arg_scr, lse_scr, tok_scr, res_ref, logp_ref, *, k1):
+    """Greedy chain NAV from the scanned state: accept, correct, log-probs.
+
+    ``n_accepted`` is the first position ``< K`` whose greedy token misses
+    its draft (or K); the correction is the greedy token there.  ``res`` gets
+    ``[n_accepted, correction]`` in lanes 0-1, ``logp`` every row (rows past
+    ``n_drafted`` are garbage by design).
+    """
+    K = k1 - 1
+    greedy = arg_scr[...]  # [K1, 1]
+    lse = m_scr[...] + jnp.log(jnp.maximum(lse_scr[...], 1e-30))
+    pos = jax.lax.broadcasted_iota(jnp.int32, (k1, 1), 0)
+    match = (greedy == tok) & (pos < n_d) & (pos < K)
+    n_acc = jnp.min(jnp.where(match, K, pos), axis=0, keepdims=True)  # (1, 1)
+    corr = jnp.sum(jnp.where(pos == n_acc, greedy, 0), axis=0, keepdims=True)
+    res_ref[0] = _lanes(n_acc, corr)
+    logp_ref[0] = tok_scr[...] - lse
+
+
+def _chain_outputs(B, k1):
+    return [
+        jax.ShapeDtypeStruct((B, 1, 128), jnp.int32),  # lanes: n_accepted, correction
+        jax.ShapeDtypeStruct((B, k1, 1), jnp.float32),  # logp per row
+    ]
+
+
+def _chain_result(res, logp, K):
+    """``(n_accepted [B,1], correction [B,1], logp [B,K])`` from the packed outputs."""
+    return res[:, 0, 0:1], res[:, 0, 1:2], logp[:, :K, 0]
+
+
+def _token_column(draft_tokens, k1):
+    """``[B, K] -> [B, K1, 1]`` int32 with -1 (no token) in the bonus row."""
+    B = draft_tokens.shape[0]
+    col = jnp.full((B, k1), -1, jnp.int32).at[:, : k1 - 1].set(draft_tokens.astype(jnp.int32))
+    return col[:, :, None]
 
 
 def _verify_kernel(
+    nd_ref,  # [B] i32 scalar-prefetch — n_drafted
     logits_ref,  # [1, K1, BV] f32/bf16 target logits block
-    tokens_ref,  # [1, K] i32 draft tokens (SMEM)
-    nd_ref,  # [1, 1] i32 n_drafted (SMEM)
-    nacc_ref,  # [1, 1] i32 out
-    corr_ref,  # [1, 1] i32 out
-    logp_ref,  # [1, K] f32 out — log P_target(draft token)
-    m_scr,  # [K1] f32 running max
-    arg_scr,  # [K1] i32 running argmax
-    lse_scr,  # [K1] f32 running sum exp (shifted by m)
-    tok_scr,  # [K1] f32 draft-token logits (position i holds logit of draft i)
+    tok_ref,  # [1, K1, 1] i32 draft token per row (-1 in the bonus row)
+    res_ref,  # [1, 1, 128] i32 out — lanes: n_accepted, correction
+    logp_ref,  # [1, K1, 1] f32 out — log P_target(draft token)
+    m_scr,  # [K1, 1] f32 running max
+    arg_scr,  # [K1, 1] i32 running argmax
+    lse_scr,  # [K1, 1] f32 running sum exp (shifted by m)
+    tok_scr,  # [K1, 1] f32 draft-token logits (row i holds logit of draft i)
     *,
     bv: int,
     nv: int,
     k1: int,
 ):
-    vb = pl.program_id(1)
+    b, vb = pl.program_id(0), pl.program_id(1)
 
     @pl.when(vb == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        arg_scr[...] = jnp.zeros_like(arg_scr)
-        lse_scr[...] = jnp.zeros_like(lse_scr)
-        tok_scr[...] = jnp.full_like(tok_scr, NEG_INF)
+        _scan_init(m_scr, arg_scr, lse_scr, tok_scr)
 
     s = logits_ref[0].astype(jnp.float32)  # [K1, BV]
     ids = vb * bv + jax.lax.broadcasted_iota(jnp.int32, (k1, bv), 1)
-    blk_max = jnp.max(s, axis=-1)  # [K1]
-    blk_arg = jnp.min(jnp.where(s == blk_max[:, None], ids, jnp.int32(2**30)), axis=-1)
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, blk_max)
-    lse_scr[...] = lse_scr[...] * jnp.exp(m_prev - m_new) + jnp.sum(jnp.exp(s - m_new[:, None]), axis=-1)
-    arg_scr[...] = jnp.where(blk_max > m_prev, blk_arg, arg_scr[...])
-    m_scr[...] = m_new
-    # Gather draft-token logits owned by this block: position i's draft token
-    # is tokens[i] and is verified against logits row i (row K is the bonus).
-    K = k1 - 1
-    tok_row = jnp.concatenate(
-        [tokens_ref[0, :].reshape(K), jnp.full((1,), -1, jnp.int32)]
-    )  # [K1]
-    hit = ids == tok_row[:, None]  # [K1, BV]
-    gathered = jnp.sum(jnp.where(hit, s, 0.0), axis=-1)
-    tok_scr[...] = jnp.where(jnp.any(hit, axis=-1), gathered, tok_scr[...])
+    _scan_update(s, ids, tok_ref[0], m_scr, arg_scr, lse_scr, tok_scr)
 
     @pl.when(vb == nv - 1)
     def _finalize():
-        greedy = arg_scr[...]  # [K1]
-        lse = m_scr[...] + jnp.log(jnp.maximum(lse_scr[...], 1e-30))
-        n_d = nd_ref[0, 0]
-        pos = jax.lax.broadcasted_iota(jnp.int32, (k1,), 0)
-        match = jnp.logical_and(greedy == tok_row, pos < n_d)[:K]
-        n_acc = jnp.sum(jnp.cumprod(match.astype(jnp.int32)))
-        nacc_ref[0, 0] = n_acc
-        corr_ref[0, 0] = jnp.sum(jnp.where(pos == jnp.minimum(n_acc, K), greedy, 0))
-        logp_ref[0, :] = (tok_scr[...] - lse)[:K]
+        _chain_finalize(
+            nd_ref[b], tok_ref[0], m_scr, arg_scr, lse_scr, tok_scr, res_ref, logp_ref, k1=k1
+        )
 
 
 def _fused_verify_kernel(
     bt_ref,  # [B, G] i32 scalar-prefetch — physical page id per logical page
     len_ref,  # [B, K1] i32 scalar-prefetch — valid KV length per query position
+    nd_ref,  # [B] i32 scalar-prefetch — n_drafted
     q_ref,  # [1, K1, H, hd] — query per draft position (row K = bonus)
     k_ref,  # [1, bs, H, hd] — physical page bt[b, min(t, G-1)]
     v_ref,  # [1, bs, H, hd]
-    *rest,  # [quant: ks/kz/vs/vz [1, bs, H]] w [F, bv], tokens, nd, outs, scratch
+    *rest,  # [quant: ks/kz/vs/vz [1, bs, H]] w [H, hd, bv], tok, outs, scratch
     sm_scale: float,
     window: int,
     bs: int,
@@ -150,20 +215,18 @@ def _fused_verify_kernel(
         ks_ref, kz_ref, vs_ref, vz_ref = rest[:4]
         rest = rest[4:]
     (
-        w_ref,  # [F, bv] f32 LM-head tile (t - ng)
-        tokens_ref,  # [1, K] i32 (SMEM)
-        nd_ref,  # [1, 1] i32 (SMEM)
-        nacc_ref,  # [1, 1] i32 out
-        corr_ref,  # [1, 1] i32 out
-        logp_ref,  # [1, K] f32 out
-        m_att,  # [K1, H] f32 — attention running max per position
-        l_att,  # [K1, H] f32
+        w_ref,  # [H, hd, bv] f32 LM-head tile (t - ng)
+        tok_ref,  # [1, K1, 1] i32
+        res_ref,  # [1, 1, 128] i32 out
+        logp_ref,  # [1, K1, 1] f32 out
+        m_att,  # [K1, H, 1] f32 — attention running max per position
+        l_att,  # [K1, H, 1] f32
         acc_att,  # [K1, H, hd] f32
-        o_scr,  # [K1, F] f32 — finalized attention outputs (F = H*hd)
-        m_scr,  # [K1] f32 — verify running max
-        arg_scr,  # [K1] i32
-        lse_scr,  # [K1] f32
-        tok_scr,  # [K1] f32
+        o_scr,  # [K1, H, hd] f32 — finalized attention outputs
+        m_scr,  # [K1, 1] f32 — verify running max
+        arg_scr,  # [K1, 1] i32
+        lse_scr,  # [K1, 1] f32
+        tok_scr,  # [K1, 1] f32
     ) = rest
     b, t = pl.program_id(0), pl.program_id(1)
 
@@ -172,15 +235,12 @@ def _fused_verify_kernel(
         m_att[...] = jnp.full_like(m_att, NEG_INF)
         l_att[...] = jnp.zeros_like(l_att)
         acc_att[...] = jnp.zeros_like(acc_att)
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        arg_scr[...] = jnp.zeros_like(arg_scr)
-        lse_scr[...] = jnp.zeros_like(lse_scr)
-        tok_scr[...] = jnp.full_like(tok_scr, NEG_INF)
+        _scan_init(m_scr, arg_scr, lse_scr, tok_scr)
 
     # ---- Phase 1 (t < ng): paged flash-decode for K1 query positions. ----
-    # Per position the ops/shapes mirror _paged_decode_kernel exactly (one
-    # [H, hd] x [bs, H, hd] einsum per position) so phase-1 state is bitwise
-    # what the unfused paged kernel would hold for the same (lane, page).
+    # Per position this is the unfused paged kernel's step (``attend_page``
+    # on the same [H, hd] x [bs, H, hd] shapes), so phase-1 state is bitwise
+    # what that kernel would hold for the same (lane, page).
     @pl.when(t < ng)
     def _attend():
         if quantized:
@@ -189,67 +249,34 @@ def _fused_verify_kernel(
         else:
             k = k_ref[0].astype(jnp.float32)  # [bs, H, hd]
             v = v_ref[0].astype(jnp.float32)
-        k_pos = t * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+        k_pos = t * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1, 1), 0)
         for i in range(k1):
-            q = q_ref[0, i].astype(jnp.float32)  # [H, hd]
-            s = jnp.einsum("hd,khd->hk", q, k) * sm_scale  # [H, bs]
-            length = len_ref[b, i]
-            valid = k_pos < length
-            valid = jnp.logical_and(valid, k_pos >= length - window)
-            s = jnp.where(valid, s, NEG_INF)
-            m_prev = m_att[i, :]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-            p = jnp.exp(s - m_new[:, None])
-            alpha = jnp.exp(m_prev - m_new)
-            l_att[i, :] = alpha * l_att[i, :] + jnp.sum(p, axis=-1)
-            acc_att[i, :, :] = acc_att[i, :, :] * alpha[:, None] + jnp.einsum("hk,khd->hd", p, v)
-            m_att[i, :] = m_new
+            m_att[i], l_att[i], acc_att[i] = attend_page(
+                q_ref[0, i].astype(jnp.float32), k, v, k_pos, len_ref[b, i],
+                m_att[i], l_att[i], acc_att[i], sm_scale=sm_scale, window=window,
+            )
 
     @pl.when(t == ng - 1)
     def _finalize_attention():
         # Round-trip through the query dtype exactly like the unfused
         # kernel's o_ref cast, so downstream logits see identical values.
-        for i in range(k1):
-            denom = jnp.maximum(l_att[i, :], 1e-30)[:, None]
-            o = (acc_att[i, :, :] / denom).astype(q_ref.dtype)  # [H, hd]
-            o_scr[i, :] = o.astype(jnp.float32).reshape(-1)
+        o = (acc_att[...] / jnp.maximum(l_att[...], 1e-30)).astype(q_ref.dtype)
+        o_scr[...] = o.astype(jnp.float32)
 
     # ---- Phase 2 (t >= ng): LM-head tile + the _verify_kernel update. ----
-    K = k1 - 1
-    tok_row = jnp.concatenate(
-        [tokens_ref[0, :].reshape(K), jnp.full((1,), -1, jnp.int32)]
-    )  # [K1]
-
     @pl.when(t >= ng)
     def _verify():
         vb = t - ng
-        s = jnp.dot(o_scr[...], w_ref[...])  # [K1, bv] f32
+        s = lm_head_tile(o_scr, w_ref)  # [K1, bv] f32
         ids = vb * bv + jax.lax.broadcasted_iota(jnp.int32, (k1, bv), 1)
         s = jnp.where(ids >= v_true, NEG_INF, s)  # vocab pad lanes are inert
-        blk_max = jnp.max(s, axis=-1)  # [K1]
-        blk_arg = jnp.min(jnp.where(s == blk_max[:, None], ids, jnp.int32(2**30)), axis=-1)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, blk_max)
-        lse_scr[...] = lse_scr[...] * jnp.exp(m_prev - m_new) + jnp.sum(
-            jnp.exp(s - m_new[:, None]), axis=-1
-        )
-        arg_scr[...] = jnp.where(blk_max > m_prev, blk_arg, arg_scr[...])
-        m_scr[...] = m_new
-        hit = ids == tok_row[:, None]  # [K1, bv]
-        gathered = jnp.sum(jnp.where(hit, s, 0.0), axis=-1)
-        tok_scr[...] = jnp.where(jnp.any(hit, axis=-1), gathered, tok_scr[...])
+        _scan_update(s, ids, tok_ref[0], m_scr, arg_scr, lse_scr, tok_scr)
 
     @pl.when(t == ng + nv - 1)
     def _finalize():
-        greedy = arg_scr[...]  # [K1]
-        lse = m_scr[...] + jnp.log(jnp.maximum(lse_scr[...], 1e-30))
-        n_d = nd_ref[0, 0]
-        pos = jax.lax.broadcasted_iota(jnp.int32, (k1,), 0)
-        match = jnp.logical_and(greedy == tok_row, pos < n_d)[:K]
-        n_acc = jnp.sum(jnp.cumprod(match.astype(jnp.int32)))
-        nacc_ref[0, 0] = n_acc
-        corr_ref[0, 0] = jnp.sum(jnp.where(pos == jnp.minimum(n_acc, K), greedy, 0))
-        logp_ref[0, :] = (tok_scr[...] - lse)[:K]
+        _chain_finalize(
+            nd_ref[b], tok_ref[0], m_scr, arg_scr, lse_scr, tok_scr, res_ref, logp_ref, k1=k1
+        )
 
 
 def spec_verify_fused_pallas(
@@ -289,11 +316,9 @@ def spec_verify_fused_pallas(
         raise ValueError(f"Vp={Vp} must be divisible by block_v={bv}")
     nv = Vp // bv
     G = block_tables.shape[1]
-    K = K1 - 1
-    sm_scale = 1.0 / math.sqrt(hd)
     kernel = functools.partial(
         _fused_verify_kernel,
-        sm_scale=sm_scale,
+        sm_scale=1.0 / math.sqrt(hd),
         window=int(window),
         bs=bs,
         ng=G,
@@ -303,10 +328,11 @@ def spec_verify_fused_pallas(
         v_true=int(v_true),
         quantized=quant is not None,
     )
-    page_ix = lambda b, t, bt, ln: (bt[b, jnp.minimum(t, G - 1)], 0, 0, 0)  # noqa: E731
-    param_ix = lambda b, t, bt, ln: (bt[b, jnp.minimum(t, G - 1)], 0, 0)  # noqa: E731
+    page_ix = lambda b, t, bt, ln, nd: (bt[b, jnp.minimum(t, G - 1)], 0, 0, 0)  # noqa: E731
+    param_ix = lambda b, t, bt, ln, nd: (bt[b, jnp.minimum(t, G - 1)], 0, 0)  # noqa: E731
+    row_ix = lambda b, t, bt, ln, nd: (b, 0, 0)  # noqa: E731
     in_specs = [
-        pl.BlockSpec((1, K1, H, hd), lambda b, t, bt, ln: (b, 0, 0, 0)),
+        pl.BlockSpec((1, K1, H, hd), lambda b, t, bt, ln, nd: (b, 0, 0, 0)),
         pl.BlockSpec((1, bs, H, hd), page_ix),
         pl.BlockSpec((1, bs, H, hd), page_ix),
     ]
@@ -315,129 +341,104 @@ def spec_verify_fused_pallas(
         in_specs += [pl.BlockSpec((1, bs, H), param_ix)] * 4
         operands += [p.astype(jnp.float32) for p in quant]
     in_specs += [
-        pl.BlockSpec((F, bv), lambda b, t, bt, ln: (0, jnp.maximum(t - G, 0))),
-        pl.BlockSpec((1, K), lambda b, t, bt, ln: (b, 0), memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, 1), lambda b, t, bt, ln: (b, 0), memory_space=pltpu.SMEM),
+        pl.BlockSpec((H, hd, bv), lambda b, t, bt, ln, nd: (0, 0, jnp.maximum(t - G, 0))),
+        pl.BlockSpec((1, K1, 1), row_ix),
     ]
-    operands += [
-        w.astype(jnp.float32),
-        draft_tokens.astype(jnp.int32),
-        n_drafted.reshape(B, 1).astype(jnp.int32),
-    ]
+    operands += [w.astype(jnp.float32).reshape(H, hd, Vp), _token_column(draft_tokens, K1)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block_tables, per-position lengths
+        num_scalar_prefetch=3,  # block_tables, per-position lengths, n_drafted
         grid=(B, G + nv),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda b, t, bt, ln: (b, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda b, t, bt, ln: (b, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, K), lambda b, t, bt, ln: (b, 0)),
-        ],
+        out_specs=[pl.BlockSpec((1, 1, 128), row_ix), pl.BlockSpec((1, K1, 1), row_ix)],
         scratch_shapes=[
-            pltpu.VMEM((K1, H), jnp.float32),
-            pltpu.VMEM((K1, H), jnp.float32),
+            pltpu.VMEM((K1, H, 1), jnp.float32),
+            pltpu.VMEM((K1, H, 1), jnp.float32),
             pltpu.VMEM((K1, H, hd), jnp.float32),
-            pltpu.VMEM((K1, F), jnp.float32),
-            pltpu.VMEM((K1,), jnp.float32),
-            pltpu.VMEM((K1,), jnp.int32),
-            pltpu.VMEM((K1,), jnp.float32),
-            pltpu.VMEM((K1,), jnp.float32),
+            pltpu.VMEM((K1, H, hd), jnp.float32),
+            pltpu.VMEM((K1, 1), jnp.float32),
+            pltpu.VMEM((K1, 1), jnp.int32),
+            pltpu.VMEM((K1, 1), jnp.float32),
+            pltpu.VMEM((K1, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    res, logp = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, K), jnp.float32),
-        ],
-        compiler_params=CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        out_shape=_chain_outputs(B, K1),
+        compiler_params=_SEMANTICS,
         interpret=interpret,
     )(
         block_tables.astype(jnp.int32),
         lengths.astype(jnp.int32),
+        n_drafted.astype(jnp.int32),
         *operands,
     )
+    return _chain_result(res, logp, K1 - 1)
 
 
 def _tree_verify_kernel(
+    nn_ref,  # [B] i32 scalar-prefetch — n_nodes
     logits_ref,  # [1, N1, BV] f32/bf16 target logits block (row 0 = anchor)
-    tokens_ref,  # [1, N] i32 packed node tokens (SMEM)
-    prow_ref,  # [1, N] i32 verify row per node = parents + 1 (SMEM)
-    depth_ref,  # [1, N] i32 1-based node depth (SMEM)
-    nn_ref,  # [1, 1] i32 n_nodes (SMEM)
+    tok_ref,  # [1, N, 1] i32 packed node tokens
+    prow_ref,  # [1, N, 1] i32 verify row per node = parents + 1
+    depth_ref,  # [1, N, 1] i32 1-based node depth
     anc_ref,  # [1, N, N] i32 packed ancestor mask (anc[i,j]=1: j on root→i path)
-    nacc_ref,  # [1, 1] i32 out — depth of deepest accepted node
-    best_ref,  # [1, 1] i32 out — packed index of that node (-1 if none)
-    corr_ref,  # [1, 1] i32 out — correction/bonus token
-    logp_ref,  # [1, N] f32 out — log P_target(node token) at its verify row
-    m_scr,  # [N1] f32 running max
-    arg_scr,  # [N1] i32 running argmax
-    lse_scr,  # [N1] f32 running sum exp (shifted by m)
-    tok_scr,  # [N] f32 node-token logits gathered at each node's verify row
+    res_ref,  # [1, 1, 128] i32 out — lanes: n_accepted (depth), best node, correction
+    logp_ref,  # [1, N, 1] f32 out — log P_target(node token) at its verify row
+    m_scr,  # [N1, 1] f32 running max
+    arg_scr,  # [N1, 1] i32 running argmax
+    lse_scr,  # [N1, 1] f32 running sum exp (shifted by m)
+    tok_scr,  # [N, 1] f32 node-token logits gathered at each node's verify row
     *,
     bv: int,
     nv: int,
     n1: int,
 ):
-    vb = pl.program_id(1)
+    b, vb = pl.program_id(0), pl.program_id(1)
     N = n1 - 1
 
     @pl.when(vb == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        arg_scr[...] = jnp.zeros_like(arg_scr)
-        lse_scr[...] = jnp.zeros_like(lse_scr)
-        tok_scr[...] = jnp.full_like(tok_scr, NEG_INF)
+        _scan_init(m_scr, arg_scr, lse_scr, tok_scr)
 
     s = logits_ref[0].astype(jnp.float32)  # [N1, BV]
-    ids1 = vb * bv + jax.lax.broadcasted_iota(jnp.int32, (n1, bv), 1)
-    blk_max = jnp.max(s, axis=-1)  # [N1]
-    blk_arg = jnp.min(jnp.where(s == blk_max[:, None], ids1, jnp.int32(2**30)), axis=-1)
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, blk_max)
-    lse_scr[...] = lse_scr[...] * jnp.exp(m_prev - m_new) + jnp.sum(jnp.exp(s - m_new[:, None]), axis=-1)
-    arg_scr[...] = jnp.where(blk_max > m_prev, blk_arg, arg_scr[...])
-    m_scr[...] = m_new
+    _scan_rows(s, vb * bv + jax.lax.broadcasted_iota(jnp.int32, (n1, bv), 1), m_scr, arg_scr, lse_scr)
     # Gather each node's token logit from its VERIFY row (unlike the chain
     # kernel, node i is scored by row prow[i], not row i): a one-hot matmul
     # re-indexes the [N1, BV] tile to [N, BV] before the in-block id match.
-    tok_row = tokens_ref[0, :].reshape(N)  # [N]
-    prow = prow_ref[0, :].reshape(N)  # [N]
+    tok = tok_ref[0]  # [N, 1]
     row_ids = jax.lax.broadcasted_iota(jnp.int32, (N, n1), 1)
-    onehot = (row_ids == prow[:, None]).astype(jnp.float32)  # [N, N1]
-    s_at = jnp.dot(onehot, s, preferred_element_type=jnp.float32)  # [N, BV]
-    ids = vb * bv + jax.lax.broadcasted_iota(jnp.int32, (N, bv), 1)
-    hit = ids == tok_row[:, None]  # [N, BV]
-    gathered = jnp.sum(jnp.where(hit, s_at, 0.0), axis=-1)
-    tok_scr[...] = jnp.where(jnp.any(hit, axis=-1), gathered, tok_scr[...])
+    onehot = (row_ids == prow_ref[0]).astype(jnp.float32)  # [N, N1]
+    s_at = jnp.dot(onehot, s, precision=HIGHEST, preferred_element_type=jnp.float32)  # [N, BV]
+    _gather_tokens(s_at, vb * bv + jax.lax.broadcasted_iota(jnp.int32, (N, bv), 1), tok, tok_scr)
 
     @pl.when(vb == nv - 1)
     def _finalize():
-        greedy = arg_scr[...]  # [N1]
-        lse = m_scr[...] + jnp.log(jnp.maximum(lse_scr[...], 1e-30))
-        n_d = nn_ref[0, 0]
-        depth = depth_ref[0, :].reshape(N)
-        oh = row_ids == prow[:, None]  # [N, N1]
-        g_at = jnp.sum(jnp.where(oh, greedy[None, :], 0), axis=-1)  # [N]
-        lse_at = jnp.sum(jnp.where(oh, lse[None, :], 0.0), axis=-1)
-        pos = jax.lax.broadcasted_iota(jnp.int32, (N,), 0)
-        valid = pos < n_d
-        match = jnp.logical_and(g_at == tok_row, valid)
-        anc = anc_ref[0] != 0  # [N, N]
-        # accepted[i] = all nodes on root→i path match (anc[i,i] covers i).
-        accepted = jnp.logical_and(jnp.all(jnp.logical_or(match[None, :], ~anc), axis=-1), valid)
-        acc_depth = jnp.where(accepted, depth, 0)
-        n_acc = jnp.max(acc_depth)
-        best = jnp.min(jnp.where(jnp.logical_and(accepted, acc_depth == n_acc), pos, jnp.int32(2**30)))
+        lse = m_scr[...] + jnp.log(jnp.maximum(lse_scr[...], 1e-30))  # [N1, 1]
+        # Row lookups as one-hot matmuls (column vectors stay columns):
+        # greedy ids are < 2**24, so float32 carries them exactly.
+        g_at = jnp.dot(onehot, arg_scr[...].astype(jnp.float32), precision=HIGHEST)
+        lse_at = jnp.dot(onehot, lse, precision=HIGHEST)  # [N, 1]
+        pos = jax.lax.broadcasted_iota(jnp.int32, (N, 1), 0)
+        valid = pos < nn_ref[b]
+        match = jnp.logical_and(g_at.astype(jnp.int32) == tok, valid)
+        # accepted[i] = no node on the root→i path misses (anc[i,i] covers i).
+        misses = jnp.dot(
+            anc_ref[0].astype(jnp.float32), 1.0 - match.astype(jnp.float32), precision=HIGHEST
+        )
+        accepted = jnp.logical_and(misses == 0.0, valid)
+        acc_depth = jnp.where(accepted, depth_ref[0], 0)
+        n_acc = jnp.max(acc_depth, axis=0, keepdims=True)  # (1, 1)
+        best = jnp.min(
+            jnp.where(jnp.logical_and(accepted, acc_depth == n_acc), pos, _BIG),
+            axis=0, keepdims=True,
+        )
         best = jnp.where(n_acc > 0, best, -1)
         best_row = jnp.where(n_acc > 0, best + 1, 0)
-        ids_n1 = jax.lax.broadcasted_iota(jnp.int32, (n1,), 0)
-        nacc_ref[0, 0] = n_acc
-        best_ref[0, 0] = best
-        corr_ref[0, 0] = jnp.sum(jnp.where(ids_n1 == best_row, greedy, 0))
-        logp_ref[0, :] = tok_scr[...] - lse_at
+        ids_n1 = jax.lax.broadcasted_iota(jnp.int32, (n1, 1), 0)
+        corr = jnp.sum(jnp.where(ids_n1 == best_row, arg_scr[...], 0), axis=0, keepdims=True)
+        res_ref[0] = _lanes(n_acc, best, corr)
+        logp_ref[0] = tok_scr[...] - lse_at
 
 
 def spec_verify_tree_pallas(
@@ -462,45 +463,43 @@ def spec_verify_tree_pallas(
         raise ValueError(f"V={V} must be divisible by block_v={bv}")
     nv = V // bv
     kernel = functools.partial(_tree_verify_kernel, bv=bv, nv=nv, n1=N1)
-    return pl.pallas_call(
+    row_ix = lambda b, j, nn: (b, 0, 0)  # noqa: E731
+    col = pl.BlockSpec((1, N, 1), row_ix)
+    res, logp = pl.pallas_call(
         kernel,
-        grid=(B, nv),
-        in_specs=[
-            pl.BlockSpec((1, N1, bv), lambda b, j: (b, 0, j)),
-            pl.BlockSpec((1, N), lambda b, j: (b, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, N), lambda b, j: (b, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, N), lambda b, j: (b, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, N, N), lambda b, j: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, N), lambda b, j: (b, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,  # n_nodes
+            grid=(B, nv),
+            in_specs=[
+                pl.BlockSpec((1, N1, bv), lambda b, j, nn: (b, 0, j)),
+                col,
+                col,
+                col,
+                pl.BlockSpec((1, N, N), row_ix),
+            ],
+            out_specs=[pl.BlockSpec((1, 1, 128), row_ix), col],
+            scratch_shapes=[
+                pltpu.VMEM((N1, 1), jnp.float32),
+                pltpu.VMEM((N1, 1), jnp.int32),
+                pltpu.VMEM((N1, 1), jnp.float32),
+                pltpu.VMEM((N, 1), jnp.float32),
+            ],
+        ),
         out_shape=[
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, N), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, 128), jnp.int32),
+            jax.ShapeDtypeStruct((B, N, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((N1,), jnp.float32),
-            pltpu.VMEM((N1,), jnp.int32),
-            pltpu.VMEM((N1,), jnp.float32),
-            pltpu.VMEM((N,), jnp.float32),
-        ],
-        compiler_params=CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=_SEMANTICS,
         interpret=interpret,
     )(
+        n_nodes.astype(jnp.int32),
         target_logits,
-        tokens.astype(jnp.int32),
-        prow.astype(jnp.int32),
-        depth.astype(jnp.int32),
-        n_nodes.reshape(B, 1).astype(jnp.int32),
+        tokens.astype(jnp.int32)[:, :, None],
+        prow.astype(jnp.int32)[:, :, None],
+        depth.astype(jnp.int32)[:, :, None],
         anc.astype(jnp.int32),
     )
+    return res[:, 0, 0:1], res[:, 0, 1:2], res[:, 0, 2:3], logp[:, :, 0]
 
 
 def spec_verify_pallas(
@@ -512,7 +511,6 @@ def spec_verify_pallas(
     interpret: bool = False,
 ):
     B, K1, V = target_logits.shape
-    K = K1 - 1
     if K1 > 128:
         raise ValueError(f"K+1={K1} exceeds the [K1] VMEM scratch budget (max 128)")
     bv = min(block_v, V)
@@ -520,30 +518,26 @@ def spec_verify_pallas(
         raise ValueError(f"V={V} must be divisible by block_v={bv}")
     nv = V // bv
     kernel = functools.partial(_verify_kernel, bv=bv, nv=nv, k1=K1)
-    return pl.pallas_call(
+    row_ix = lambda b, j, nd: (b, 0, 0)  # noqa: E731
+    res, logp = pl.pallas_call(
         kernel,
-        grid=(B, nv),
-        in_specs=[
-            pl.BlockSpec((1, K1, bv), lambda b, j: (b, 0, j)),
-            pl.BlockSpec((1, K), lambda b, j: (b, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0), memory_space=pltpu.SMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, K), lambda b, j: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, K), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((K1,), jnp.float32),
-            pltpu.VMEM((K1,), jnp.int32),
-            pltpu.VMEM((K1,), jnp.float32),
-            pltpu.VMEM((K1,), jnp.float32),
-        ],
-        compiler_params=CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,  # n_drafted
+            grid=(B, nv),
+            in_specs=[
+                pl.BlockSpec((1, K1, bv), lambda b, j, nd: (b, 0, j)),
+                pl.BlockSpec((1, K1, 1), row_ix),
+            ],
+            out_specs=[pl.BlockSpec((1, 1, 128), row_ix), pl.BlockSpec((1, K1, 1), row_ix)],
+            scratch_shapes=[
+                pltpu.VMEM((K1, 1), jnp.float32),
+                pltpu.VMEM((K1, 1), jnp.int32),
+                pltpu.VMEM((K1, 1), jnp.float32),
+                pltpu.VMEM((K1, 1), jnp.float32),
+            ],
+        ),
+        out_shape=_chain_outputs(B, K1),
+        compiler_params=_SEMANTICS,
         interpret=interpret,
-    )(target_logits, draft_tokens.astype(jnp.int32), n_drafted.reshape(B, 1).astype(jnp.int32))
+    )(n_drafted.astype(jnp.int32), target_logits, _token_column(draft_tokens, K1))
+    return _chain_result(res, logp, K1 - 1)

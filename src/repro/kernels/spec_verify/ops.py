@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .kernel import spec_verify_fused_pallas, spec_verify_pallas, spec_verify_tree_pallas
+from .kernel import DEFAULT_BV, spec_verify_fused_pallas, spec_verify_pallas, spec_verify_tree_pallas
 from .ref import spec_verify_fused_ref, spec_verify_ref, spec_verify_tree_ref, tree_topology
 
 
@@ -33,7 +33,7 @@ def spec_verify(
     draft_tokens: jax.Array,  # [B, K]
     n_drafted: jax.Array,  # [B]
     *,
-    impl: str = "interpret",
+    impl: str,
     block_v: int = 2048,
 ):
     if impl == "ref":
@@ -59,8 +59,8 @@ def spec_verify_fused(
     n_drafted: jax.Array,  # [B] i32
     *,
     v_true: Optional[int] = None,
-    impl: str = "interpret",
-    block_v: int = 2048,
+    impl: str,
+    block_v: int = DEFAULT_BV,
     window: int = 1 << 30,
     quant=None,  # (k_scale, k_zero, v_scale, v_zero), each [P, bs, Hkv] f32
 ):
@@ -120,8 +120,8 @@ def spec_verify_fused_batched(
     v_pages: jax.Array,
     w: jax.Array,
     *,
-    impl: str = "interpret",
-    block_v: int = 2048,
+    impl: str,
+    block_v: int = DEFAULT_BV,
     bucket: bool = True,
     window: int = 1 << 30,
     pad_page_id: int = 0,
@@ -209,7 +209,7 @@ def spec_verify_batched(
     logits_seq: Optional[Sequence],  # B entries of [K_i+1, V]; None with batched_logits_fn
     tokens_seq: Sequence,  # B entries of length-K_i int sequences
     *,
-    impl: str = "ref",
+    impl: str,
     block_v: int = 2048,
     bucket: bool = True,
     block_tables_seq: Optional[Sequence] = None,  # B ragged KV block tables
@@ -317,7 +317,7 @@ def spec_verify_tree(
     parents: jax.Array,  # [B, N] int32, -1 = root level, parents[i] < i
     n_nodes: jax.Array,  # [B]
     *,
-    impl: str = "interpret",
+    impl: str,
     block_v: int = 2048,
 ):
     """Greedy tree-NAV: (n_accepted [B,1], best_node [B,1], corr [B,1], logp [B,N])."""
@@ -341,7 +341,7 @@ def spec_verify_tree_batched(
     tokens_seq: Sequence,  # B entries of length-N_i int sequences
     parents_seq: Sequence,  # B entries of length-N_i int sequences
     *,
-    impl: str = "ref",
+    impl: str,
     block_v: int = 2048,
     bucket: bool = True,
     block_tables_seq: Optional[Sequence] = None,  # B ragged KV block tables

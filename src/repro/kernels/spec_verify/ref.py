@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..tiles import lm_head_tile
+
 
 def spec_verify_ref(target_logits: jax.Array, draft_tokens: jax.Array, n_drafted: jax.Array):
     """Returns (n_accepted [B,1], correction [B,1], draft_logp [B,K])."""
@@ -46,28 +48,43 @@ def spec_verify_ref(target_logits: jax.Array, draft_tokens: jax.Array, n_drafted
     return n_acc[:, None], corr, logp
 
 
+def lm_head_logits(o: jax.Array, w: jax.Array, *, block_v: int) -> jax.Array:
+    """Blocked LM-head projection matching the fused kernel tile-for-tile.
+
+    ``o [B, K1, H, hd]`` attention outputs times ``w [H*hd, Vp]`` (``Vp`` a
+    ``block_v`` multiple) gives ``[B, K1, Vp]`` logits, one
+    ``tiles.lm_head_tile`` — the EXACT per-head products and summation
+    order the fused kernel issues — per (lane, vocab tile).  ``lax.map``
+    keeps the traced program one tile long whatever B and Vp are.
+    """
+    B, K1, H, hd = o.shape
+    Vp = w.shape[1]
+    if Vp % block_v:
+        raise ValueError(f"Vp={Vp} must be a multiple of block_v={block_v}")
+    nt = Vp // block_v
+    w_tiles = jnp.moveaxis(w.astype(jnp.float32).reshape(H, hd, nt, block_v), 2, 0)
+
+    def lane(o_b):  # [K1, H, hd] -> [nt, K1, bv]
+        return jax.lax.map(lambda w_t: lm_head_tile(o_b, w_t), w_tiles)
+
+    tiles = jax.lax.map(lane, o.astype(jnp.float32))  # [B, nt, K1, bv]
+    return jnp.moveaxis(tiles, 1, 2).reshape(B, K1, Vp)
+
+
 def fused_target_logits(
-    o: jax.Array,  # [B, K1, F] f32 attention outputs (F = H*hd)
-    w: jax.Array,  # [F, Vp] f32 LM head, Vp a multiple of block_v
+    o: jax.Array,  # [B, K1, H, hd] f32 attention outputs
+    w: jax.Array,  # [H*hd, Vp] f32 LM head, Vp a multiple of block_v
     *,
     block_v: int,
     v_true: int,
 ) -> jax.Array:
-    """Blocked LM-head projection matching the fused kernel tile-for-tile.
+    """``lm_head_logits`` with padded vocab ids masked to ``-1e30``.
 
-    One ``jnp.dot([K1, F], [F, block_v])`` per (lane, vocab tile) — the
-    EXACT shapes the fused kernel issues — then padded vocab ids masked to
-    ``-1e30``, so composing this with ``spec_verify`` reproduces the fused
-    launch bitwise (same values through the same arithmetic).
+    Composing this with ``spec_verify`` reproduces the fused launch bitwise
+    (same values through the same arithmetic).
     """
-    B, K1, F = o.shape
-    Vp = w.shape[1]
-    if Vp % block_v:
-        raise ValueError(f"Vp={Vp} must be a multiple of block_v={block_v}")
-    tiles = [w[:, j : j + block_v] for j in range(0, Vp, block_v)]
-    rows = [jnp.concatenate([jnp.dot(o[b], t) for t in tiles], axis=-1) for b in range(B)]
-    logits = jnp.stack(rows)
-    ids = jnp.arange(Vp)[None, None, :]
+    logits = lm_head_logits(o, w, block_v=block_v)
+    ids = jnp.arange(logits.shape[-1])[None, None, :]
     return jnp.where(ids >= v_true, -1e30, logits)
 
 
@@ -99,8 +116,8 @@ def spec_verify_fused_ref(
     tf = jnp.repeat(jnp.asarray(block_tables, jnp.int32), K1, axis=0)
     lf = jnp.asarray(lengths, jnp.int32).reshape(B * K1)
     o = paged_decode_attention_ref(qf, k_pages, v_pages, tf, lf, window=window)
-    o = o.reshape(B, K1, H * hd).astype(jnp.float32)
-    logits = fused_target_logits(o, w.astype(jnp.float32), block_v=block_v, v_true=v_true)
+    o = o.reshape(B, K1, H, hd).astype(jnp.float32)
+    logits = fused_target_logits(o, w, block_v=block_v, v_true=v_true)
     return spec_verify_ref(logits, draft_tokens, n_drafted)
 
 

@@ -199,7 +199,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: Path, force: b
     try:
         mesh = make_production_mesh(multi_pod=(mesh_kind == "multipod"))
         step, arg_specs, in_sh, out_sh, donate, cfg2, part = build_cell(arch, shape_name, mesh)
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(step, in_shardings=in_sh, out_shardings=out_sh, donate_argnums=donate)
             lowered = jitted.lower(*arg_specs)
             t_lower = time.time() - t0
@@ -218,7 +218,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: Path, force: b
         for lp in (l1, l2):
             pcfg = dataclasses.replace(_with_layers(cfg, lp), scan_unroll=True)
             pstep, pargs, pin, pout, pdon, _, _ = build_cell(arch, shape_name, mesh, cfg=pcfg)
-            with mesh:
+            with jax.set_mesh(mesh):
                 pcompiled = jax.jit(pstep, in_shardings=pin, out_shardings=pout, donate_argnums=pdon).lower(*pargs).compile()
                 pcost = _as_cost_dict(pcompiled.cost_analysis())
                 pcoll = collective_census(pcompiled.as_text())

@@ -49,9 +49,11 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, Set, Tuple
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.monitor import EnvironmentMonitor
+from repro.kernels.spec_verify.kernel import DEFAULT_BV
 from repro.models.paged_kv import BlockPoolExhausted, PagedKVPool
 from repro.obs.trace import NULL_TRACER
 from .protocol import (
@@ -203,14 +205,21 @@ class SyntheticBackend(VerifyBackend):
         return [self._accept_tree(c, p) for (_, _, c, p) in requests]
 
 
+# Kernel implementations a verify backend can be asked for (see docs/kernels.md).
+IMPLS = ("pallas", "interpret", "ref")
+
+
 class SpecVerifyBackend(VerifyBackend):
     """Real NAV verification through the fused spec_verify kernel.
 
     ``logits_fn(session, tokens) -> [len(tokens)+1, V]`` produces the target
     logits for one session (a model forward in a real deployment, a seeded
     synthetic sampler in tests).  ``verify_batch`` pads the ragged requests
-    and runs them through ``spec_verify_batched`` in ONE launch — Pallas on
-    TPU (``impl='pallas'``), interpret mode or the pure-JAX ``ref`` on CPU.
+    and runs them through ``spec_verify_batched`` in ONE launch.  ``impl``
+    is the caller's choice and is never changed behind its back: the
+    compiled Pallas kernel on a TPU (``'pallas'``), the same kernel under
+    the CPU interpreter (``'interpret'``), or the pure-JAX oracle
+    (``'ref'``).
 
     **Paged target forward.**  With ``batched_logits_fn`` (and a ``kv_pool``
     supplying per-session KV block tables) the per-session ``logits_fn``
@@ -247,8 +256,9 @@ class SpecVerifyBackend(VerifyBackend):
     def __init__(
         self,
         logits_fn: Optional[Callable] = None,
-        impl: str = "ref",
-        block_v: int = 2048,
+        *,
+        impl: str,
+        block_v: int = DEFAULT_BV,
         kv_pool: Optional[PagedKVPool] = None,
         batched_logits_fn: Optional[Callable] = None,
         batched_tree_logits_fn: Optional[Callable] = None,
@@ -264,6 +274,8 @@ class SpecVerifyBackend(VerifyBackend):
                 raise ValueError("fused=True needs query_fn and lm_head")
         elif logits_fn is None and batched_logits_fn is None:
             raise ValueError("need logits_fn or batched_logits_fn")
+        if impl not in IMPLS:
+            raise ValueError(f"impl must be one of {IMPLS}, got {impl!r}")
         self.logits_fn = logits_fn
         self.impl = impl
         self.block_v = block_v
@@ -272,7 +284,9 @@ class SpecVerifyBackend(VerifyBackend):
         self.batched_tree_logits_fn = batched_tree_logits_fn
         self.fused = fused
         self.query_fn = query_fn
-        self.lm_head = lm_head
+        # Device-resident once: a host LM head would cross to the device on
+        # every dispatch.
+        self.lm_head = None if lm_head is None else jnp.asarray(lm_head, jnp.float32)
         self.kv_fn = kv_fn if kv_fn is not None else self._default_kv_fn
 
     def _tables(self, sessions: Sequence[int]):
@@ -331,9 +345,9 @@ class SpecVerifyBackend(VerifyBackend):
             return []
         from repro.kernels.spec_verify import spec_verify_batched
 
-        tokens = [t for (_, t, _) in requests]
         if self.fused:
-            return self._verify_batch_fused(requests)
+            return [(int(n_acc), int(corr)) for (n_acc, corr, _) in self.fused_verify(requests)]
+        tokens = [t for (_, t, _) in requests]
         if self.batched_logits_fn is not None:
             out = spec_verify_batched(
                 None,
@@ -349,40 +363,44 @@ class SpecVerifyBackend(VerifyBackend):
             out = spec_verify_batched(logits, tokens, impl=self.impl, block_v=self.block_v)
         return [(int(n_acc), int(corr)) for (n_acc, corr, _) in out]
 
-    def _verify_batch_fused(self, requests):
-        """ONE launch for the whole round: attention + LM head + NAV scan.
+    def fused_inputs(self, requests):
+        """The fused launch's arguments for one round, as keyword arguments.
 
-        Fills any unmaterialized KV slots (the dispatcher appends page
-        metadata in ``_kv_secure`` before we run), then hands queries, block
-        tables, page tensors (+ int8 quant params when the pool quantizes),
-        and the LM head to ``spec_verify_fused_batched``.
+        Fills any unmaterialized KV slots first (the dispatcher appends page
+        metadata in ``_kv_secure`` before we run), then gathers queries,
+        block tables, base lengths, the pool's layer-0 page tensors (+ int8
+        quant params when the pool quantizes) and the LM head — the shared
+        signature of ``spec_verify_fused_batched`` and its sharded twin.
         """
-        from repro.kernels.spec_verify import spec_verify_fused_batched
-
         pool = self.kv_pool
         sessions = [s for (s, _, _) in requests]
         for s in sessions:
             self.ensure_kv(s)
-        tokens = [t for (_, t, _) in requests]
-        q_seq = [np.asarray(self.query_fn(s, t), np.float32) for (s, t, _) in requests]
-        base = [max(pool.length(s) - len(t), 0) for (s, t, _) in requests]
         quant = None
         if pool.quantize == "int8":
             quant = (pool.k_scale[0], pool.k_zero[0], pool.v_scale[0], pool.v_zero[0])
-        out = spec_verify_fused_batched(
-            q_seq,
-            tokens,
-            self._tables(sessions),
-            base,
-            pool.k_pages[0],
-            pool.v_pages[0],
-            self.lm_head,
-            impl=self.impl,
-            block_v=self.block_v,
+        return dict(
+            q_seq=[np.asarray(self.query_fn(s, t), np.float32) for (s, t, _) in requests],
+            tokens_seq=[t for (_, t, _) in requests],
+            block_tables_seq=self._tables(sessions),
+            base_lengths=[max(pool.length(s) - len(t), 0) for (s, t, _) in requests],
+            k_pages=pool.k_pages[0],
+            v_pages=pool.v_pages[0],
+            w=self.lm_head,
             pad_page_id=pool.sentinel_page,
             quant=quant,
         )
-        return [(int(n_acc), int(corr)) for (n_acc, corr, _) in out]
+
+    def fused_verify(self, requests):
+        """ONE launch for the whole round: attention + LM head + NAV scan.
+
+        Returns ``(n_accepted, correction, logp[K_i])`` per request.
+        """
+        from repro.kernels.spec_verify import spec_verify_fused_batched
+
+        return spec_verify_fused_batched(
+            **self.fused_inputs(requests), impl=self.impl, block_v=self.block_v
+        )
 
     def verify_tree(self, session, tokens, confs, parents):
         """Verify one session's tree through the batched path (batch of one)."""
@@ -440,16 +458,27 @@ class ShardedSpecVerifyBackend(SpecVerifyBackend):
     the unsharded backend (``tests/test_sharded_verify.py``) for fp32 and
     int8 pools, including GQA head counts that don't divide the mesh.
 
+    The sharded launch is XLA ``jax.numpy`` code — the stage-by-stage
+    oracle of the fused kernel, partitioned — not a Pallas kernel.  So
+    ``impl`` can only be ``'ref'``; any other value is refused rather than
+    silently ignored.
+
     Pass either a prebuilt ``mesh`` or a ``shards`` count; the latter builds
-    a host mesh over the first ``shards`` visible devices (set
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` for CPU runs).
+    a mesh over the first ``shards`` visible devices (``host_mesh``): the
+    chips of a TPU host, or on a CPU host the virtual devices that
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` creates.
     """
 
-    def __init__(self, *, shards: int = 1, mesh: Any = None, **kwargs: Any):
+    def __init__(self, *, shards: int = 1, mesh: Any = None, impl: str = "ref", **kwargs: Any):
         kwargs.setdefault("fused", True)
         if not kwargs["fused"]:
             raise ValueError("ShardedSpecVerifyBackend requires the fused path")
-        super().__init__(**kwargs)
+        if impl != "ref":
+            raise ValueError(
+                f"ShardedSpecVerifyBackend runs the sharded jax.numpy verify, not a "
+                f"Pallas kernel: impl must be 'ref', got {impl!r}"
+            )
+        super().__init__(impl=impl, **kwargs)
         from repro.sharding.shardctx import host_mesh
 
         self.mesh = mesh if mesh is not None else host_mesh(int(shards))
@@ -457,34 +486,13 @@ class ShardedSpecVerifyBackend(SpecVerifyBackend):
         if self.kv_pool is not None:
             self.kv_pool.place_on_mesh(self.mesh)
 
-    def _verify_batch_fused(self, requests):
+    def fused_verify(self, requests):
         """ONE SHARDED launch for the whole round (see the unsharded twin)."""
         from repro.sharding.spec_verify import spec_verify_sharded_batched
 
-        pool = self.kv_pool
-        sessions = [s for (s, _, _) in requests]
-        for s in sessions:
-            self.ensure_kv(s)
-        tokens = [t for (_, t, _) in requests]
-        q_seq = [np.asarray(self.query_fn(s, t), np.float32) for (s, t, _) in requests]
-        base = [max(pool.length(s) - len(t), 0) for (s, t, _) in requests]
-        quant = None
-        if pool.quantize == "int8":
-            quant = (pool.k_scale[0], pool.k_zero[0], pool.v_scale[0], pool.v_zero[0])
-        out = spec_verify_sharded_batched(
-            q_seq,
-            tokens,
-            self._tables(sessions),
-            base,
-            pool.k_pages[0],
-            pool.v_pages[0],
-            self.lm_head,
-            mesh=self.mesh,
-            block_v=self.block_v,
-            pad_page_id=pool.sentinel_page,
-            quant=quant,
+        return spec_verify_sharded_batched(
+            **self.fused_inputs(requests), mesh=self.mesh, block_v=self.block_v
         )
-        return [(int(n_acc), int(corr)) for (n_acc, corr, _) in out]
 
 
 @dataclass

@@ -1,7 +1,7 @@
 """Ambient-mesh-aware sharding constraints.
 
 ``constrain(x, spec_axes)`` applies ``with_sharding_constraint`` only when a
-mesh is ambient (inside ``with mesh:`` under jit) AND every requested axis
+mesh is ambient (inside ``with jax.set_mesh(mesh):``) AND every requested axis
 exists AND the corresponding dim divides evenly — so model code can express
 its preferred layout once and still run un-meshed (CPU tests) or on meshes
 where a dim doesn't divide (falls back to unconstrained for that dim).
@@ -9,7 +9,7 @@ where a dim doesn't divide (falls back to unconstrained for that dim).
 
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple, Union
+from typing import Sequence, Tuple, Union
 
 import numpy as np
 import jax
@@ -23,12 +23,13 @@ __all__ = ["constrain", "ambient_mesh", "axis_size", "abstract_mesh", "host_mesh
 def host_mesh(shards: int, axis: str = "model"):
     """A physical 1-D ``(axis,)`` mesh over the first ``shards`` devices.
 
-    The CPU-mesh entry point for the sharded verifier and its tests: under
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` the host platform
-    exposes N devices, so a multi-shard ``shard_map`` launch runs (and is
-    proven bit-exact) without accelerators.  Raises with the flag spelled
-    out when the process has fewer devices than requested — the flag must be
-    set BEFORE jax initializes its backends.
+    The entry point for the sharded verifier and its tests.  On a TPU host
+    the devices are its chips (a 2x2 v5e host gives four).  On a CPU host
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``, set before jax
+    initializes its backends, exposes N host devices, so a multi-shard
+    ``shard_map`` launch runs (and is proven bit-exact) without chips.
+    Raises with both remedies spelled out when the process sees fewer
+    devices than requested.
     """
     from jax.sharding import Mesh
 
@@ -38,7 +39,8 @@ def host_mesh(shards: int, axis: str = "model"):
     if len(devices) < shards:
         raise RuntimeError(
             f"need {shards} devices for a {shards}-shard mesh but only "
-            f"{len(devices)} are visible; set "
+            f"{len(devices)} {devices[0].platform} devices are visible; run on a "
+            f"host with {shards} chips, or on a CPU host set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={shards} "
             "in the environment before jax initializes"
         )
@@ -46,40 +48,16 @@ def host_mesh(shards: int, axis: str = "model"):
 
 
 def abstract_mesh(sizes: Sequence[int], names: Sequence[str]):
-    """Version-tolerant ``jax.sharding.AbstractMesh`` constructor.
+    """A device-free ``AbstractMesh`` with Auto axes (for spec planning)."""
+    from jax.sharding import AbstractMesh, AxisType
 
-    Newer jax takes ``AbstractMesh(sizes, names)``; 0.4.x takes one
-    ``((name, size), ...)`` shape tuple.  Tests and tools build abstract
-    meshes through this helper so either toolchain works.
-    """
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(sizes), tuple(names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
+    return AbstractMesh(tuple(sizes), tuple(names), axis_types=(AxisType.Auto,) * len(names))
 
 
 def ambient_mesh():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    try:  # physical mesh context (`with mesh:` style)
-        import warnings
-
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            from jax._src import mesh as mesh_lib
-
-            m = mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
+    """The mesh made current by ``jax.set_mesh(mesh)``, or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def axis_size(mesh, axis: Axis) -> int:

@@ -16,14 +16,14 @@ dispatcher and router never learn the shard count:
   garbage that is sliced off right after the gather.
 * **LM head — vocab-parallel (Megatron column style).**  Each shard holds a
   ``[F, Vs]`` column slice of the LM head (``Vs`` a ``block_v`` multiple)
-  and issues the SAME ``jnp.dot([K1, F], [F, block_v])`` tiles as
+  and issues the SAME per-head ``lm_head_tile`` products as
   ``fused_target_logits`` — full contraction dim, local vocab tiles — so
   every logit is produced by identical arithmetic on one shard.  Padded
   vocab ids are masked to ``-1e30`` with GLOBAL ids before the vocab
   ``all_gather``, preserving the unsharded masking contract.
 * **NAV scan — replicated.**  After the gather every shard holds the full
   ``[B, K1, Vp]`` logits and runs ``spec_verify_ref`` redundantly; outputs
-  are replicated (``check_rep=False`` + fully-replicated out specs).
+  are replicated (``check_vma=False`` + fully-replicated out specs).
 * **int8 pages.**  Quantized pools shard the affine ``scale``/``zero``
   planes WITH their KV on the head axis; dequantization is per-element, so
   local dequant of a head slice is bitwise identical to slicing a global
@@ -50,16 +50,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # moved out of jax.experimental in newer releases
-    from jax.shard_map import shard_map  # type: ignore[import]
-except Exception:  # pragma: no cover - jax 0.4.x path
-    from jax.experimental.shard_map import shard_map
-
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.decode_attention.ref import dequantize_pages, paged_decode_attention_ref
 from repro.kernels.spec_verify.ops import _next_pow2, pad_block_tables
-from repro.kernels.spec_verify.ref import spec_verify_ref
+from repro.kernels.spec_verify.ref import lm_head_logits, spec_verify_ref
 
 from .shardctx import host_mesh
 
@@ -188,7 +183,6 @@ def _build_launch(
     replicated NAV scan (or the raw logits when ``with_scan`` is False).
     """
     H, hd, Vp, Vs, bv = heads, head_dim, padded_vocab, vocab_per_shard, block_v
-    F = H * hd
 
     def body(q, kp, vp, w, tables, lengths, tokens, nd, *quant):
         B, K1 = q.shape[0], q.shape[1]
@@ -201,12 +195,10 @@ def _build_launch(
         lf = lengths.reshape(-1)
         o = paged_decode_attention_ref(qf, kp, vp, tf, lf, window=window)
         o = jax.lax.all_gather(o, MODEL_AXIS, axis=1, tiled=True)
-        o = o[:, :H].reshape(B, K1, F).astype(jnp.float32)
+        o = o[:, :H].reshape(B, K1, H, hd).astype(jnp.float32)
         # Same vocab tiles as fused_target_logits, restricted to this
         # shard's LM-head columns — identical per-logit arithmetic.
-        tiles = [w[:, j : j + bv] for j in range(0, Vs, bv)]
-        rows = [jnp.concatenate([jnp.dot(o[b], t) for t in tiles], axis=-1) for b in range(B)]
-        logits = jnp.stack(rows)  # [B, K1, Vs]
+        logits = lm_head_logits(o, w, block_v=bv)  # [B, K1, Vs]
         shard = jax.lax.axis_index(MODEL_AXIS)
         ids = shard * Vs + jnp.arange(Vs)[None, None, :]
         logits = jnp.where(ids >= v_true, -1e30, logits)
@@ -234,7 +226,7 @@ def _build_launch(
         else (P(None, None), P(None, None), P(None, None))
     )
     return jax.jit(
-        shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+        jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
     )
 
 

@@ -304,7 +304,7 @@ def composed_logits(q, k_pages, v_pages, w, tables, lengths, *, impl, block_v, q
         impl=impl,
         quant=quant,
     )
-    o = o.reshape(B, K1, H * hd).astype(jnp.float32)
+    o = o.reshape(B, K1, H, hd).astype(jnp.float32)
     V = w.shape[1]
     bv = min(block_v, V)
     Vp = -(-V // bv) * bv

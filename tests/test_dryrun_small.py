@@ -25,7 +25,8 @@ SCRIPT = textwrap.dedent(
     from repro.sharding.partition import Partitioner
     from repro.launch.dryrun import collective_census, _as_cost_dict
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_test_mesh
+    mesh = make_test_mesh(2, 4)
     cfg = get_config("granite-3-2b", reduced=True)
     part = Partitioner(mesh)
     params_spec = jax.eval_shape(lambda: zoo.init(jax.random.PRNGKey(0), cfg))
@@ -39,7 +40,7 @@ SCRIPT = textwrap.dedent(
     from jax.sharding import PartitionSpec as P
     state_sh = TrainState(params_sh, opt_sh, NamedSharding(mesh, P()))
     step = build_train_step(cfg, opt)
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jax.jit(step, in_shardings=(state_sh, batch_sh), out_shardings=(state_sh, None)).lower(state_spec, batch).compile()
         cost = _as_cost_dict(compiled.cost_analysis())
         mem = compiled.memory_analysis()

@@ -144,7 +144,7 @@ def test_pod_scale_divisibility_audit():
 
 @requires_mesh
 def test_constrain_respects_ambient_mesh_and_divisibility():
-    """shardctx.constrain: identity when un-meshed; under `with mesh:` it
+    """shardctx.constrain: identity when un-meshed; under `jax.set_mesh` it
     constrains only the dims whose axes exist AND divide, silently dropping
     the rest — the degradation contract model code relies on."""
     from repro.sharding.shardctx import ambient_mesh, axis_size, constrain
@@ -158,7 +158,7 @@ def test_constrain_respects_ambient_mesh_and_divisibility():
     assert axis_size(mesh, "model") == 2
     assert axis_size(mesh, ("data", "model")) == 4
 
-    with mesh:
+    with jax.set_mesh(mesh):
         assert ambient_mesh() is not None
         # Both dims divide → constrained, values untouched.
         y = jax.jit(lambda a: constrain(a, ("data", "model")))(x)

@@ -190,6 +190,58 @@ def test_backend_rejects_unfused():
         ShardedSpecVerifyBackend(shards=2, fused=False, lm_head=np.ones((4, 8), np.float32))
 
 
+@pytest.mark.parametrize("impl", ["pallas", "interpret"])
+def test_backend_refuses_impl_it_would_ignore(impl):
+    """The sharded launch is jax.numpy: a Pallas impl is refused, not ignored."""
+    from repro.runtime import ShardedSpecVerifyBackend
+
+    with pytest.raises(ValueError, match="impl must be 'ref'"):
+        ShardedSpecVerifyBackend(shards=1, lm_head=np.ones((4, 8), np.float32), impl=impl)
+
+
+def test_spec_backend_refuses_unknown_impl():
+    from repro.runtime import SpecVerifyBackend
+
+    with pytest.raises(ValueError, match="impl must be one of"):
+        SpecVerifyBackend(lambda s, t: None, impl="tpu")
+
+
+def _launcher():
+    import importlib.util
+    import pathlib
+
+    path = pathlib.Path(__file__).resolve().parents[1] / "launch" / "serve.py"
+    spec = importlib.util.spec_from_file_location("serve_under_test", path)
+    serve = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(serve)
+    return serve
+
+
+@pytest.mark.parametrize("shards", [1, 2])
+def test_launcher_spec_backend_takes_registry_geometry(monkeypatch, shards):
+    """``serve.py --backend spec`` sizes pool and LM head from ``--arch``, runs
+    the fused backend with the named impl at one shard, the sharded one above."""
+    from repro import configs
+    from repro.runtime import ShardedSpecVerifyBackend
+
+    real = configs.get_config
+    monkeypatch.setattr(configs, "get_config", lambda name: real(name, reduced=True))
+    cfg = real("granite-3-2b", reduced=True)
+    serve = _launcher()
+    argv = ["--listen", "127.0.0.1:0", "--backend", "spec", "--shards", str(shards)]
+    backend, kw = serve._make_backend(serve.build_parser().parse_args(argv + ["--impl", "ref"]))
+    pool = kw["kv_pool"]
+    assert (pool.n_layers, pool.n_kv_heads, pool.head_dim, pool.block_size, pool.num_blocks) == (
+        cfg.n_layers, cfg.n_kv_heads, cfg.head_dim, serve.PAGE_SIZE, serve.KV_BLOCKS,
+    )
+    assert backend.lm_head.shape == (cfg.n_heads * cfg.head_dim, cfg.vocab_size)
+    assert backend.fused and backend.impl == "ref" and backend.block_v == 512
+    assert isinstance(backend, ShardedSpecVerifyBackend) == (shards > 1)
+    if shards > 1:  # the default --impl pallas is refused, not silently swapped
+        with pytest.raises(ValueError, match="impl must be 'ref'"):
+            serve._make_backend(serve.build_parser().parse_args(argv))
+
+
 @requires_mesh
 def test_backend_rollback_recycle_matches_unsharded():
     """Rollback frees a page, a foreign session dirties it, the session

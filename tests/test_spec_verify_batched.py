@@ -134,11 +134,11 @@ def test_batched_all_accepted_round(impl):
 def test_batched_rejects_bad_inputs():
     lg = np.zeros((4, 64), np.float32)
     with pytest.raises(ValueError):
-        spec_verify_batched([], [])
+        spec_verify_batched([], [], impl="ref")
     with pytest.raises(ValueError):
-        spec_verify_batched([lg], [[1, 2]])  # K_i mismatch: 3+1 rows needed
+        spec_verify_batched([lg], [[1, 2]], impl="ref")  # K_i mismatch: 3+1 rows needed
     with pytest.raises(ValueError):
-        spec_verify_batched([lg, np.zeros((4, 128), np.float32)], [[1, 2, 3], [1, 2, 3]])
+        spec_verify_batched([lg, np.zeros((4, 128), np.float32)], [[1, 2, 3], [1, 2, 3]], impl="ref")
 
 
 def test_spec_verify_backend_no_cross_session_leakage():
@@ -203,7 +203,7 @@ def test_batched_paged_target_forward_parity(impl):
         assert paged[i][0] == plain[i][0] and paged[i][1] == plain[i][1]
         np.testing.assert_allclose(paged[i][2], plain[i][2], atol=1e-4)
     with pytest.raises(ValueError):
-        spec_verify_batched(logits_seq, tokens_seq, batched_logits_fn=batched_logits_fn)
+        spec_verify_batched(logits_seq, tokens_seq, batched_logits_fn=batched_logits_fn, impl="ref")
 
 
 def test_spec_verify_backend_paged_batched_forward():
@@ -297,6 +297,7 @@ def test_spec_verify_backend_paged_tree_forward():
         kv_pool=pool,
         batched_logits_fn=lambda t, n, b: np.zeros((t.shape[0], t.shape[1] + 1, V), np.float32),
         batched_tree_logits_fn=batched_tree_logits_fn,
+        impl="ref",
     )
     got = backend.verify_tree_batch([(0, tokens, [0.9] * 3, parents)])
     from repro.kernels.spec_verify import spec_verify_tree_batched
@@ -307,6 +308,7 @@ def test_spec_verify_backend_paged_tree_forward():
     chain_only = SpecVerifyBackend(
         kv_pool=pool,
         batched_logits_fn=lambda t, n, b: np.zeros((t.shape[0], t.shape[1] + 1, V), np.float32),
+        impl="ref",
     )
     with pytest.raises(ValueError, match="tree requests need"):
         chain_only.verify_tree_batch([(0, tokens, [0.9] * 3, parents)])
@@ -341,7 +343,7 @@ def test_fused_backend_one_launch_matches_composition():
         o = paged_decode_attention(
             q.reshape(K1, *q.shape[2:]), pool.k_pages[0], pool.v_pages[0],
             jnp.repeat(tab, K1, axis=0), lengths.reshape(-1), impl="ref",
-        ).reshape(1, K1, -1).astype(jnp.float32)
+        ).reshape(1, K1, *q.shape[2:]).astype(jnp.float32)
         logits = fused_target_logits(o, jnp.asarray(w), block_v=256, v_true=V)
         na, corr, _ = spec_verify(
             logits, jnp.asarray([toks], jnp.int32), jnp.asarray([len(toks)], jnp.int32),
@@ -437,7 +439,7 @@ def test_fused_backend_reused_session_id_refills_from_scratch():
 
     backend = SpecVerifyBackend(
         fused=True, kv_pool=pool, kv_fn=kv_fn, lm_head=np.ones((H * hd, V), np.float32),
-        query_fn=lambda s, t: np.zeros((len(t) + 1, H, hd), np.float32),
+        query_fn=lambda s, t: np.zeros((len(t) + 1, H, hd), np.float32), impl="ref",
     )
     pool.create(7)
     pool.append(7, 8)

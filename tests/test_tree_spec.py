@@ -180,11 +180,11 @@ def test_tree_chain_equivalence_with_chain_verifier():
 def test_tree_batched_rejects_bad_topology():
     lg = np.zeros((3, 64), np.float32)
     with pytest.raises(ValueError):
-        spec_verify_tree_batched([lg], [[1, 2]], [[0, 0]])  # parents[0] must be -1
+        spec_verify_tree_batched([lg], [[1, 2]], [[0, 0]], impl="ref")  # parents[0] must be -1
     with pytest.raises(ValueError):
-        spec_verify_tree_batched([lg], [[1, 2]], [[-1, 5]])  # forward reference
+        spec_verify_tree_batched([lg], [[1, 2]], [[-1, 5]], impl="ref")  # forward reference
     with pytest.raises(ValueError):
-        spec_verify_tree_batched([lg], [[1, 2]], [[-1]])  # length mismatch
+        spec_verify_tree_batched([lg], [[1, 2]], [[-1]], impl="ref")  # length mismatch
 
 
 # --------------------------------------------------------------------------- #

@@ -367,6 +367,7 @@ def spec_verify_fused_pallas(
         out_shape=_chain_outputs(B, K1),
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="_fused_verify_kernel",  # names the compiled op, and so the profiler's event
     )(
         block_tables.astype(jnp.int32),
         lengths.astype(jnp.int32),
@@ -491,6 +492,7 @@ def spec_verify_tree_pallas(
         ],
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="_tree_verify_kernel",
     )(
         n_nodes.astype(jnp.int32),
         target_logits,
@@ -539,5 +541,6 @@ def spec_verify_pallas(
         out_shape=_chain_outputs(B, K1),
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="_verify_kernel",
     )(n_drafted.astype(jnp.int32), target_logits, _token_column(draft_tokens, K1))
     return _chain_result(res, logp, K1 - 1)

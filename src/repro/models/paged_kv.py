@@ -82,6 +82,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import NULL_TRACER
+
 __all__ = ["BlockPoolExhausted", "BlockTable", "PagedKVPool"]
 
 
@@ -161,11 +163,17 @@ class PagedKVPool:
         self.tables: Dict[int, BlockTable] = {}
         self._clock = 0
         self._resident = 0  # sessions holding >=1 page, maintained incrementally
-        self.stats = {"allocs": 0, "frees": 0, "cow_copies": 0, "evictions": 0}
+        # ``page_writes`` counts functional updates of a page buffer (k, v,
+        # and the int8 planes): each copies the whole multi-layer buffer
+        # unless XLA updates it in place.
+        self.stats = {"allocs": 0, "frees": 0, "cow_copies": 0, "evictions": 0, "page_writes": 0}
         # Optional repro.obs.metrics.MetricRegistry: op counts are mirrored
         # into ``kv_<op>`` counters as they happen (stats stays the source
         # of truth; the mirror feeds the telemetry endpoint).
         self.metrics = metrics
+        # repro.obs tracer for the ``kv.fill`` span; a CloudVerifier hands
+        # its own to a pool that has none.
+        self.tracer = NULL_TRACER
         # Host seconds spent in metadata mutations (append/rollback/fork/
         # reserve/evict) — the pool's entire latency cost on the serving
         # path, so benchmarks can bound the TPT impact of paging.
@@ -266,10 +274,10 @@ class PagedKVPool:
             return False  # no partial tail page to write into
         return int(self.refcounts[t.blocks[-1]]) > 1
 
-    def _count(self, op: str) -> None:
-        self.stats[op] += 1
+    def _count(self, op: str, n: int = 1) -> None:
+        self.stats[op] += n
         if self.metrics is not None:
-            self.metrics.counter(f"kv_{op}", "Paged-KV pool page operations").inc()
+            self.metrics.counter(f"kv_{op}", "Paged-KV pool page operations").inc(n)
 
     def _alloc_page(self) -> int:
         if not self._free:
@@ -482,6 +490,7 @@ class PagedKVPool:
                 self.k_zero = self.k_zero.at[:, dst].set(self.k_zero[:, src])
                 self.v_scale = self.v_scale.at[:, dst].set(self.v_scale[:, src])
                 self.v_zero = self.v_zero.at[:, dst].set(self.v_zero[:, src])
+            self._count("page_writes", 6 if self.quantize == "int8" else 2)
 
     @staticmethod
     def quantize_kv(x: jax.Array):
@@ -551,8 +560,13 @@ class PagedKVPool:
         fill shared slots.
 
         Advances the session's materialized watermark (``filled``) when the
-        write extends the contiguous materialized prefix.
+        write extends the contiguous materialized prefix.  Traced as
+        ``kv.fill``.
         """
+        with self.tracer.span("kv.fill"):
+            self._fill(session, start, k_new, v_new)
+
+    def _fill(self, session: int, start: int, k_new: jax.Array, v_new: jax.Array) -> None:
         if self.k_pages is None:
             raise RuntimeError("pool was built without tensor storage (n_layers=0)")
         k_new, v_new = self._check_write_dtype(k_new, v_new)
@@ -590,6 +604,7 @@ class PagedKVPool:
                 ):
                     cut = jax.lax.dynamic_slice_in_dim(new, written, take, axis=1)
                     setattr(self, pages, getattr(self, pages).at[:, page, sl].set(cut))
+            self._count("page_writes", 6 if self.quantize == "int8" else 2)
             written += take
         if start <= t.filled:  # gap-free writes extend the materialized prefix
             t.filled = max(t.filled, start + T)

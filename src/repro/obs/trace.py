@@ -19,6 +19,12 @@ shrink.
 Instrumentation sites hold a tracer that defaults to the module-level
 :data:`NULL_TRACER`, whose ``span`` context manager never reads the clock —
 tracing disabled costs one attribute lookup and a no-op ``with``.
+
+An enabled tracer's context spans (:meth:`Tracer.span`, not the post-hoc
+:meth:`Tracer.add`) record their ``parent`` (the span open on the same
+thread when it started) and take the ``dispatch`` and ``verifier``
+attributes of their parent when they set none, so every span a verifier's
+dispatch causes carries that dispatch's number.
 """
 
 from __future__ import annotations
@@ -47,6 +53,9 @@ STAGES: Tuple[str, ...] = ("draft", "upload", "nav_queue", "verify", "commit", "
 #: (``migrate``/``frame`` are control-plane, not round stages).
 ROUND_STAGES: Tuple[str, ...] = ("draft", "upload", "nav_queue", "verify", "commit")
 
+#: Attributes a context span takes from its parent when it does not set them.
+INHERITED: Tuple[str, ...] = ("dispatch", "verifier")
+
 
 def _default_clock():
     """The process-wide ``SYSTEM_CLOCK``, imported lazily.
@@ -65,13 +74,18 @@ class Span:
     """One finished span: half-open interval ``[t0, t1)`` plus attributes.
 
     ``attrs`` is a key-sorted tuple of (name, value) pairs so spans are
-    hashable, comparable, and render deterministically.
+    hashable, comparable, and render deterministically.  ``sid`` numbers
+    the context spans of one tracer in the order they started (1, 2, …) and
+    ``parent`` is the ``sid`` of the span open on the same thread when this
+    one started; both are 0 for spans recorded with :meth:`Tracer.add`.
     """
 
     name: str
     t0: float
     t1: float
     attrs: Tuple[Tuple[str, Any], ...] = ()
+    sid: int = 0
+    parent: int = 0
 
     @property
     def duration(self) -> float:
@@ -89,20 +103,40 @@ class Span:
 class _SpanContext:
     """Context manager produced by :meth:`Tracer.span`; records on exit."""
 
-    __slots__ = ("_tracer", "_name", "_attrs", "_t0")
+    __slots__ = ("_tracer", "_name", "_attrs", "_t0", "_sid", "_parent")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self._tracer = tracer
         self._name = name
         self._attrs = attrs
         self._t0 = 0.0
+        self._sid = 0
+        self._parent = 0
 
     def __enter__(self) -> "_SpanContext":
-        self._t0 = self._tracer.clock.monotonic()
+        tracer = self._tracer
+        stack = tracer._open_spans()
+        if stack:
+            top = stack[-1]
+            self._parent = top._sid
+            for key in INHERITED:
+                if key not in self._attrs and key in top._attrs:
+                    self._attrs[key] = top._attrs[key]
+        with tracer._lock:
+            tracer._last_sid += 1
+            self._sid = tracer._last_sid
+        stack.append(self)
+        self._t0 = tracer.clock.monotonic()
         return self
 
     def __exit__(self, *exc) -> bool:
-        self._tracer.add(self._name, self._t0, self._tracer.clock.monotonic(), **self._attrs)
+        tracer = self._tracer
+        t1 = tracer.clock.monotonic()
+        tracer._open_spans().pop()
+        tracer._record(
+            Span(self._name, float(self._t0), float(t1), tuple(sorted(self._attrs.items())),
+                 self._sid, self._parent)
+        )
         return False
 
 
@@ -110,9 +144,10 @@ class Tracer:
     """Clock-driven span recorder with bounded ring-buffer storage.
 
     Thread-safe: spans may be recorded from any actor/thread; the ring
-    buffer holds the most recent ``capacity`` finished spans.  Under
-    ``VirtualClock`` the recording order is deterministic, so exports are
-    byte-reproducible.
+    buffer holds the most recent ``capacity`` finished spans, and
+    ``dropped`` counts the older ones it let go, so a trace whose ring
+    overflowed cannot pass for a complete one.  Under ``VirtualClock`` the
+    recording order is deterministic, so exports are byte-reproducible.
     """
 
     enabled = True
@@ -121,6 +156,22 @@ class Tracer:
         self.clock = clock if clock is not None else _default_clock()
         self._spans: Deque[Span] = deque(maxlen=int(capacity))
         self._lock = threading.Lock()
+        self._local = threading.local()
+        self._last_sid = 0
+        self.dropped = 0
+
+    def _open_spans(self) -> List[_SpanContext]:
+        """This thread's stack of open context spans, innermost last."""
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def _record(self, span: Span) -> None:
+        with self._lock:
+            if len(self._spans) == self._spans.maxlen:
+                self.dropped += 1
+            self._spans.append(span)
 
     # -------------------------------------------------------------- record --
     def span(self, name: str, **attrs: Any) -> _SpanContext:
@@ -128,10 +179,11 @@ class Tracer:
         return _SpanContext(self, name, attrs)
 
     def add(self, name: str, t0: float, t1: float, **attrs: Any) -> None:
-        """Record an already-timed span (for queue waits measured from stamps)."""
-        span = Span(name, float(t0), float(t1), tuple(sorted(attrs.items())))
-        with self._lock:
-            self._spans.append(span)
+        """Record an already-timed span (for queue waits measured from stamps).
+
+        It has no parent and takes no attributes from an open span.
+        """
+        self._record(Span(name, float(t0), float(t1), tuple(sorted(attrs.items()))))
 
     # --------------------------------------------------------------- query --
     def spans(self) -> List[Span]:
@@ -140,9 +192,10 @@ class Tracer:
             return list(self._spans)
 
     def clear(self) -> None:
-        """Drop every recorded span."""
+        """Drop every recorded span (and the count of those the ring dropped)."""
         with self._lock:
             self._spans.clear()
+            self.dropped = 0
 
     def __len__(self) -> int:
         with self._lock:
@@ -155,13 +208,17 @@ class Tracer:
         Events are complete (``ph="X"``) spans with microsecond timestamps;
         ``pid`` is the span's ``session`` attribute (0 when absent) and
         ``tid`` the stage's fixed track index, so one session renders as one
-        process with a lane per stage.  Keys are sorted and floats rounded
-        to the microsecond domain's 3 decimals — two identical runs produce
+        process with a lane per stage.  A context span's args also carry its
+        ``sid`` and ``parent``, and the document carries ``dropped_spans``
+        when the ring overflowed.  Keys are sorted and floats rounded to the
+        microsecond domain's 3 decimals — two identical runs produce
         byte-identical output.
         """
         events = []
         for s in self.spans():
             args = {k: v for k, v in s.attrs}
+            if s.sid:
+                args.update(sid=s.sid, parent=s.parent)
             tid = STAGES.index(s.name) if s.name in STAGES else len(STAGES)
             events.append(
                 dict(
@@ -175,11 +232,10 @@ class Tracer:
                 )
             )
         events.sort(key=lambda e: (e["ts"], e["pid"], e["tid"], e["name"]))
-        return json.dumps(
-            {"displayTimeUnit": "ms", "traceEvents": events},
-            sort_keys=True,
-            separators=(",", ":"),
-        )
+        doc: Dict[str, Any] = {"displayTimeUnit": "ms", "traceEvents": events}
+        if self.dropped:
+            doc["dropped_spans"] = self.dropped
+        return json.dumps(doc, sort_keys=True, separators=(",", ":"))
 
 
 class _NullSpanContext:
@@ -208,6 +264,7 @@ class NullTracer(Tracer):
         self.clock = None
         self._spans = deque(maxlen=1)
         self._lock = threading.Lock()
+        self.dropped = 0
 
     def span(self, name: str, **attrs: Any) -> _NullSpanContext:  # type: ignore[override]
         """A shared do-nothing context manager."""

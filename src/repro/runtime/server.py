@@ -251,6 +251,10 @@ class SpecVerifyBackend(VerifyBackend):
     pages (``PagedKVPool.fill`` would CoW-copy them, forfeiting the
     sharing).  An int8 pool (``quantize='int8'``) is picked up
     automatically — the launch dequantizes pages in-kernel.
+
+    ``tracer`` (a ``repro.obs`` tracer, ``NULL_TRACER`` unless a
+    ``CloudVerifier`` hands down its own) times one ``query`` per session
+    in the fused path's input assembly.
     """
 
     def __init__(
@@ -288,6 +292,7 @@ class SpecVerifyBackend(VerifyBackend):
         # every dispatch.
         self.lm_head = None if lm_head is None else jnp.asarray(lm_head, jnp.float32)
         self.kv_fn = kv_fn if kv_fn is not None else self._default_kv_fn
+        self.tracer = NULL_TRACER
 
     def _tables(self, sessions: Sequence[int]):
         if self.kv_pool is None:
@@ -379,8 +384,12 @@ class SpecVerifyBackend(VerifyBackend):
         quant = None
         if pool.quantize == "int8":
             quant = (pool.k_scale[0], pool.k_zero[0], pool.v_scale[0], pool.v_zero[0])
+        q_seq = []
+        for s, t, _ in requests:
+            with self.tracer.span("query"):
+                q_seq.append(np.asarray(self.query_fn(s, t), np.float32))
         return dict(
-            q_seq=[np.asarray(self.query_fn(s, t), np.float32) for (s, t, _) in requests],
+            q_seq=q_seq,
             tokens_seq=[t for (_, t, _) in requests],
             block_tables_seq=self._tables(sessions),
             base_lengths=[max(pool.length(s) - len(t), 0) for (s, t, _) in requests],
@@ -591,6 +600,11 @@ class CloudVerifier:
         # no-ops by default — tracing/metrics are strictly opt-in so the
         # serving hot path pays one attribute check when disabled.
         self.tracer = tracer if tracer is not None else NULL_TRACER
+        # The backend and the pool record their spans into the same tracer
+        # unless they were given one of their own.
+        for part in (backend, getattr(backend, "kv_pool", None), kv_pool):
+            if getattr(part, "tracer", None) is NULL_TRACER:
+                setattr(part, "tracer", self.tracer)
         self.metrics = metrics
         self.verifier_id = int(verifier_id)
         self.batch_window = batch_window
@@ -637,6 +651,10 @@ class CloudVerifier:
             # Clock seconds the backend spent inside verify calls — the busy
             # time the energy model charges at (p_active − p_idle) watts.
             "verify_busy_time": 0.0,
+            # Page buffer writes the pool made during dispatches, from
+            # admission (copy-on-write copies) through the verify call
+            # (``PagedKVPool.stats["page_writes"]``; 0 without tensor pages).
+            "kv_page_writes": 0,
         }
         # The monitor here is an accumulator for the whole serving run, not
         # the paper's 100-observation estimator — size the window accordingly
@@ -647,6 +665,7 @@ class CloudVerifier:
         self._lock = threading.Lock()
         self._work = self.clock.condition(self._lock)
         self._queue: Deque[_VerifyRequest] = deque()
+        self._dispatches = 0  # dispatches made; the traced ``dispatch`` number
 
     def attach(self, session: int, uplink: Transport, downlink: Transport) -> None:
         """Register a session and start its receive loop.
@@ -1066,33 +1085,63 @@ class CloudVerifier:
         return drained
 
     def _dispatch_loop(self) -> None:
+        tracer, vid = self.tracer, self.verifier_id
         while not self._stop.is_set():
-            with self._work:
-                while not self._queue and not self._stop.is_set():
-                    self._work.wait(timeout=0.25)
-                if self._stop.is_set():
-                    return
+            # The number this iteration's dispatch takes if it admits work;
+            # every span it records (and each span under them) carries it,
+            # with the verifier's id.
+            n = self._dispatches + 1
+            with tracer.span("dispatch.wait", verifier=vid, dispatch=n):
+                with self._work:
+                    while not self._queue and not self._stop.is_set():
+                        self._work.wait(timeout=0.25)
+                    if self._stop.is_set():
+                        return
             if self.batch_window > 0:
                 with self._lock:
                     full = len(self._queue) >= self.max_batch
                 if not full:  # a full batch needs no coalescing delay
-                    self.clock.sleep(self.batch_window)  # absorb concurrent arrivals
-            with self._lock:
-                batch, depth = self._admit()
+                    with tracer.span("dispatch.coalesce", verifier=vid, dispatch=n):
+                        self.clock.sleep(self.batch_window)  # absorb concurrent arrivals
+            writes = self._page_writes()
+            with tracer.span("dispatch.admit", verifier=vid, dispatch=n):
+                with self._lock:
+                    batch, depth = self._admit()
             if not batch:
+                self.stats["kv_page_writes"] += self._page_writes() - writes
                 # Nothing admitted but work may remain queued (all requests
                 # KV-parked): back off instead of hot-spinning until pages
                 # free up, a deadline expires, or new work arrives.
-                with self._work:
-                    if self._queue and not self._stop.is_set():
-                        self._work.wait(timeout=0.05)
+                with tracer.span("dispatch.wait", verifier=vid, dispatch=n):
+                    with self._work:
+                        if self._queue and not self._stop.is_set():
+                            self._work.wait(timeout=0.05)
                 continue
-            # Chain and tree requests share the admission queue but pad
-            # differently (draft length vs node count), so each kind gets its
-            # own backend launch within ONE dispatch round.
-            chain = [r for r in batch if r.parents is None]
-            tree = [r for r in batch if r.parents is not None]
-            results: Dict[int, tuple] = {}
+            self._dispatches = n
+            self._dispatch(batch, depth, n, writes)
+
+    def _page_writes(self) -> int:
+        """The pool's page buffer writes so far (0 without a pool)."""
+        pool = getattr(self.backend, "kv_pool", None)
+        if pool is None:
+            pool = self.kv_pool
+        return pool.stats["page_writes"] if pool is not None else 0
+
+    def _dispatch(self, batch: List[_VerifyRequest], depth: int, n: int, writes: int) -> None:
+        """Verify one admitted batch, commit its rounds and send the results.
+
+        ``writes`` is the pool's page write count before admission.
+        """
+        tracer = self.tracer
+        # Chain and tree requests share the admission queue but pad
+        # differently (draft length vs node count), so each kind gets its
+        # own backend launch within ONE dispatch round.
+        chain = [r for r in batch if r.parents is None]
+        tree = [r for r in batch if r.parents is not None]
+        results: Dict[int, tuple] = {}
+        with tracer.span(
+            "verify", verifier=self.verifier_id, batch=len(batch), depth=depth, dispatch=n
+        ):
             verify_t0 = self.clock.monotonic()
             if chain:
                 if self.backend.positional:
@@ -1114,77 +1163,79 @@ class CloudVerifier:
                 for r, (n_acc, corr, path) in zip(tree, out):
                     results[id(r)] = (n_acc, corr, path)
             verify_t1 = self.clock.monotonic()
-            self.stats["verify_busy_time"] += verify_t1 - verify_t0
-            self.stats["nav_calls"] += len(batch)
-            self.stats["batched_calls"] += 1
-            self.monitor.observe_verifier_batch(len(batch), depth)
-            if self.tracer.enabled:
-                # One verify span per dispatch; one nav_queue span per
-                # admitted request covering enqueue → backend start.
-                self.tracer.add(
-                    "verify", verify_t0, verify_t1,
-                    verifier=self.verifier_id, batch=len(batch), depth=depth,
-                )
-                for req in batch:
-                    self.tracer.add(
-                        "nav_queue", req.t_enqueue, verify_t0,
-                        session=req.session, round=req.msg.round,
-                        verifier=self.verifier_id,
-                    )
-            if self.metrics is not None:
-                self.metrics.counter(
-                    "verifier_nav_calls", "NAV requests verified"
-                ).inc(len(batch), verifier=self.verifier_id)
-                self.metrics.histogram(
-                    "verifier_batch_size", "Admitted NAV batch sizes"
-                ).observe(len(batch), verifier=self.verifier_id)
-                self.metrics.gauge(
-                    "verifier_queue_depth", "Queue depth at admission"
-                ).set(depth, verifier=self.verifier_id)
+        self.stats["verify_busy_time"] += verify_t1 - verify_t0
+        self.stats["kv_page_writes"] += self._page_writes() - writes
+        self.stats["nav_calls"] += len(batch)
+        self.stats["batched_calls"] += 1
+        self.monitor.observe_verifier_batch(len(batch), depth)
+        with tracer.span("dispatch.reply", verifier=self.verifier_id, dispatch=n):
+            self._reply(batch, depth, n, results, verify_t0)
+
+    def _reply(self, batch, depth: int, n: int, results: Dict[int, tuple], verify_t0: float) -> None:
+        """Commit or roll back each verified round and send its ``NavResult``."""
+        if self.tracer.enabled:
+            # One nav_queue span per admitted request covering enqueue →
+            # backend start.
             for req in batch:
-                n_acc, corr, path = results[id(req)]
-                self.stats["tokens_verified"] += len(req.tokens)
-                self.stats["accepted_tokens"] += n_acc
-                sess = self.sessions.get(req.session)
-                if sess is not None:
-                    sess.served += 1
-                    # Commit accepted + correction tokens; with a pool, also
-                    # release every page wholly past the new prefix (rejection
-                    # rollback is a page free, not a buffer copy).  A round
-                    # verified across a re-attach reconciliation (stale epoch)
-                    # was abandoned by the edge: committing it would inflate
-                    # the reconciled position, so it is dropped here (the
-                    # client discards its stale result by seq anyway).
-                    with self._lock:
-                        if req.epoch == sess.epoch:
-                            sess.kv_committed += n_acc + 1
-                            if (
-                                req.kv_secured
-                                and self.kv_pool is not None
-                                and req.session in self.kv_pool.tables
-                            ):
-                                self.kv_pool.rollback(
-                                    req.session,
-                                    min(sess.kv_committed, self.kv_pool.length(req.session)),
-                                )
-                link = self.links.get(req.session)
-                if link is None:
-                    continue
-                _, dn = link
-                dn.send(
-                    NavResult(
-                        session=req.session,
-                        seq=req.msg.seq,
-                        n_accepted=n_acc,
-                        correction=corr,
-                        n_drafted=len(req.tokens),
-                        # Chain rounds carry no path; tree rounds carry the
-                        # accepted packed node indices (possibly empty).
-                        path=tuple(path) if path is not None else None,
-                    )
+                self.tracer.add(
+                    "nav_queue", req.t_enqueue, verify_t0,
+                    session=req.session, round=req.msg.round,
+                    verifier=self.verifier_id, dispatch=n,
                 )
-            if self.kv_pool is not None:
+        if self.metrics is not None:
+            self.metrics.counter(
+                "verifier_nav_calls", "NAV requests verified"
+            ).inc(len(batch), verifier=self.verifier_id)
+            self.metrics.histogram(
+                "verifier_batch_size", "Admitted NAV batch sizes"
+            ).observe(len(batch), verifier=self.verifier_id)
+            self.metrics.gauge(
+                "verifier_queue_depth", "Queue depth at admission"
+            ).set(depth, verifier=self.verifier_id)
+        for req in batch:
+            n_acc, corr, path = results[id(req)]
+            self.stats["tokens_verified"] += len(req.tokens)
+            self.stats["accepted_tokens"] += n_acc
+            sess = self.sessions.get(req.session)
+            if sess is not None:
+                sess.served += 1
+                # Commit accepted + correction tokens; with a pool, also
+                # release every page wholly past the new prefix (rejection
+                # rollback is a page free, not a buffer copy).  A round
+                # verified across a re-attach reconciliation (stale epoch)
+                # was abandoned by the edge: committing it would inflate
+                # the reconciled position, so it is dropped here (the
+                # client discards its stale result by seq anyway).
                 with self._lock:
-                    self.monitor.observe_kv(
-                        self.kv_pool.resident_bytes(), self.kv_pool.resident_sessions
-                    )
+                    if req.epoch == sess.epoch:
+                        sess.kv_committed += n_acc + 1
+                        if (
+                            req.kv_secured
+                            and self.kv_pool is not None
+                            and req.session in self.kv_pool.tables
+                        ):
+                            self.kv_pool.rollback(
+                                req.session,
+                                min(sess.kv_committed, self.kv_pool.length(req.session)),
+                            )
+            link = self.links.get(req.session)
+            if link is None:
+                continue
+            _, dn = link
+            dn.send(
+                NavResult(
+                    session=req.session,
+                    seq=req.msg.seq,
+                    n_accepted=n_acc,
+                    correction=corr,
+                    n_drafted=len(req.tokens),
+                    # Chain rounds carry no path; tree rounds carry the
+                    # accepted packed node indices (possibly empty).
+                    path=tuple(path) if path is not None else None,
+                )
+            )
+        if self.kv_pool is not None:
+            with self._lock:
+                self.monitor.observe_kv(
+                    self.kv_pool.resident_bytes(), self.kv_pool.resident_sessions
+                )

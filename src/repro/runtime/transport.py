@@ -489,15 +489,19 @@ class SocketListener:
         while session in self._sessions:  # collision: remap to the next free id
             session += 1
         reply = handshake_reply(hello, session=session)
+        if not reply.accepted:
+            # Counted before the refusal is sent: a client that reads it
+            # then finds the rejection in ``stats``.
+            self.stats["rejected"] += 1
         try:
             conn.sendall(encode(reply))
         except OSError:
             conn.close()
-            self.stats["rejected"] += 1
+            if reply.accepted:
+                self.stats["rejected"] += 1
             return None
-        if not reply.accepted:  # version mismatch: reject and hang up
+        if not reply.accepted:  # version mismatch: hang up
             conn.close()
-            self.stats["rejected"] += 1
             return None
         self._sessions.add(session)
         self.stats["accepted"] += 1

@@ -160,6 +160,88 @@ def test_null_tracer_is_inert_and_clock_free():
     assert len(NULL_TRACER) == 0
 
 
+def test_tracer_ring_counts_the_spans_it_drops():
+    tracer = Tracer(clock=VirtualClock(), capacity=4)
+    for i in range(10):
+        tracer.add("verify", float(i), float(i) + 0.5)
+    assert tracer.dropped == 6
+    assert json.loads(tracer.export_chrome_trace())["dropped_spans"] == 6
+    tracer.clear()
+    assert tracer.dropped == 0 and "dropped_spans" not in json.loads(tracer.export_chrome_trace())
+
+
+def test_span_parents_and_dispatch_on_nested_and_cross_thread_spans():
+    """A context span's parent is the span open on its own thread; it takes
+    ``dispatch``/``verifier`` from that parent unless it sets its own."""
+    import threading
+
+    tracer = Tracer()
+    inner_open, other_done = threading.Event(), threading.Event()
+
+    def other_thread():
+        inner_open.wait(5.0)
+        with tracer.span("query", session=9):  # overlaps main's spans in time
+            pass
+        other_done.set()
+
+    t = threading.Thread(target=other_thread)
+    t.start()
+    with tracer.span("verify", verifier=2, dispatch=7):
+        with tracer.span("outer", session=1):
+            with tracer.span("kv.fill"):
+                inner_open.set()
+                other_done.wait(5.0)
+        with tracer.span("sibling", dispatch=8):
+            pass
+    t.join()
+    by = {s.name: s for s in tracer.spans()}
+    verify, outer, fill = by["verify"], by["outer"], by["kv.fill"]
+    assert verify.parent == 0 and outer.parent == verify.sid and fill.parent == outer.sid
+    assert by["sibling"].parent == verify.sid and by["sibling"].get("dispatch") == 8
+    assert outer.get("session") == 1 and fill.get("session") is None  # only dispatch/verifier pass down
+    for s in (outer, fill):
+        assert (s.get("dispatch"), s.get("verifier")) == (7, 2)
+    query = by["query"]
+    assert query.parent == 0 and query.get("dispatch") is None  # other thread: no parent
+    assert fill.t0 <= query.t0 and query.t1 <= fill.t1
+    assert len({s.sid for s in tracer.spans()}) == 5
+    tracer.add("nav_queue", 0.0, 1.0, dispatch=7)  # post-hoc: no id, no parent
+    assert (tracer.spans()[-1].sid, tracer.spans()[-1].parent) == (0, 0)
+
+
+def test_null_tracer_reads_no_clock_and_keeps_no_stack():
+    """The off path: one shared no-op context whatever the attributes, no
+    clock to read, no per-thread stack, nothing recorded."""
+    with NULL_TRACER.span("verify", dispatch=1):
+        with NULL_TRACER.span("kv.fill"):
+            pass
+    assert NULL_TRACER.span("a") is NULL_TRACER.span("b", dispatch=2)  # one shared no-op context
+    assert NULL_TRACER.clock is None and len(NULL_TRACER) == 0 and NULL_TRACER.dropped == 0
+    assert not hasattr(NULL_TRACER, "_local")
+
+
+def test_chrome_export_carries_span_ids_and_stays_deterministic():
+    def _build():
+        clock = VirtualClock()
+        t = Tracer(clock=clock)
+
+        def work():
+            with t.span("verify", dispatch=1):
+                with t.span("query"):
+                    clock.sleep(0.001)
+            t.add("nav_queue", 0.0, 0.001, session=2, dispatch=1)
+
+        clock.run(work)
+        return t.export_chrome_trace()
+
+    blob = _build()
+    assert blob == _build()
+    events = {e["name"]: e for e in json.loads(blob)["traceEvents"]}
+    assert events["verify"]["args"] == {"dispatch": 1, "parent": 0, "sid": 1}
+    assert events["query"]["args"] == {"dispatch": 1, "parent": 1, "sid": 2}
+    assert events["nav_queue"]["args"] == {"dispatch": 1}
+
+
 def test_chrome_export_is_valid_and_deterministic():
     def _build():
         t = Tracer(clock=VirtualClock())
@@ -194,6 +276,24 @@ def test_seeded_fleet_trace_export_is_byte_identical():
 def test_fleet_spans_cover_every_pipeline_stage(traced_fleet):
     names = {s.name for s in traced_fleet["tracer"].spans()}
     assert set(ROUND_STAGES) <= names, names
+
+
+def test_dispatcher_spans_share_the_dispatch_number(traced_fleet):
+    """Each dispatch's admit, verify and reply spans, and the nav_queue
+    spans of its requests, carry one (verifier, dispatch) pair."""
+    spans = traced_fleet["tracer"].spans()
+
+    def keys(name):
+        return [(s.get("verifier"), s.get("dispatch")) for s in spans if s.name == name]
+
+    verifies = keys("verify")
+    assert verifies and len(set(verifies)) == len(verifies)
+    assert set(keys("nav_queue")) == set(verifies)
+    assert set(verifies) <= set(keys("dispatch.admit")) and set(keys("dispatch.reply")) == set(verifies)
+    for vid in {v for v, _ in verifies}:  # numbered 1, 2, ... per verifier
+        assert sorted(d for v, d in verifies if v == vid) == list(range(1, 1 + sum(v == vid for v, _ in verifies)))
+    total = sum(vc.verifier.stats["batched_calls"] for vc in traced_fleet["fleet"])
+    assert len(verifies) == total
 
 
 # --------------------------------------------------------------------------- #

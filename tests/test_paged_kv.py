@@ -319,6 +319,35 @@ def test_fill_cow_diverges_shared_pages():
     np.testing.assert_array_equal(got, np.asarray(k1)[0])
 
 
+@pytest.mark.parametrize("quantize, planes", [(None, 2), ("int8", 6)])
+def test_page_writes_count_each_buffer_update(quantize, planes):
+    """A fill across a page boundary is two chunks, each one update of every
+    page buffer: (K, V), and the four int8 planes when the pool quantizes.
+    A CoW copy updates each buffer once more.  Each call is one ``kv.fill``
+    span; the registry mirror sees the same total."""
+    from repro.obs.metrics import MetricRegistry
+    from repro.obs.trace import Tracer
+
+    pool = PagedKVPool(num_blocks=8, block_size=4, n_layers=1, n_kv_heads=1, head_dim=8,
+                       quantize=quantize, metrics=MetricRegistry())
+    pool.tracer = Tracer()
+    pool.create(0)
+    pool.append(0, 6)
+    pool.fill(0, 2, _tok8(3), _tok8(3))  # slots 2-3 of page 0, slot 0 of page 1
+    assert pool.stats["page_writes"] == 2 * planes
+    assert [span.name for span in pool.tracer.spans()] == ["kv.fill"]
+    pool.fork(0, 1)
+    pool.fill(1, 5, _tok8(1), _tok8(1))  # shared tail page: CoW copy, then one chunk
+    assert pool.stats["cow_copies"] == 1
+    assert pool.stats["page_writes"] == 4 * planes
+    assert [span.name for span in pool.tracer.spans()] == ["kv.fill", "kv.fill"]
+    assert pool.metrics.counter("kv_page_writes").value() == 4 * planes
+
+
+def _tok8(n):
+    return jnp.ones((1, n, 1, 8), jnp.float32)
+
+
 # ---------------------------------------------------- write dtype boundary --
 
 

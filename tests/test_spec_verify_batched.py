@@ -571,3 +571,47 @@ def test_fused_serve_shared_prefix_materialized_once_and_stays_shared():
     assert len(prefix_pages) == 4
     assert all(int(pool.refcounts[p]) == 3 for p in prefix_pages)
     assert pool.stats["cow_copies"] == 0  # nothing ever wrote a shared page
+
+
+def test_verifier_counts_page_writes_from_admission_through_verify():
+    """With an unaligned shared prefix, each session's first admission
+    CoW-copies the shared tail page (``_kv_secure`` → ``append``): the
+    dispatcher's ``kv_page_writes`` counts those copies with the fills, so
+    it equals every pool write made after the prefix was materialized."""
+    from repro.models.paged_kv import PagedKVPool
+    from repro.runtime import SpecVerifyBackend
+    from repro.runtime.client import EdgeClient, EdgeConfig
+    from repro.runtime.server import CloudVerifier
+    from repro.runtime.simclock import VirtualClock
+    from repro.runtime.transport import Channel, ChannelConfig
+
+    H, hd, V = 2, 16, 256
+    w = np.asarray(jax.random.normal(jax.random.PRNGKey(3), (H * hd, V)) * 6, np.float32)
+
+    def query_fn(session, tokens):
+        k = jax.random.fold_in(jax.random.PRNGKey(4), session * 997 + len(tokens))
+        return np.asarray(jax.random.normal(k, (len(tokens) + 1, H, hd)), np.float32)
+
+    clock = VirtualClock()
+    pool = PagedKVPool(num_blocks=64, block_size=8, n_layers=1, n_kv_heads=H, head_dim=hd)
+    backend = SpecVerifyBackend(
+        fused=True, kv_pool=pool, query_fn=query_fn, lm_head=w, impl="ref", block_v=256
+    )
+    server = CloudVerifier(backend, kv_pool=pool, kv_shared_prefix=28, clock=clock)
+    before = pool.stats["page_writes"]  # the prefix, materialized at init
+    clients = []
+    for s in range(2):
+        up = Channel(ChannelConfig(alpha=0.02, beta=0.002), f"up{s}", clock=clock)
+        dn = Channel(ChannelConfig(alpha=0.01, beta=0.0005), f"dn{s}", clock=clock)
+        server.attach(s, up, dn)
+        clients.append(EdgeClient(s, up, dn, EdgeConfig(gamma=0.02, nav_timeout=3.0)))
+
+    def body():
+        server.start()
+        stats = [c.run(6) for c in clients]
+        server.stop()
+        return stats
+
+    clock.run(body)
+    assert pool.stats["cow_copies"] == 2  # one shared tail page per session
+    assert server.stats["kv_page_writes"] == pool.stats["page_writes"] - before > 2 * 2

@@ -73,6 +73,7 @@ Example (metadata mode; 4-token pages)::
 
 from __future__ import annotations
 
+import functools
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -89,6 +90,43 @@ __all__ = ["BlockPoolExhausted", "BlockTable", "PagedKVPool"]
 
 class BlockPoolExhausted(RuntimeError):
     """Raised when an allocation needs more physical pages than are free."""
+
+
+@functools.partial(jax.jit, static_argnames=("take",), donate_argnums=(0,))
+def _write_pages(bufs, news, pages, slot, *, take):
+    """Write ``T`` tokens into the donated page buffers, in place.
+
+    ``bufs`` is ``(k_pages, v_pages)``, or with the four int8 planes
+    ``(k_pages, v_pages, k_scale, k_zero, v_scale, v_zero)``; ``news`` holds
+    what goes into each, ``[L, T, ...]``.  Tokens ``[0, take)`` land in page
+    ``pages[0]`` from slot ``slot``; the rest fill whole pages ``pages[1:]``
+    from slot 0.  Static: ``T`` (the shape) and ``take``; the page ids and
+    ``slot`` are traced int32, so one compile serves every page and slot.
+    Each chunk is a ``dynamic_update_slice`` into a donated operand, which
+    XLA writes in place; the outputs keep the inputs' shardings.
+    """
+    bs = bufs[0].shape[2]
+    T = news[0].shape[1]
+    bounds = [0] + list(range(take, T, bs)) + [T]
+    out = list(bufs)
+    for i, (a, b) in enumerate(zip(bounds, bounds[1:])):
+        first = slot if i == 0 else 0
+        for j, new in enumerate(news):
+            chunk = jax.lax.slice_in_dim(new, a, b, axis=1)[:, None]
+            idx = (0, pages[i], first) + (0,) * (new.ndim - 2)
+            out[j] = jax.lax.dynamic_update_slice(out[j], chunk, idx)
+    return tuple(out)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _copy_pages(bufs, src, dst):
+    """Copy page ``src`` onto page ``dst`` of every donated buffer, in place."""
+    return tuple(
+        jax.lax.dynamic_update_slice_in_dim(
+            b, jax.lax.dynamic_slice_in_dim(b, src, 1, axis=1), dst, axis=1
+        )
+        for b in bufs
+    )
 
 
 @dataclass
@@ -135,6 +173,14 @@ class PagedKVPool:
         Byte-accounting override for metadata mode.  Tensor mode derives it
         from the KV geometry (k+v); metadata mode defaults to 1 so
         ``resident_bytes`` counts token slots.
+
+    Tensor-mode writes are made in place: each ``fill`` (and each CoW page
+    copy) is one jitted update that takes the page buffers donated and the
+    pool rebinds ``k_pages``/``v_pages`` (and the int8 planes) to what it
+    returns.  So an array taken from one of those attributes before a write
+    is deleted by it; read the attribute again after every ``fill``,
+    ``write`` or ``append`` (slices such as ``pool.k_pages[0]`` are copies
+    and stay valid).
     """
 
     def __init__(
@@ -163,10 +209,13 @@ class PagedKVPool:
         self.tables: Dict[int, BlockTable] = {}
         self._clock = 0
         self._resident = 0  # sessions holding >=1 page, maintained incrementally
-        # ``page_writes`` counts functional updates of a page buffer (k, v,
-        # and the int8 planes): each copies the whole multi-layer buffer
-        # unless XLA updates it in place.
-        self.stats = {"allocs": 0, "frees": 0, "cow_copies": 0, "evictions": 0, "page_writes": 0}
+        # ``page_writes`` counts updates of one page buffer (k, v, and the
+        # int8 planes) by one chunk or CoW copy; ``write_launches`` counts
+        # the jitted updates that make them, one per ``fill`` and one per
+        # CoW copy.  Each launch donates the buffers and XLA writes them in
+        # place: an array read from ``k_pages`` etc. before it is deleted.
+        self.stats = {"allocs": 0, "frees": 0, "cow_copies": 0, "evictions": 0,
+                      "page_writes": 0, "write_launches": 0}
         # Optional repro.obs.metrics.MetricRegistry: op counts are mirrored
         # into ``kv_<op>`` counters as they happen (stats stays the source
         # of truth; the mirror feeds the telemetry endpoint).
@@ -481,16 +530,24 @@ class PagedKVPool:
         return sid
 
     # ------------------------------------------------------------- tensors --
+    _BUFFERS = ("k_pages", "v_pages", "k_scale", "k_zero", "v_scale", "v_zero")
+
+    def _buffer_names(self):
+        """The page buffers one write updates: k, v, and the int8 planes."""
+        return self._BUFFERS if self.quantize == "int8" else self._BUFFERS[:2]
+
+    def _launch(self, update, *args, **static) -> None:
+        """Run one jitted, donated update over the page buffers and rebind them."""
+        names = self._buffer_names()
+        out = update(tuple(getattr(self, n) for n in names), *args, **static)
+        for name, buf in zip(names, out):
+            setattr(self, name, buf)
+        self._count("write_launches")
+
     def _copy_page(self, src: int, dst: int) -> None:
         if self.k_pages is not None:
-            self.k_pages = self.k_pages.at[:, dst].set(self.k_pages[:, src])
-            self.v_pages = self.v_pages.at[:, dst].set(self.v_pages[:, src])
-            if self.quantize == "int8":
-                self.k_scale = self.k_scale.at[:, dst].set(self.k_scale[:, src])
-                self.k_zero = self.k_zero.at[:, dst].set(self.k_zero[:, src])
-                self.v_scale = self.v_scale.at[:, dst].set(self.v_scale[:, src])
-                self.v_zero = self.v_zero.at[:, dst].set(self.v_zero[:, src])
-            self._count("page_writes", 6 if self.quantize == "int8" else 2)
+            self._launch(_copy_pages, np.int32(src), np.int32(dst))
+            self._count("page_writes", len(self._buffer_names()))
 
     @staticmethod
     def quantize_kv(x: jax.Array):
@@ -569,20 +626,24 @@ class PagedKVPool:
     def _fill(self, session: int, start: int, k_new: jax.Array, v_new: jax.Array) -> None:
         if self.k_pages is None:
             raise RuntimeError("pool was built without tensor storage (n_layers=0)")
-        k_new, v_new = self._check_write_dtype(k_new, v_new)
+        news = self._check_write_dtype(k_new, v_new)
         if self.quantize == "int8":
-            k_new, k_sc, k_zp = self.quantize_kv(k_new)
-            v_new, v_sc, v_zp = self.quantize_kv(v_new)
+            # Eagerly, op by op: compiled into the update, XLA may rewrite the
+            # ``/ 255`` and round ``scale`` one ulp differently.
+            (kq, ks, kz), (vq, vs, vz) = map(self.quantize_kv, news)
+            news = (kq, vq, ks, kz, vs, vz)
         t = self._table(session)
-        T = k_new.shape[1]
+        T = int(news[0].shape[1])
         if start < 0 or start + T > t.length:
             raise ValueError(
                 f"fill [{start}, {start + T}) outside the session's {t.length} slots"
             )
-        written = 0
-        while written < T:
-            pos = start + written
-            bi = pos // self.block_size
+        if T == 0:
+            return
+        # Host pass: the page of each chunk, CoW-diverging shared ones first.
+        bs = self.block_size
+        pages = []
+        for bi in range(start // bs, (start + T - 1) // bs + 1):
             page = t.blocks[bi]
             if not t.reserved and int(self.refcounts[page]) > 1:
                 new = self._alloc_page()
@@ -591,21 +652,14 @@ class PagedKVPool:
                 t.blocks[bi] = new
                 self._decref(page)
                 page = new
-            slot = pos % self.block_size
-            take = min(self.block_size - slot, T - written)
-            ksl = jax.lax.dynamic_slice_in_dim(k_new, written, take, axis=1)
-            vsl = jax.lax.dynamic_slice_in_dim(v_new, written, take, axis=1)
-            self.k_pages = self.k_pages.at[:, page, slot : slot + take].set(ksl)
-            self.v_pages = self.v_pages.at[:, page, slot : slot + take].set(vsl)
-            if self.quantize == "int8":
-                sl = slice(slot, slot + take)
-                for pages, new in (
-                    ("k_scale", k_sc), ("k_zero", k_zp), ("v_scale", v_sc), ("v_zero", v_zp),
-                ):
-                    cut = jax.lax.dynamic_slice_in_dim(new, written, take, axis=1)
-                    setattr(self, pages, getattr(self, pages).at[:, page, sl].set(cut))
-            self._count("page_writes", 6 if self.quantize == "int8" else 2)
-            written += take
+            pages.append(page)
+            self._count("page_writes", len(self._buffer_names()))
+        # Device pass: every chunk in one in-place update.
+        slot = start % bs
+        self._launch(
+            _write_pages, news, np.asarray(pages, np.int32), np.int32(slot),
+            take=min(bs - slot, T),
+        )
         if start <= t.filled:  # gap-free writes extend the materialized prefix
             t.filled = max(t.filled, start + T)
 
@@ -658,15 +712,9 @@ class PagedKVPool:
         pages_spec, planes_spec = self.shard_spec(shards, axis=axis)
         if self.k_pages is None:
             return pages_spec
-        pages_sh = NamedSharding(mesh, pages_spec)
-        planes_sh = NamedSharding(mesh, planes_spec)
-        self.k_pages = jax.device_put(self.k_pages, pages_sh)
-        self.v_pages = jax.device_put(self.v_pages, pages_sh)
-        if self.quantize == "int8":
-            self.k_scale = jax.device_put(self.k_scale, planes_sh)
-            self.k_zero = jax.device_put(self.k_zero, planes_sh)
-            self.v_scale = jax.device_put(self.v_scale, planes_sh)
-            self.v_zero = jax.device_put(self.v_zero, planes_sh)
+        for name in self._buffer_names():
+            spec = pages_spec if name.endswith("_pages") else planes_spec
+            setattr(self, name, jax.device_put(getattr(self, name), NamedSharding(mesh, spec)))
         return pages_spec
 
     def resident_bytes_per_shard(self, shards: int) -> int:
@@ -689,8 +737,7 @@ class PagedKVPool:
         invariant that pins the byte accounting to the real buffer
         footprint (``tests/test_paged_kv.py``).  Metadata mode returns 0.
         """
-        bufs = (self.k_pages, self.v_pages, self.k_scale, self.k_zero,
-                self.v_scale, self.v_zero)
+        bufs = (getattr(self, name) for name in self._buffer_names())
         return sum(b.nbytes for b in bufs if b is not None)
 
     # ----------------------------------------------------------- reporting --

@@ -393,6 +393,7 @@ class SpecVerifyBackend(VerifyBackend):
             tokens_seq=[t for (_, t, _) in requests],
             block_tables_seq=self._tables(sessions),
             base_lengths=[max(pool.length(s) - len(t), 0) for (s, t, _) in requests],
+            # Read after the fills: each donates the buffers it writes.
             k_pages=pool.k_pages[0],
             v_pages=pool.v_pages[0],
             w=self.lm_head,

@@ -348,6 +348,131 @@ def _tok8(n):
     return jnp.ones((1, n, 1, 8), jnp.float32)
 
 
+# ------------------------------------------------------- in-place writes --
+
+
+def _random_pool(quantize, num_blocks=6, block_size=16):
+    """A tensor pool whose every buffer (sentinel aside) holds random values."""
+    pool = PagedKVPool(num_blocks=num_blocks, block_size=block_size, n_layers=2,
+                       n_kv_heads=2, head_dim=8, quantize=quantize)
+    rng = np.random.default_rng(5)
+    for name in pool._buffer_names():
+        buf = getattr(pool, name)
+        if buf.dtype == jnp.int8:
+            vals = rng.integers(-128, 128, size=buf.shape)
+        else:
+            vals = rng.normal(size=buf.shape)
+        vals[:, pool.sentinel_page] = 0
+        setattr(pool, name, jnp.asarray(vals, buf.dtype))
+    return pool
+
+
+def _eager_fill(bufs, pages, start, k, v, block_size):
+    """The functional ``.at[].set`` form of a fill, chunk by chunk."""
+    if len(bufs) == 6:
+        kq, ks, kz = PagedKVPool.quantize_kv(k)
+        vq, vs, vz = PagedKVPool.quantize_kv(v)
+        news = (kq, vq, ks, kz, vs, vz)
+    else:
+        news = (k, v)
+    T, written = k.shape[1], 0
+    while written < T:
+        pos = start + written
+        page = pages[pos // block_size - start // block_size]
+        slot = pos % block_size
+        take = min(block_size - slot, T - written)
+        bufs = [b.at[:, page, slot:slot + take].set(n[:, written:written + take])
+                for b, n in zip(bufs, news)]
+        written += take
+    return bufs
+
+
+def _pool_buffers(pool):
+    return [getattr(pool, n) for n in pool._buffer_names()]
+
+
+@pytest.mark.parametrize("quantize", [None, "int8"])
+@pytest.mark.parametrize("T, take", [(T, take) for T in range(1, 10) for take in range(1, T + 1)])
+def test_fill_matches_eager_updates(T, take, quantize):
+    """Every (T, take) split of a fill of up to window + 1 = 9 tokens writes
+    exactly what the eager ``.at[].set`` chunks write: first through a
+    forked table (CoW copies of the shared pages, then the chunks), then
+    through the owner's, whose pages are no longer shared."""
+    bs = 16
+    pool = _random_pool(quantize, block_size=bs)
+    start = bs - take if take < T else bs - T
+    rng = np.random.default_rng(T * 10 + take)
+    pool.create(0)
+    pool.append(0, 2 * bs)
+    pool.fork(0, 1)
+    for session in (1, 0):
+        k = rng.normal(size=(2, T, 2, 8)).astype(np.float32)
+        v = rng.normal(size=(2, T, 2, 8)).astype(np.float32)
+        want = [jnp.array(b, copy=True) for b in _pool_buffers(pool)]
+        old = list(pool.tables[session].blocks)
+        pool.fill(session, start, k, v)
+        new = pool.tables[session].blocks
+        touched = range(start // bs, (start + T - 1) // bs + 1)
+        for bi in touched:  # CoW: the eager reference copies the page first
+            if new[bi] != old[bi]:
+                want = [b.at[:, new[bi]].set(b[:, old[bi]]) for b in want]
+        assert (new != old) == (session == 1)
+        want = _eager_fill(want, [new[bi] for bi in touched], start,
+                           jnp.asarray(k), jnp.asarray(v), bs)
+        for got, ref in zip(_pool_buffers(pool), want):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+        assert pool.filled(session) == 0  # the fill starts past the watermark
+    _check_invariants(pool)
+
+
+@pytest.mark.parametrize("quantize", [None, "int8"])
+def test_fill_and_cow_copy_write_in_place(quantize):
+    """A fill and a CoW page copy donate the page buffers: XLA writes them
+    where they are, so each buffer keeps its device address."""
+    pool = PagedKVPool(num_blocks=6, block_size=4, n_layers=2, n_kv_heads=2, head_dim=8,
+                       quantize=quantize)
+    pool.create(0)
+    pool.append(0, 6)
+    before = [b.unsafe_buffer_pointer() for b in _pool_buffers(pool)]
+    held = pool.k_pages
+    pool.fill(0, 2, _tok8(3).repeat(2, 0).repeat(2, 2), _tok8(3).repeat(2, 0).repeat(2, 2))
+    assert held.is_deleted()  # the donation contract: re-read after a write
+    pool.fork(0, 1)
+    pool.append(1, 1)  # shared partial tail page: one CoW copy
+    assert pool.stats["cow_copies"] == 1
+    assert [b.unsafe_buffer_pointer() for b in _pool_buffers(pool)] == before
+
+
+@pytest.mark.parametrize("quantize, planes", [(None, 2), ("int8", 6)])
+def test_write_launches_count_one_per_fill_and_copy(quantize, planes):
+    """``write_launches`` counts the jitted updates: one per ``fill``,
+    however many chunks it writes, and one per CoW copy; the registry
+    mirrors it as ``kv_write_launches``.  ``page_writes`` counts as before."""
+    from repro.obs.metrics import MetricRegistry
+
+    pool = PagedKVPool(num_blocks=8, block_size=4, n_layers=1, n_kv_heads=1, head_dim=8,
+                       quantize=quantize, metrics=MetricRegistry())
+    pool.create(0)
+    pool.append(0, 10)
+    pool.fill(0, 0, _tok8(10), _tok8(10))  # three chunks, one launch
+    assert (pool.stats["write_launches"], pool.stats["page_writes"]) == (1, 3 * planes)
+    pool.fork(0, 1)
+    pool.append(1, 1)  # CoW copy of the shared tail page
+    assert (pool.stats["write_launches"], pool.stats["page_writes"]) == (2, 4 * planes)
+    pool.fill(1, 10, _tok8(1), _tok8(1))
+    assert (pool.stats["write_launches"], pool.stats["page_writes"]) == (3, 5 * planes)
+    pool.fill(1, 8, _tok8(3), _tok8(3))  # page 2 is the child's own now: no copy
+    assert (pool.stats["write_launches"], pool.stats["page_writes"]) == (4, 6 * planes)
+    assert pool.metrics.counter("kv_write_launches").value() == 4
+    assert pool.metrics.counter("kv_page_writes").value() == 6 * planes
+    meta = PagedKVPool(num_blocks=4, block_size=4)  # metadata mode launches nothing
+    meta.create(0)
+    meta.append(0, 3)
+    meta.fork(0, 1)
+    meta.append(1, 1)
+    assert meta.stats["write_launches"] == 0 and meta.stats["cow_copies"] == 1
+
+
 # ---------------------------------------------------- write dtype boundary --
 
 

@@ -283,6 +283,37 @@ def test_backend_evict_rematerialize_matches_unsharded():
 
 
 @requires_mesh
+@pytest.mark.parametrize("quantize", [None, "int8"])
+def test_mesh_placed_pool_fills_in_place(quantize):
+    """Fills and CoW copies of a pool laid out over the mesh keep every
+    buffer's sharding, so JAX can use the donated buffers: no "donated
+    buffers were not usable" warning, the same per-device addresses, and
+    the same pages as the unsharded twin."""
+    import warnings
+
+    ref, p_ref, sh, p_sh = _twin_backends(2, quantize)
+    names = p_sh._buffer_names()
+    shardings = [getattr(p_sh, n).sharding for n in names]
+    addresses = [[s.data.unsafe_buffer_pointer() for s in getattr(p_sh, n).addressable_shards]
+                 for n in names]
+    with warnings.catch_warnings():
+        warnings.filterwarnings("error", message=".*donated buffers were not usable")
+        for backend, pool in ((ref, p_ref), (sh, p_sh)):
+            pool.create(0)
+            pool.append(0, 6)
+            backend.ensure_kv(0)  # a fill across a page end
+            pool.fork(0, 1)
+            pool.append(1, 2)  # CoW copy of the shared tail page
+            backend.ensure_kv(1)
+    assert p_sh.stats["write_launches"] == 3
+    assert [getattr(p_sh, n).sharding for n in names] == shardings
+    assert [[s.data.unsafe_buffer_pointer() for s in getattr(p_sh, n).addressable_shards]
+            for n in names] == addresses
+    for n in names:
+        np.testing.assert_array_equal(np.asarray(getattr(p_sh, n)), np.asarray(getattr(p_ref, n)))
+
+
+@requires_mesh
 def test_backend_cow_fork_matches_unsharded():
     """CoW-forked sessions share prefix pages; the first divergent write
     copies — identically on both backends, so verdicts stay equal."""

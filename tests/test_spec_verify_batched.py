@@ -369,6 +369,35 @@ def test_fused_backend_int8_pool_auto_quant():
     assert pool8.bytes_per_token * 1.5 <= pool32.bytes_per_token
 
 
+def test_fused_backend_consecutive_dispatches_interpret_matches_ref():
+    """Two dispatches in a row through the interpreted kernel give the
+    verdicts and log-probs of ``impl='ref'``.  Each round's fills donate the
+    pool's page buffers, so the second dispatch would fail, or read stale
+    pages, if the backend held an array from before them."""
+    runs = {}
+    for impl in ("ref", "interpret"):
+        backend, pool, _, _ = _fused_backend(impl=impl)
+        rounds = [
+            [(0, [3, 9, 7], [0.9] * 3), (1, [5], [0.9])],
+            [(0, [2, 4], [0.9] * 2), (1, [8, 1, 6, 2], [0.9] * 4)],
+        ]
+        out = []
+        for reqs in rounds:
+            for s, toks, _ in reqs:
+                if s not in pool.tables:
+                    pool.create(s)
+                    pool.append(s, 5 + s)
+                pool.append(s, len(toks) + 1)  # dispatcher-style metadata append
+            out.append(backend.fused_verify(reqs))
+            for s, toks, _ in reqs:  # a round that accepts nothing keeps one slot
+                pool.rollback(s, pool.length(s) - len(toks))
+        runs[impl] = out
+    for got, want in zip(runs["interpret"], runs["ref"]):
+        assert [(int(n), int(c)) for n, c, _ in got] == [(int(n), int(c)) for n, c, _ in want]
+        for (_, _, lp_got), (_, _, lp_want) in zip(got, want):
+            np.testing.assert_allclose(np.asarray(lp_got), np.asarray(lp_want), atol=1e-5)
+
+
 def _materialized_k(pool, session):
     """Gather the session's K tensors [L, length, H, hd] through its table."""
     tab = pool.table(session)

@@ -6,6 +6,8 @@ case lowers one kernel entry at granite-3-2b widths (32 query heads, 8 KV
 heads, head_dim 64, vocab 49155, 16-token pages, the serving backend's
 ``block_v``) for one chip of a described ``v5e:2x2`` topology, compiles it,
 and asserts the compiled program holds the kernel (``tpu_custom_call``).
+The paged pool's jitted writes are compiled at the 40-layer pool as well,
+to check that they update the donated buffers in place.
 
 The topology is described inside a fixture, never at import time: only one
 process may load the TPU library, and every test worker imports this file.
@@ -13,6 +15,7 @@ process may load the TPU library, and every test worker imports this file.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -93,3 +96,29 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     fn, args, kwargs = _entries(sd)[name]
     compiled = jax.jit(fn).lower(*args, **kwargs).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("T, take", [(3, 3), (9, 2)])
+def test_pool_write_compiles_in_place_for_v5e(one_chip, T, take):
+    """A pool fill (and a CoW page copy) at granite-3-2b's 40-layer pool
+    compiles to an update of the donated buffers: the outputs alias the
+    inputs and no copy of a whole buffer is left in the program."""
+    from repro.models.paged_kv import _copy_pages, _write_pages
+
+    L = 40
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    bufs = (sd((L, P, BS, HKV, HD)), sd((L, P, BS, HKV, HD)))
+    news = (sd((L, T, HKV, HD)), sd((L, T, HKV, HD)))
+    n_pages = 1 + -(-(T - take) // BS)
+    programs = [
+        _write_pages.lower(bufs, news, sd((n_pages,), jnp.int32), sd((), jnp.int32), take=take),
+        _copy_pages.lower(bufs, sd((), jnp.int32), sd((), jnp.int32)),
+    ]
+    whole = f"f32[{L},{P},{BS},{HKV},{HD}]"
+    for lowered in programs:
+        text = lowered.compile().as_text()
+        assert "input_output_alias" in text
+        assert not re.search(rf"= {re.escape(whole)}\S* copy\(", text)

@@ -62,6 +62,7 @@ acceptance.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 
@@ -75,6 +76,52 @@ from ..tiles import HIGHEST, NEG_INF, attend_page, lm_head_tile
 DEFAULT_BV = 512
 _BIG = 2**30
 _SEMANTICS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+VMEM_DEFAULT = 16 << 20  # the scoped VMEM a v5e kernel gets unless it asks for more
+# The TPU compiler's own work space: about 4.5 ``[K1, bv]`` f32 tiles per
+# head while it forms the LM-head products, counted as 5, and the attention
+# step's two ``[bs, H, hd]`` f32 products (fitted to the smallest limit each
+# launch compiles under, for a described v5e over H 16-48, hd 64 and 128,
+# K+1 2-9, block_v 256 and 512; the count is at or above every one).
+_HEAD_WORK_TILES = 5
+
+
+def _vmem_bytes(shape, dtype) -> int:
+    """One VMEM buffer of ``shape``: its last two dims padded to the dtype's
+    tile, (8, 128) for 32-bit values and (32, 128) for int8."""
+    itemsize = jnp.dtype(dtype).itemsize
+    *lead, rows, cols = shape
+    sub = 8 * (4 // itemsize)
+    return math.prod(lead) * (-(-rows // sub) * sub) * (-(-cols // 128) * 128) * itemsize
+
+
+def fused_vmem_bytes(k1: int, H: int, hd: int, bs: int, bv: int, page_dtype, quantized: bool) -> int:
+    """What one fused chain launch needs of VMEM, from its block shapes.
+
+    Every block is double-buffered: the queries, the K and V page tiles (and
+    the int8 planes' scale/zero tiles), the ``[H, hd, bv]`` LM-head tile, the
+    token column and the outputs.  Scratch is held once: ``[K1, H, 1]`` and
+    ``[K1, H, hd]`` twice each, four ``[K1, 1]`` columns.  On top comes the
+    compiler's own work space for the per-head LM-head products and the
+    attention step.
+    """
+    f32, i32 = jnp.float32, jnp.int32
+    blocks = [((1, k1, H, hd), f32), ((1, bs, H, hd), page_dtype), ((1, bs, H, hd), page_dtype)]
+    if quantized:
+        blocks += [((1, bs, H), f32)] * 4
+    blocks += [((H, hd, bv), f32), ((1, k1, 1), i32), ((1, 1, 128), i32), ((1, k1, 1), f32)]
+    scratch = [((k1, H, 1), f32)] * 2 + [((k1, H, hd), f32)] * 2 + [((k1, 1), f32)] * 4
+    work = _HEAD_WORK_TILES * H * _vmem_bytes((k1, bv), f32) + 2 * _vmem_bytes((bs, H, hd), f32)
+    return 2 * sum(_vmem_bytes(*b) for b in blocks) + sum(_vmem_bytes(*b) for b in scratch) + work
+
+
+def fused_vmem_limit(k1: int, H: int, hd: int, bs: int, bv: int, page_dtype, quantized: bool):
+    """The scoped VMEM limit a fused launch asks for: ``None`` (the default)
+    while its need fits the default, else the need and a quarter more,
+    rounded up to a whole MiB."""
+    need = fused_vmem_bytes(k1, H, hd, bs, bv, page_dtype, quantized)
+    if need <= VMEM_DEFAULT:
+        return None
+    return -(-(need * 5 // 4) // (1 << 20)) << 20
 
 
 def _scan_init(m_scr, arg_scr, lse_scr, tok_scr):
@@ -316,6 +363,8 @@ def spec_verify_fused_pallas(
         raise ValueError(f"Vp={Vp} must be divisible by block_v={bv}")
     nv = Vp // bv
     G = block_tables.shape[1]
+    limit = fused_vmem_limit(K1, H, hd, bs, bv, k_pages.dtype, quant is not None)
+    params = _SEMANTICS if limit is None else dataclasses.replace(_SEMANTICS, vmem_limit_bytes=limit)
     kernel = functools.partial(
         _fused_verify_kernel,
         sm_scale=1.0 / math.sqrt(hd),
@@ -365,7 +414,7 @@ def spec_verify_fused_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=_chain_outputs(B, K1),
-        compiler_params=_SEMANTICS,
+        compiler_params=params,
         interpret=interpret,
         name="_fused_verify_kernel",  # names the compiled op, and so the profiler's event
     )(

@@ -45,6 +45,11 @@ def _next_pow2(n: int) -> int:
     return 1 << max(int(n) - 1, 0).bit_length()
 
 
+def batch_rows(n: int, bucket: bool = True) -> int:
+    """Rows a batched launch gives ``n`` sessions, pad rows included."""
+    return _next_pow2(n) if bucket else int(n)
+
+
 @functools.partial(
     jax.jit, static_argnames=("v_true", "impl", "block_v", "window")
 )
@@ -146,7 +151,7 @@ def spec_verify_fused_batched(
         if qi.shape[0] != k + 1:
             raise ValueError(f"queries must be [K_i+1, H, hd]; got {qi.shape} for K_i={k}")
     B, kmax = len(ks), max(max(ks, default=0), 1)
-    Bp = _next_pow2(B) if bucket else B
+    Bp = batch_rows(B, bucket)
     Kp = _next_pow2(kmax) if bucket else kmax
     H, hd = q_seq[0].shape[1], q_seq[0].shape[2]
     qpad = np.zeros((Bp, Kp + 1, H, hd), np.float32)
@@ -241,7 +246,7 @@ def spec_verify_batched(
         raise ValueError("need one block table per session")
     ks = [len(t) for t in tokens_seq]
     B, kmax = len(ks), max(max(ks, default=0), 1)
-    Bp = _next_pow2(B) if bucket else B
+    Bp = batch_rows(B, bucket)
     Kp = _next_pow2(kmax) if bucket else kmax
     tokens = np.zeros((Bp, Kp), np.int32)
     nd = np.zeros((Bp,), np.int32)
@@ -381,7 +386,7 @@ def spec_verify_tree_batched(
             if not (-1 <= int(p) < i):
                 raise ValueError(f"parents must be topologically packed; parents[{i}]={p}")
     B, nmax = len(ns), max(max(ns), 1)
-    Bp = _next_pow2(B) if bucket else B
+    Bp = batch_rows(B, bucket)
     Np = _next_pow2(nmax) if bucket else nmax
     tokens = np.zeros((Bp, Np), np.int32)
     parents = np.full((Bp, Np), -1, np.int32)

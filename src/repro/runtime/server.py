@@ -54,6 +54,7 @@ import numpy as np
 
 from repro.core.monitor import EnvironmentMonitor
 from repro.kernels.spec_verify.kernel import DEFAULT_BV
+from repro.kernels.spec_verify.ops import batch_rows
 from repro.models.paged_kv import BlockPoolExhausted, PagedKVPool
 from repro.obs.trace import NULL_TRACER
 from .protocol import (
@@ -254,7 +255,9 @@ class SpecVerifyBackend(VerifyBackend):
 
     ``tracer`` (a ``repro.obs`` tracer, ``NULL_TRACER`` unless a
     ``CloudVerifier`` hands down its own) times one ``query`` per session
-    in the fused path's input assembly.
+    in the fused path's input assembly, and each ``kv_fn`` call of
+    ``ensure_kv`` as ``kv.synth``.  ``stats["kernel_rows"]`` adds up the
+    padded batch of each fused launch, pad rows included.
     """
 
     def __init__(
@@ -293,6 +296,7 @@ class SpecVerifyBackend(VerifyBackend):
         self.lm_head = None if lm_head is None else jnp.asarray(lm_head, jnp.float32)
         self.kv_fn = kv_fn if kv_fn is not None else self._default_kv_fn
         self.tracer = NULL_TRACER
+        self.stats = {"kernel_rows": 0}
 
     def _tables(self, sessions: Sequence[int]):
         if self.kv_pool is None:
@@ -337,7 +341,8 @@ class SpecVerifyBackend(VerifyBackend):
         have = pool.filled(session)
         need = pool.length(session)
         if need > have:
-            k, v = self.kv_fn(session, have, need - have)
+            with self.tracer.span("kv.synth"):
+                k, v = self.kv_fn(session, have, need - have)
             pool.fill(session, have, k, v)
 
     def verify(self, session: int, tokens: List[int], confs: List[float]):
@@ -408,9 +413,9 @@ class SpecVerifyBackend(VerifyBackend):
         """
         from repro.kernels.spec_verify import spec_verify_fused_batched
 
-        return spec_verify_fused_batched(
-            **self.fused_inputs(requests), impl=self.impl, block_v=self.block_v
-        )
+        inputs = self.fused_inputs(requests)
+        self.stats["kernel_rows"] += batch_rows(len(requests))
+        return spec_verify_fused_batched(**inputs, impl=self.impl, block_v=self.block_v)
 
     def verify_tree(self, session, tokens, confs, parents):
         """Verify one session's tree through the batched path (batch of one)."""
@@ -500,9 +505,9 @@ class ShardedSpecVerifyBackend(SpecVerifyBackend):
         """ONE SHARDED launch for the whole round (see the unsharded twin)."""
         from repro.sharding.spec_verify import spec_verify_sharded_batched
 
-        return spec_verify_sharded_batched(
-            **self.fused_inputs(requests), mesh=self.mesh, block_v=self.block_v
-        )
+        inputs = self.fused_inputs(requests)
+        self.stats["kernel_rows"] += batch_rows(len(requests))
+        return spec_verify_sharded_batched(**inputs, mesh=self.mesh, block_v=self.block_v)
 
 
 @dataclass
@@ -656,6 +661,9 @@ class CloudVerifier:
             # admission (copy-on-write copies) through the verify call
             # (``PagedKVPool.stats["page_writes"]``; 0 without tensor pages).
             "kv_page_writes": 0,
+            # Rows the backend's fused launches carried, pad rows included
+            # (``SpecVerifyBackend.stats["kernel_rows"]``; 0 for others).
+            "kernel_rows": 0,
         }
         # The monitor here is an accumulator for the whole serving run, not
         # the paper's 100-observation estimator — size the window accordingly
@@ -1128,6 +1136,10 @@ class CloudVerifier:
             pool = self.kv_pool
         return pool.stats["page_writes"] if pool is not None else 0
 
+    def _kernel_rows(self) -> int:
+        """Rows the backend's fused launches carried so far (0 for others)."""
+        return getattr(self.backend, "stats", {}).get("kernel_rows", 0)
+
     def _dispatch(self, batch: List[_VerifyRequest], depth: int, n: int, writes: int) -> None:
         """Verify one admitted batch, commit its rounds and send the results.
 
@@ -1140,6 +1152,7 @@ class CloudVerifier:
         chain = [r for r in batch if r.parents is None]
         tree = [r for r in batch if r.parents is not None]
         results: Dict[int, tuple] = {}
+        rows = self._kernel_rows()
         with tracer.span(
             "verify", verifier=self.verifier_id, batch=len(batch), depth=depth, dispatch=n
         ):
@@ -1166,6 +1179,7 @@ class CloudVerifier:
             verify_t1 = self.clock.monotonic()
         self.stats["verify_busy_time"] += verify_t1 - verify_t0
         self.stats["kv_page_writes"] += self._page_writes() - writes
+        self.stats["kernel_rows"] += self._kernel_rows() - rows
         self.stats["nav_calls"] += len(batch)
         self.stats["batched_calls"] += 1
         self.monitor.observe_verifier_batch(len(batch), depth)

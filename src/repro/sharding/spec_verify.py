@@ -53,7 +53,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.decode_attention.ref import dequantize_pages, paged_decode_attention_ref
-from repro.kernels.spec_verify.ops import _next_pow2, pad_block_tables
+from repro.kernels.spec_verify.ops import _next_pow2, batch_rows, pad_block_tables
 from repro.kernels.spec_verify.ref import lm_head_logits, spec_verify_ref
 
 from .shardctx import host_mesh
@@ -402,7 +402,7 @@ def spec_verify_sharded_batched(
         if qi.shape[0] != k + 1:
             raise ValueError(f"queries must be [K_i+1, H, hd]; got {qi.shape} for K_i={k}")
     B, kmax = len(ks), max(max(ks, default=0), 1)
-    Bp = _next_pow2(B) if bucket else B
+    Bp = batch_rows(B, bucket)
     Kp = _next_pow2(kmax) if bucket else kmax
     H, hd = q_seq[0].shape[1], q_seq[0].shape[2]
     qpad = np.zeros((Bp, Kp + 1, H, hd), np.float32)

@@ -644,3 +644,74 @@ def test_verifier_counts_page_writes_from_admission_through_verify():
     clock.run(body)
     assert pool.stats["cow_copies"] == 2  # one shared tail page per session
     assert server.stats["kv_page_writes"] == pool.stats["page_writes"] - before > 2 * 2
+
+
+def test_verifier_traces_kv_synthesis_and_counts_launched_rows(monkeypatch):
+    """Each ``kv_fn`` call of ``ensure_kv`` is a ``kv.synth`` span under its
+    dispatch's ``verify`` (the prefix's, made at construction, has none),
+    and ``kernel_rows`` adds up the padded batch of every fused launch, pad
+    rows included, in the backend and in the dispatcher's stats alike."""
+    import repro.kernels.spec_verify.ops as ops
+    from repro.models.paged_kv import PagedKVPool
+    from repro.obs.trace import Tracer
+    from repro.runtime import SpecVerifyBackend
+    from repro.runtime.client import EdgeClient, EdgeConfig
+    from repro.runtime.server import CloudVerifier
+    from repro.runtime.simclock import VirtualClock
+    from repro.runtime.transport import Channel, ChannelConfig
+
+    launched = []
+    real = ops.spec_verify_fused
+
+    def spy(q, *args, **kwargs):
+        launched.append(int(q.shape[0]))
+        return real(q, *args, **kwargs)
+
+    monkeypatch.setattr(ops, "spec_verify_fused", spy)
+    H, hd, V = 2, 16, 256
+    w = np.asarray(jax.random.normal(jax.random.PRNGKey(3), (H * hd, V)) * 6, np.float32)
+
+    def query_fn(session, tokens):
+        k = jax.random.fold_in(jax.random.PRNGKey(4), session * 997 + len(tokens))
+        return np.asarray(jax.random.normal(k, (len(tokens) + 1, H, hd)), np.float32)
+
+    clock = VirtualClock()
+    tracer = Tracer()
+    pool = PagedKVPool(num_blocks=64, block_size=8, n_layers=1, n_kv_heads=H, head_dim=hd)
+    backend = SpecVerifyBackend(
+        fused=True, kv_pool=pool, query_fn=query_fn, lm_head=w, impl="ref", block_v=256
+    )
+    server = CloudVerifier(
+        backend, kv_pool=pool, kv_shared_prefix=16, clock=clock, tracer=tracer,
+        batch_window=0.01, max_batch=8,
+    )
+    clients = []
+    for s in range(3):
+        up = Channel(ChannelConfig(alpha=0.02, beta=0.002), f"up{s}", clock=clock)
+        dn = Channel(ChannelConfig(alpha=0.01, beta=0.0005), f"dn{s}", clock=clock)
+        server.attach(s, up, dn)
+        clients.append(EdgeClient(s, up, dn, EdgeConfig(gamma=0.02, nav_timeout=3.0)))
+
+    def body():
+        server.start()
+        handles = [clock.spawn(lambda c=c: c.run(6), name=f"cli-{c.session}") for c in clients]
+        for h in handles:
+            h.join()
+        server.stop()
+
+    clock.run(body)
+    assert launched and server.stats["batched_calls"] == len(launched)
+    assert backend.stats["kernel_rows"] == server.stats["kernel_rows"] == sum(launched)
+    assert any(rows > 1 for rows in launched)
+    assert all(rows & (rows - 1) == 0 for rows in launched)  # pow2 buckets
+
+    spans = tracer.spans()
+    by_sid = {s.sid: s for s in spans if s.sid}
+    synths = [s for s in spans if s.name == "kv.synth"]
+    assert not synths[0].parent  # the shared prefix, at construction
+    assert len(synths) > 1
+    for s in synths[1:]:
+        verify = by_sid[s.parent]
+        assert verify.name == "verify"
+        assert s.get("dispatch") == verify.get("dispatch") is not None
+        assert verify.t0 <= s.t0 <= s.t1 <= verify.t1

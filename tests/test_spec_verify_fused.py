@@ -81,6 +81,34 @@ def test_fused_forced_accept_reject_edges(forced):
     np.testing.assert_array_equal(np.asarray(fused[0]).ravel(), want)
 
 
+def test_fused_bitexact_where_the_vmem_rule_raises_the_limit():
+    """At minicpm-2b's head widths (36 MHA heads of 64) and the edge window's
+    K+1 = 9 rows, ``block_v`` 512 needs more than the default scoped VMEM,
+    so the launch asks for a limit of its own.  Its results are still the
+    unfused composition's, bit for bit, with a vocabulary that is not a
+    multiple of ``block_v`` and drafts that the target accepts."""
+    from repro.kernels.spec_verify.kernel import DEFAULT_BV, fused_vmem_limit
+
+    B, K, H, hd, bs, G, P, V = 2, 8, 36, 64, 16, 2, 6, 1000
+    assert fused_vmem_limit(K + 1, H, hd, bs, DEFAULT_BV, jnp.float32, False) is not None
+    q, kp, vp, w, tables, lengths, tokens, nd = _make_case(
+        B, K, H, H, hd, bs, G, P, V, seed=11, sharp=True
+    )
+    _, greedy, _ = _composed(
+        q, kp, vp, w, tables, lengths, jnp.full((B, K), -1, jnp.int32),
+        jnp.ones((B,), jnp.int32), impl="ref", block_v=DEFAULT_BV,
+    )
+    tokens = tokens.at[:, 0].set(jnp.asarray(greedy)[:, 0])  # row 0's greedy token
+    fused = spec_verify_fused(
+        q, kp, vp, w, tables, lengths, tokens, nd, impl="interpret", block_v=DEFAULT_BV
+    )
+    composed = _composed(
+        q, kp, vp, w, tables, lengths, tokens, nd, impl="interpret", block_v=DEFAULT_BV
+    )
+    _assert_fused_matches(fused, composed)
+    assert (np.asarray(fused[0]) >= 1).all()
+
+
 @settings(max_examples=10, deadline=None)
 @given(geom=rect_geometries())
 def test_property_fused_bitexact(geom):

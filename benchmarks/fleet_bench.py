@@ -149,9 +149,12 @@ def _sharded_spec_backend(shards: int, seed: int):
     key = jax.random.PRNGKey(seed)
     w = np.asarray(jax.random.normal(jax.random.fold_in(key, 77), (H * hd, V)) * 4, np.float32)
 
-    def query_fn(session, tokens):
-        k = jax.random.fold_in(jax.random.fold_in(key, 88), session * 131 + len(tokens))
-        return np.asarray(jax.random.normal(k, (len(tokens) + 1, H, hd)), np.float32)
+    queries = jax.random.fold_in(key, 88)
+
+    def query_fn(session, tokens, k=None):  # the launcher's target (launch/serve.py)
+        n = len(tokens) if k is None else k
+        draw = jax.random.fold_in(queries, session * 131 + n)
+        return jax.random.normal(draw, (len(tokens) + 1, H, hd))
 
     backend = ShardedSpecVerifyBackend(
         shards=shards, kv_pool=pool, query_fn=query_fn, lm_head=w,

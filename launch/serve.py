@@ -192,7 +192,6 @@ def _spec_backend(args):
     wire protocol are oblivious to both choices.
     """
     import jax
-    import numpy as np
 
     from repro.configs import get_config
     from repro.models.paged_kv import PagedKVPool
@@ -208,9 +207,16 @@ def _spec_backend(args):
     # Scaled by 1/sqrt(fan-in) so the logit spread does not grow with width.
     w = jax.random.normal(jax.random.fold_in(key, 77), (H * hd, V)) * (16.0 / math.sqrt(H * hd))
 
-    def query_fn(session, tokens):
-        k = jax.random.fold_in(jax.random.fold_in(key, 88), session * 131 + len(tokens))
-        return np.asarray(jax.random.normal(k, (len(tokens) + 1, H, hd)), np.float32)
+    queries = jax.random.fold_in(key, 88)  # folded once, outside the traced draw
+
+    def query_fn(session, tokens, k=None):
+        # Traceable (SpecVerifyBackend): with k, tokens come padded past the
+        # round's k drafts.  The first k+1 rows of the wider draw equal the
+        # k-draft draw under partitionable threefry (jax_threefry_partitionable,
+        # JAX's default), which counts each element by its own index.
+        n = len(tokens) if k is None else k
+        draw = jax.random.fold_in(queries, session * 131 + n)
+        return jax.random.normal(draw, (len(tokens) + 1, H, hd))
 
     kw = dict(kv_pool=pool, query_fn=query_fn, lm_head=w, impl=args.impl)
     if args.shards > 1:

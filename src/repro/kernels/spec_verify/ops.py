@@ -50,11 +50,18 @@ def batch_rows(n: int, bucket: bool = True) -> int:
     return _next_pow2(n) if bucket else int(n)
 
 
+def check_queries(q, draft_tokens) -> None:
+    """Raise unless ``q`` is ``[R, P, H, hd]``, ``R >= B``, ``P >= K+1``, for ``draft_tokens [B, K]``."""
+    B, K = draft_tokens.shape
+    if q.ndim != 4 or q.shape[0] < B or q.shape[1] < K + 1:
+        raise ValueError(f"queries must be [R>=B, P>=K+1, H, hd] with B={B}, K={K}; got {q.shape}")
+
+
 @functools.partial(
     jax.jit, static_argnames=("v_true", "impl", "block_v", "window")
 )
 def spec_verify_fused(
-    q: jax.Array,  # [B, K+1, H, hd] — per-position queries
+    q: jax.Array,  # [R, P, H, hd] per-position queries; reads q[:B, :K+1]
     k_pages: jax.Array,  # [P, bs, Hkv, hd] (int8 payload when quant is given)
     v_pages: jax.Array,
     w: jax.Array,  # [H*hd, V] LM head (padded to a block_v multiple here)
@@ -79,9 +86,13 @@ def spec_verify_fused(
     verify-launch unfused).  ``lengths[b, i]`` is the valid KV length seen
     by query position ``i`` (causal: the serving entry passes
     ``base + i``).  With ``quant`` the pages are int8 and dequantized
-    in-kernel (``models/paged_kv.py`` affine layout).  Bit-exact vs the
+    in-kernel (``models/paged_kv.py`` affine layout).  ``q`` may hold
+    more rows and positions than the round (a serving backend draws at a
+    fixed least shape): the launch reads ``q[:B, :K+1]``.  Bit-exact vs the
     unfused composition per ``tests/test_spec_verify_fused.py``.
     """
+    check_queries(q, draft_tokens)
+    q = q[: draft_tokens.shape[0], : draft_tokens.shape[1] + 1]
     H = q.shape[2]
     n_kv = k_pages.shape[2]
     if n_kv != H:
@@ -116,8 +127,52 @@ def spec_verify_fused(
     )
 
 
+def padded_dims(ks: Sequence[int], bucket: bool = True) -> Tuple[int, int]:
+    """``(Bp, Kp)`` of the launch that carries sessions drafting ``ks`` tokens.
+
+    Pad rows and draft positions follow the serving entries' pow2 bucketing;
+    a verify backend draws its queries at least this wide.
+    """
+    kmax = max(max(ks, default=0), 1)
+    return batch_rows(len(ks), bucket), (_next_pow2(kmax) if bucket else kmax)
+
+
+def ragged_launch_args(
+    tokens_seq: Sequence,
+    block_tables_seq: Sequence,
+    base_lengths: Sequence,
+    *,
+    bucket: bool = True,
+    pad_page_id: int = 0,
+):
+    """Pad a ragged round's host-side arguments for one launch.
+
+    Shared by ``spec_verify_fused_batched`` and its sharded twin.  Returns
+    ``(ks, tables [Bp, Gp], lengths [Bp, Kp+1], tokens [Bp, Kp],
+    n_drafted [Bp])``; pad rows and positions see length 0 and draft
+    nothing, which makes them inert.
+    """
+    if not (len(tokens_seq) == len(block_tables_seq) == len(base_lengths)):
+        raise ValueError("need one (tokens, table, base_length) per session")
+    if not len(tokens_seq):
+        raise ValueError("need at least one session")
+    ks = [len(t) for t in tokens_seq]
+    Bp, Kp = padded_dims(ks, bucket)
+    tokens = np.zeros((Bp, Kp), np.int32)
+    nd = np.zeros((Bp,), np.int32)
+    lengths = np.zeros((Bp, Kp + 1), np.int32)
+    for i, (tk, k, base) in enumerate(zip(tokens_seq, ks, base_lengths)):
+        tokens[i, :k] = np.asarray(tk, np.int32)
+        nd[i] = k
+        lengths[i, : k + 1] = int(base) + np.arange(k + 1)
+    tables = pad_block_tables(
+        block_tables_seq, batch_pad=Bp, bucket=bucket, pad_id=pad_page_id
+    )
+    return ks, tables, lengths, tokens, nd
+
+
 def spec_verify_fused_batched(
-    q_seq: Sequence,  # B entries of [K_i+1, H, hd] per-position queries
+    q: jax.Array,  # [R, P, H, hd] padded queries, R >= Bp, P >= Kp+1 (see padded_dims)
     tokens_seq: Sequence,  # B entries of length-K_i int sequences
     block_tables_seq: Sequence,  # B ragged KV block tables
     base_lengths: Sequence,  # B ints — KV length visible to query position 0
@@ -135,39 +190,21 @@ def spec_verify_fused_batched(
     """Ragged serving entry for the fused verify — one launch for B sessions.
 
     The fused twin of ``spec_verify_batched``'s ``batched_logits_fn`` path,
-    with the forward folded INTO the verify launch: pads queries, tokens,
-    block tables (``pad_page_id`` — pass the pool's ``sentinel_page``), and
-    per-position lengths (position ``i`` of session ``s`` sees
-    ``base_lengths[s] + i``; pad rows/positions see 0, making them inert)
-    under the same pow2 bucketing, launches once, and unpacks
+    with the forward folded INTO the verify launch.  The queries come
+    padded already, at least to the ``padded_dims`` of the round: ``[R, P,
+    H, hd]`` with ``R >= Bp`` and ``P >= Kp+1`` (session ``s`` in row
+    ``s``, its ``K_s+1`` positions first; pad rows and positions zero;
+    the launch reads ``[:Bp, :Kp+1]``).  The entry pads tokens, block tables (``pad_page_id`` — pass the
+    pool's ``sentinel_page``) and per-position lengths (position ``i`` of
+    session ``s`` sees ``base_lengths[s] + i``; pad rows/positions see 0,
+    making them inert) under the same pow2 bucketing, launches once, and unpacks
     ``(n_accepted, correction, logp[K_i])`` per session in input order.
     """
-    if not (len(q_seq) == len(tokens_seq) == len(block_tables_seq) == len(base_lengths)):
-        raise ValueError("need one (queries, tokens, table, base_length) per session")
-    if not len(tokens_seq):
-        raise ValueError("need at least one session")
-    ks = [len(t) for t in tokens_seq]
-    for qi, k in zip(q_seq, ks):
-        if qi.shape[0] != k + 1:
-            raise ValueError(f"queries must be [K_i+1, H, hd]; got {qi.shape} for K_i={k}")
-    B, kmax = len(ks), max(max(ks, default=0), 1)
-    Bp = batch_rows(B, bucket)
-    Kp = _next_pow2(kmax) if bucket else kmax
-    H, hd = q_seq[0].shape[1], q_seq[0].shape[2]
-    qpad = np.zeros((Bp, Kp + 1, H, hd), np.float32)
-    tokens = np.zeros((Bp, Kp), np.int32)
-    nd = np.zeros((Bp,), np.int32)
-    lengths = np.zeros((Bp, Kp + 1), np.int32)
-    for i, (qi, tk, k, base) in enumerate(zip(q_seq, tokens_seq, ks, base_lengths)):
-        qpad[i, : k + 1] = np.asarray(qi, np.float32)
-        tokens[i, :k] = np.asarray(tk, np.int32)
-        nd[i] = k
-        lengths[i, : k + 1] = int(base) + np.arange(k + 1)
-    tables = pad_block_tables(
-        block_tables_seq, batch_pad=Bp, bucket=bucket, pad_id=pad_page_id
+    ks, tables, lengths, tokens, nd = ragged_launch_args(
+        tokens_seq, block_tables_seq, base_lengths, bucket=bucket, pad_page_id=pad_page_id
     )
     na, corr, logp = spec_verify_fused(
-        jnp.asarray(qpad),
+        q,
         k_pages,
         v_pages,
         w,
@@ -181,7 +218,7 @@ def spec_verify_fused_batched(
         quant=quant,
     )
     na, corr, logp = np.asarray(na), np.asarray(corr), np.asarray(logp)
-    return [(int(na[i, 0]), int(corr[i, 0]), logp[i, : ks[i]]) for i in range(B)]
+    return [(int(na[i, 0]), int(corr[i, 0]), logp[i, :k]) for i, k in enumerate(ks)]
 
 
 def pad_block_tables(
@@ -245,9 +282,8 @@ def spec_verify_batched(
     if block_tables_seq is not None and len(block_tables_seq) != len(tokens_seq):
         raise ValueError("need one block table per session")
     ks = [len(t) for t in tokens_seq]
-    B, kmax = len(ks), max(max(ks, default=0), 1)
-    Bp = batch_rows(B, bucket)
-    Kp = _next_pow2(kmax) if bucket else kmax
+    B = len(ks)
+    Bp, Kp = padded_dims(ks, bucket)
     tokens = np.zeros((Bp, Kp), np.int32)
     nd = np.zeros((Bp,), np.int32)
     for i, (tk, k) in enumerate(zip(tokens_seq, ks)):
@@ -385,9 +421,8 @@ def spec_verify_tree_batched(
         for i, p in enumerate(pr):
             if not (-1 <= int(p) < i):
                 raise ValueError(f"parents must be topologically packed; parents[{i}]={p}")
-    B, nmax = len(ns), max(max(ns), 1)
-    Bp = batch_rows(B, bucket)
-    Np = _next_pow2(nmax) if bucket else nmax
+    B = len(ns)
+    Bp, Np = padded_dims(ns, bucket)
     tokens = np.zeros((Bp, Np), np.int32)
     parents = np.full((Bp, Np), -1, np.int32)
     nn = np.zeros((Bp,), np.int32)

@@ -49,12 +49,13 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, Set, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.monitor import EnvironmentMonitor
 from repro.kernels.spec_verify.kernel import DEFAULT_BV
-from repro.kernels.spec_verify.ops import batch_rows
+from repro.kernels.spec_verify.ops import batch_rows, padded_dims
 from repro.models.paged_kv import BlockPoolExhausted, PagedKVPool
 from repro.obs.trace import NULL_TRACER
 from .protocol import (
@@ -210,6 +211,31 @@ class SyntheticBackend(VerifyBackend):
 IMPLS = ("pallas", "interpret", "ref")
 
 
+# The least shape of a query draw: the dispatcher's default ``max_batch`` rows
+# and the launcher's edge window of drafts.  Every round within both shares one
+# draw program, since each program costs its lowering and loading at start-up.
+DRAW_ROWS, DRAW_DRAFTS = 32, 8
+
+
+def _query_draw(jaxpr):
+    """The jitted draw of every row's queries from ``query_fn``'s one-row ``jaxpr``.
+
+    ``ids [R, Kd+2]`` rows hold a session, its draft count ``K`` (-1 on
+    pad rows) and its drafts; the draw returns ``[R, Kd+1, H, hd]``, each
+    row one evaluation of ``query_fn`` at ``Kd`` with its own ``K``, and
+    positions past ``K`` zeroed.
+    """
+    def query_draw(consts, ids):
+        def row(session, k, tokens):
+            return jax.core.eval_jaxpr(jaxpr, consts, session, tokens, k)[0]
+
+        q = jax.vmap(row)(ids[:, 0], ids[:, 1], ids[:, 2:]).astype(jnp.float32)
+        keep = jnp.arange(ids.shape[1] - 1)[None, :] <= ids[:, 1:2]
+        return jnp.where(keep[:, :, None, None], q, 0.0)
+
+    return jax.jit(query_draw)
+
+
 class SpecVerifyBackend(VerifyBackend):
     """Real NAV verification through the fused spec_verify kernel.
 
@@ -235,8 +261,9 @@ class SpecVerifyBackend(VerifyBackend):
     (see ``PagedKVPool.table``).
 
     **Fused one-launch verify** (``fused=True``).  Requires a TENSOR-mode
-    ``kv_pool``, a ``query_fn(session, tokens) -> [K+1, H, hd]`` producing
-    the target's per-position queries, and ``lm_head [H*hd, V]``: chain
+    ``kv_pool``, a traceable ``query_fn(session, tokens, k=None) -> [K+1,
+    H, hd]`` producing the target's per-position queries (below), and
+    ``lm_head [H*hd, V]``: chain
     rounds then run ``spec_verify_fused_batched`` — paged attention over
     the sessions' block tables + LM-head projection + NAV scan in ONE
     Pallas launch instead of forward-then-verify.  The round's KV slots
@@ -253,11 +280,36 @@ class SpecVerifyBackend(VerifyBackend):
     sharing).  An int8 pool (``quantize='int8'``) is picked up
     automatically — the launch dequantizes pages in-kernel.
 
+    **The target's queries.**  ``query_fn(session, tokens, k=None)`` is the
+    one definition of the target's per-position queries: called as
+    ``query_fn(s, tokens)`` it returns ``[len(tokens)+1, H, hd]`` for a
+    round of ``len(tokens)`` drafts, as a ``jax.Array``.  It must be
+    traceable, and the backend calls it traced: ``session`` an int32
+    scalar, ``tokens`` a row padded to the launch's ``Kp`` (so the shape,
+    ``[Kp+1, H, hd]``, is static), and ``k`` the round's real draft count.
+    Its first ``k+1`` positions must equal those of ``query_fn(session,
+    tokens[:k])``; the rest are zeroed.  A ``jax.random`` draw meets this
+    only under JAX's partitionable threefry (``jax_threefry_partitionable``,
+    on by default), which keys each element by its own index; the backend
+    refuses to draw with it off.  Each fused round draws all of its
+    sessions' queries as ONE jitted, vmapped evaluation over the rows
+    (``draw_queries``) and hands the device array to the launch: no call
+    per session and no copy to the host.  The draw is ``[max(Bp,
+    DRAW_ROWS), max(Kp, DRAW_DRAFTS)+1, H, hd]``, zero past the round's
+    sessions and drafts, and the launch reads its first ``[Bp, Kp+1]``: one
+    draw program serves every round of up to 32 sessions drafting up to 8
+    tokens, and nothing compiles for a round's mix of draft counts.
+    ``query_fn`` is traced once per draw width, and the arrays it closes
+    over are arguments of the draw: its compiled program does not depend on
+    their values.
+
     ``tracer`` (a ``repro.obs`` tracer, ``NULL_TRACER`` unless a
-    ``CloudVerifier`` hands down its own) times one ``query`` per session
-    in the fused path's input assembly, and each ``kv_fn`` call of
-    ``ensure_kv`` as ``kv.synth``.  ``stats["kernel_rows"]`` adds up the
-    padded batch of each fused launch, pad rows included.
+    ``CloudVerifier`` hands down its own) times the host side of that draw
+    (its ids and the draw's dispatch, no sync) as one ``query`` span per
+    fused round, and each ``kv_fn`` call of ``ensure_kv`` as ``kv.synth``.
+    ``stats["kernel_rows"]`` adds up the padded batch of each fused launch,
+    pad rows included; ``stats["query_launches"]`` counts the draws, one
+    per fused round.
     """
 
     def __init__(
@@ -291,12 +343,13 @@ class SpecVerifyBackend(VerifyBackend):
         self.batched_tree_logits_fn = batched_tree_logits_fn
         self.fused = fused
         self.query_fn = query_fn
+        self._draws: Dict[int, tuple] = {}  # drafts -> (jitted draw, query_fn's arrays)
         # Device-resident once: a host LM head would cross to the device on
         # every dispatch.
         self.lm_head = None if lm_head is None else jnp.asarray(lm_head, jnp.float32)
         self.kv_fn = kv_fn if kv_fn is not None else self._default_kv_fn
         self.tracer = NULL_TRACER
-        self.stats = {"kernel_rows": 0}
+        self.stats = {"kernel_rows": 0, "query_launches": 0}
 
     def _tables(self, sessions: Sequence[int]):
         if self.kv_pool is None:
@@ -373,14 +426,54 @@ class SpecVerifyBackend(VerifyBackend):
             out = spec_verify_batched(logits, tokens, impl=self.impl, block_v=self.block_v)
         return [(int(n_acc), int(corr)) for (n_acc, corr, _) in out]
 
+    def draw_queries(self, requests) -> jax.Array:
+        """The round's queries, ``[max(Bp, DRAW_ROWS), max(Kp, DRAW_DRAFTS)+1, H, hd]``.
+
+        Drawn on the device.  Row ``b`` holds ``query_fn(session_b,
+        tokens_b)`` in its first ``K_b + 1`` positions and zeros after them;
+        rows past the round's sessions are zero.  Only the round's ids cross
+        to the device.
+        """
+        with self.tracer.span("query"):
+            Bp, Kp = padded_dims([len(t) for (_, t, _) in requests])
+            kd = max(Kp, DRAW_DRAFTS)
+            ids = np.full((max(Bp, DRAW_ROWS), kd + 2), -1, np.int32)  # pad rows: K = -1
+            for i, (s, t, _) in enumerate(requests):
+                ids[i, 0], ids[i, 1] = s, len(t)
+                ids[i, 2 : 2 + len(t)] = t
+            draw, consts = self._draw(kd)
+            q = draw(consts, ids)
+        self.stats["query_launches"] += 1
+        return q
+
+    def _draw(self, kd: int):
+        """``(jitted draw, arrays)`` for rows of ``kd`` drafts; ``query_fn`` traced once per ``kd``.
+
+        ``query_fn`` becomes a one-row jaxpr whose closed-over arrays (a
+        seeded key, say) are arguments of the draw, not constants of its
+        program: the program, and its persistent compile-cache entry, is
+        then the same for every seed.
+        """
+        if kd not in self._draws:
+            if not jax.config.jax_threefry_partitionable:
+                raise RuntimeError(
+                    "the query draw needs jax_threefry_partitionable: a jax.random "
+                    "draw of kd drafts must start with the draw at the round's own K"
+                )
+            i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+            closed = jax.make_jaxpr(self.query_fn)(i32(), i32(kd), i32())
+            self._draws[kd] = (_query_draw(closed.jaxpr), tuple(closed.consts))
+        return self._draws[kd]
+
     def fused_inputs(self, requests):
         """The fused launch's arguments for one round, as keyword arguments.
 
         Fills any unmaterialized KV slots first (the dispatcher appends page
-        metadata in ``_kv_secure`` before we run), then gathers queries,
-        block tables, base lengths, the pool's layer-0 page tensors (+ int8
-        quant params when the pool quantizes) and the LM head — the shared
-        signature of ``spec_verify_fused_batched`` and its sharded twin.
+        metadata in ``_kv_secure`` before we run), then draws the round's
+        queries on the device (``draw_queries``) and gathers block tables,
+        base lengths, the pool's layer-0 page tensors (+ int8 quant params
+        when the pool quantizes) and the LM head — the shared signature of
+        ``spec_verify_fused_batched`` and its sharded twin.
         """
         pool = self.kv_pool
         sessions = [s for (s, _, _) in requests]
@@ -389,12 +482,8 @@ class SpecVerifyBackend(VerifyBackend):
         quant = None
         if pool.quantize == "int8":
             quant = (pool.k_scale[0], pool.k_zero[0], pool.v_scale[0], pool.v_zero[0])
-        q_seq = []
-        for s, t, _ in requests:
-            with self.tracer.span("query"):
-                q_seq.append(np.asarray(self.query_fn(s, t), np.float32))
         return dict(
-            q_seq=q_seq,
+            q=self.draw_queries(requests),
             tokens_seq=[t for (_, t, _) in requests],
             block_tables_seq=self._tables(sessions),
             base_lengths=[max(pool.length(s) - len(t), 0) for (s, t, _) in requests],
@@ -664,6 +753,9 @@ class CloudVerifier:
             # Rows the backend's fused launches carried, pad rows included
             # (``SpecVerifyBackend.stats["kernel_rows"]``; 0 for others).
             "kernel_rows": 0,
+            # The backend's device query draws, one per fused round
+            # (``SpecVerifyBackend.stats["query_launches"]``; 0 for others).
+            "query_launches": 0,
         }
         # The monitor here is an accumulator for the whole serving run, not
         # the paper's 100-observation estimator — size the window accordingly
@@ -1136,9 +1228,10 @@ class CloudVerifier:
             pool = self.kv_pool
         return pool.stats["page_writes"] if pool is not None else 0
 
-    def _kernel_rows(self) -> int:
-        """Rows the backend's fused launches carried so far (0 for others)."""
-        return getattr(self.backend, "stats", {}).get("kernel_rows", 0)
+    def _backend_counts(self) -> Tuple[int, int]:
+        """The backend's ``kernel_rows`` and ``query_launches`` so far (0 for others)."""
+        stats = getattr(self.backend, "stats", {})
+        return stats.get("kernel_rows", 0), stats.get("query_launches", 0)
 
     def _dispatch(self, batch: List[_VerifyRequest], depth: int, n: int, writes: int) -> None:
         """Verify one admitted batch, commit its rounds and send the results.
@@ -1152,7 +1245,7 @@ class CloudVerifier:
         chain = [r for r in batch if r.parents is None]
         tree = [r for r in batch if r.parents is not None]
         results: Dict[int, tuple] = {}
-        rows = self._kernel_rows()
+        rows, draws = self._backend_counts()
         with tracer.span(
             "verify", verifier=self.verifier_id, batch=len(batch), depth=depth, dispatch=n
         ):
@@ -1179,7 +1272,9 @@ class CloudVerifier:
             verify_t1 = self.clock.monotonic()
         self.stats["verify_busy_time"] += verify_t1 - verify_t0
         self.stats["kv_page_writes"] += self._page_writes() - writes
-        self.stats["kernel_rows"] += self._kernel_rows() - rows
+        rows_end, draws_end = self._backend_counts()
+        self.stats["kernel_rows"] += rows_end - rows
+        self.stats["query_launches"] += draws_end - draws
         self.stats["nav_calls"] += len(batch)
         self.stats["batched_calls"] += 1
         self.monitor.observe_verifier_batch(len(batch), depth)

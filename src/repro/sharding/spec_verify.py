@@ -53,7 +53,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.decode_attention.ref import dequantize_pages, paged_decode_attention_ref
-from repro.kernels.spec_verify.ops import _next_pow2, batch_rows, pad_block_tables
+from repro.kernels.spec_verify.ops import _next_pow2, check_queries, ragged_launch_args
 from repro.kernels.spec_verify.ref import lm_head_logits, spec_verify_ref
 
 from .shardctx import host_mesh
@@ -271,7 +271,7 @@ def _prepare(
 
 
 def spec_verify_sharded(
-    q: jax.Array,  # [B, K+1, H, hd] — per-position queries
+    q: jax.Array,  # [R, P, H, hd] per-position queries; reads q[:B, :K+1]
     k_pages: jax.Array,  # [P, bs, Hkv, hd] (int8 payload when quant is given)
     v_pages: jax.Array,
     w: jax.Array,  # [H*hd, V] LM head
@@ -293,6 +293,10 @@ def spec_verify_sharded(
     Bit-exact against the jitted unsharded oracle for any shard count,
     including head counts that don't divide the mesh and int8 pools.
     """
+    check_queries(q, draft_tokens)
+    B, K = draft_tokens.shape
+    if q.shape[:2] != (B, K + 1):
+        q = q[:B, : K + 1]
     q, k_pages, v_pages, w, quant, plan, v_true = _prepare(
         q, k_pages, v_pages, w, mesh, v_true=v_true, block_v=block_v, quant=quant
     )
@@ -365,7 +369,7 @@ def sharded_target_logits(
 
 
 def spec_verify_sharded_batched(
-    q_seq: Sequence,  # B entries of [K_i+1, H, hd] per-position queries
+    q: jax.Array,  # [R, P, H, hd] padded queries, R >= Bp, P >= Kp+1 (see padded_dims)
     tokens_seq: Sequence,  # B entries of length-K_i int sequences
     block_tables_seq: Sequence,  # B ragged KV block tables
     base_lengths: Sequence,  # B ints — KV length visible to query position 0
@@ -383,42 +387,22 @@ def spec_verify_sharded_batched(
 ) -> List[Tuple[int, int, np.ndarray]]:
     """Ragged serving entry for the SHARDED fused verify — one launch.
 
-    The sharded twin of ``spec_verify_fused_batched``: identical pow2
-    bucketing, sentinel-page table padding, inert pad rows, and per-session
-    unpacking — only the launch underneath runs ``shard_map`` across the
-    mesh.  Pass either a prebuilt 1-D ``mesh`` or a ``shards`` count (a host
-    mesh over the first ``shards`` devices is built for you).
+    The sharded twin of ``spec_verify_fused_batched``: the same padded
+    queries, identical pow2 bucketing, sentinel-page table padding, inert
+    pad rows, and per-session unpacking (``ragged_launch_args``) — only the
+    launch underneath runs ``shard_map`` across the mesh.  Pass either a
+    prebuilt 1-D ``mesh`` or a ``shards`` count (a host mesh over the first
+    ``shards`` devices is built for you).
     """
     if mesh is None:
         if shards is None:
             raise ValueError("pass mesh= or shards=")
         mesh = host_mesh(shards)
-    if not (len(q_seq) == len(tokens_seq) == len(block_tables_seq) == len(base_lengths)):
-        raise ValueError("need one (queries, tokens, table, base_length) per session")
-    if not len(tokens_seq):
-        raise ValueError("need at least one session")
-    ks = [len(t) for t in tokens_seq]
-    for qi, k in zip(q_seq, ks):
-        if qi.shape[0] != k + 1:
-            raise ValueError(f"queries must be [K_i+1, H, hd]; got {qi.shape} for K_i={k}")
-    B, kmax = len(ks), max(max(ks, default=0), 1)
-    Bp = batch_rows(B, bucket)
-    Kp = _next_pow2(kmax) if bucket else kmax
-    H, hd = q_seq[0].shape[1], q_seq[0].shape[2]
-    qpad = np.zeros((Bp, Kp + 1, H, hd), np.float32)
-    tokens = np.zeros((Bp, Kp), np.int32)
-    nd = np.zeros((Bp,), np.int32)
-    lengths = np.zeros((Bp, Kp + 1), np.int32)
-    for i, (qi, tk, k, base) in enumerate(zip(q_seq, tokens_seq, ks, base_lengths)):
-        qpad[i, : k + 1] = np.asarray(qi, np.float32)
-        tokens[i, :k] = np.asarray(tk, np.int32)
-        nd[i] = k
-        lengths[i, : k + 1] = int(base) + np.arange(k + 1)
-    tables = pad_block_tables(
-        block_tables_seq, batch_pad=Bp, bucket=bucket, pad_id=pad_page_id
+    ks, tables, lengths, tokens, nd = ragged_launch_args(
+        tokens_seq, block_tables_seq, base_lengths, bucket=bucket, pad_page_id=pad_page_id
     )
     na, corr, logp = spec_verify_sharded(
-        jnp.asarray(qpad),
+        q,
         k_pages,
         v_pages,
         w,
@@ -432,4 +416,4 @@ def spec_verify_sharded_batched(
         quant=quant,
     )
     na, corr, logp = np.asarray(na), np.asarray(corr), np.asarray(logp)
-    return [(int(na[i, 0]), int(corr[i, 0]), logp[i, : ks[i]]) for i in range(B)]
+    return [(int(na[i, 0]), int(corr[i, 0]), logp[i, :k]) for i, k in enumerate(ks)]

@@ -42,6 +42,7 @@ from repro.kernels.spec_verify import (
     spec_verify_fused_batched,
     spec_verify_tree_batched,
 )
+from repro.kernels.spec_verify.ops import padded_dims
 
 KEY = jax.random.PRNGKey(23)
 
@@ -128,6 +129,25 @@ class RaggedCase:
     @property
     def ks(self) -> List[int]:
         return [len(t) for t in self.tok_seq]
+
+    @property
+    def q(self) -> np.ndarray:
+        """The sessions' queries padded for the ragged entries' launch."""
+        return pad_queries(self.q_seq)
+
+
+def pad_queries(q_seq: Sequence, bucket: bool = True) -> np.ndarray:
+    """Per-session ``[K_i+1, H, hd]`` queries -> the padded ``[Bp, Kp+1, H, hd]``.
+
+    The host-side form of what a verify backend draws on the device: row
+    ``i`` holds session ``i``'s positions first, zeros after; pad rows zero.
+    """
+    Bp, Kp = padded_dims([len(q) - 1 for q in q_seq], bucket)
+    H, hd = q_seq[0].shape[1:]
+    out = np.zeros((Bp, Kp + 1, H, hd), np.float32)
+    for i, q in enumerate(q_seq):
+        out[i, : len(q)] = np.asarray(q, np.float32)
+    return out
 
 
 def make_ragged_case(
@@ -268,9 +288,10 @@ def fused_backend(quantize=None, impl="ref", num_blocks=16, shards=None):
     )
     w = np.asarray(jax.random.normal(jax.random.fold_in(KEY, 77), (H * hd, V)) * 4, np.float32)
 
-    def query_fn(session, tokens):
-        k = jax.random.fold_in(jax.random.fold_in(KEY, 88), session * 131 + len(tokens))
-        return np.asarray(jax.random.normal(k, (len(tokens) + 1, H, hd)), np.float32)
+    def query_fn(session, tokens, k=None):
+        n = len(tokens) if k is None else k
+        draw = jax.random.fold_in(jax.random.fold_in(KEY, 88), session * 131 + n)
+        return jax.random.normal(draw, (len(tokens) + 1, H, hd))
 
     kw = dict(kv_pool=pool, query_fn=query_fn, lm_head=w, impl=impl, block_v=256)
     if shards is None:
@@ -389,7 +410,7 @@ def assert_paths_agree(
     """
     ks = case.ks
     pivot = spec_verify_fused_batched(
-        case.q_seq, case.tok_seq, case.tables_seq, case.base,
+        case.q, case.tok_seq, case.tables_seq, case.base,
         case.k_pages, case.v_pages, case.w,
         impl=impl, block_v=block_v, pad_page_id=case.sentinel_page, quant=case.quant,
     )
@@ -434,7 +455,7 @@ def assert_paths_agree(
         from repro.sharding.spec_verify import spec_verify_sharded_batched
 
         sharded = spec_verify_sharded_batched(
-            case.q_seq, case.tok_seq, case.tables_seq, case.base,
+            case.q, case.tok_seq, case.tables_seq, case.base,
             case.k_pages, case.v_pages, case.w,
             shards=n, block_v=block_v, pad_page_id=case.sentinel_page, quant=case.quant,
         )

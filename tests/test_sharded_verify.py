@@ -134,13 +134,13 @@ def test_sharded_int8_planes_travel_with_kv():
 
     case = make_ragged_case([4, 2, 1], Hkv=2, gqa=2, V=256, seed=17, quantize="int8")
     pivot = spec_verify_fused_batched(
-        case.q_seq, case.tok_seq, case.tables_seq, case.base,
+        case.q, case.tok_seq, case.tables_seq, case.base,
         case.k_pages, case.v_pages, case.w,
         impl="ref", block_v=256, pad_page_id=case.sentinel_page, quant=case.quant,
     )
     for n in (2, 4):
         got = spec_verify_sharded_batched(
-            case.q_seq, case.tok_seq, case.tables_seq, case.base,
+            case.q, case.tok_seq, case.tables_seq, case.base,
             case.k_pages, case.v_pages, case.w,
             shards=n, block_v=256, pad_page_id=case.sentinel_page, quant=case.quant,
         )
@@ -346,9 +346,10 @@ def test_serve_round_trip_stream_invariant_under_shards():
     H, hd, V = 2, 16, 512
     w = np.asarray(jax.random.normal(jax.random.PRNGKey(1), (H * hd, V)) * 6, np.float32)
 
-    def query_fn(session, tokens):
-        k = jax.random.fold_in(jax.random.PRNGKey(2), session * 997 + len(tokens))
-        return np.asarray(jax.random.normal(k, (len(tokens) + 1, H, hd)), np.float32)
+    def query_fn(session, tokens, k=None):
+        n = len(tokens) if k is None else k
+        draw = jax.random.fold_in(jax.random.PRNGKey(2), session * 997 + n)
+        return jax.random.normal(draw, (len(tokens) + 1, H, hd))
 
     def once(shards):
         clock = VirtualClock()

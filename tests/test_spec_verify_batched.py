@@ -11,7 +11,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from strategies import fused_backend, ragged_logits_requests as _ragged_requests
+from strategies import (
+    assert_ragged_match,
+    fused_backend,
+    pad_queries,
+    ragged_logits_requests as _ragged_requests,
+)
 
 from repro.kernels.spec_verify import (
     spec_verify,
@@ -468,7 +473,7 @@ def test_fused_backend_reused_session_id_refills_from_scratch():
 
     backend = SpecVerifyBackend(
         fused=True, kv_pool=pool, kv_fn=kv_fn, lm_head=np.ones((H * hd, V), np.float32),
-        query_fn=lambda s, t: np.zeros((len(t) + 1, H, hd), np.float32), impl="ref",
+        query_fn=lambda s, t, k=None: np.zeros((len(t) + 1, H, hd), np.float32), impl="ref",
     )
     pool.create(7)
     pool.append(7, 8)
@@ -520,9 +525,10 @@ def test_fused_backend_full_serve_round_trip():
     H, hd, V = 2, 16, 512
     w = np.asarray(jax.random.normal(jax.random.PRNGKey(1), (H * hd, V)) * 6, np.float32)
 
-    def query_fn(session, tokens):
-        k = jax.random.fold_in(jax.random.PRNGKey(2), session * 997 + len(tokens))
-        return np.asarray(jax.random.normal(k, (len(tokens) + 1, H, hd)), np.float32)
+    def query_fn(session, tokens, k=None):
+        n = len(tokens) if k is None else k
+        draw = jax.random.fold_in(jax.random.PRNGKey(2), session * 997 + n)
+        return jax.random.normal(draw, (len(tokens) + 1, H, hd))
 
     def once(quantize):
         clock = VirtualClock()
@@ -568,9 +574,10 @@ def test_fused_serve_shared_prefix_materialized_once_and_stays_shared():
     H, hd, V = 2, 16, 512
     w = np.asarray(jax.random.normal(jax.random.PRNGKey(3), (H * hd, V)) * 6, np.float32)
 
-    def query_fn(session, tokens):
-        k = jax.random.fold_in(jax.random.PRNGKey(4), session * 997 + len(tokens))
-        return np.asarray(jax.random.normal(k, (len(tokens) + 1, H, hd)), np.float32)
+    def query_fn(session, tokens, k=None):
+        n = len(tokens) if k is None else k
+        draw = jax.random.fold_in(jax.random.PRNGKey(4), session * 997 + n)
+        return jax.random.normal(draw, (len(tokens) + 1, H, hd))
 
     clock = VirtualClock()
     pool = PagedKVPool(num_blocks=256, block_size=8, n_layers=1, n_kv_heads=H, head_dim=hd)
@@ -617,9 +624,10 @@ def test_verifier_counts_page_writes_from_admission_through_verify():
     H, hd, V = 2, 16, 256
     w = np.asarray(jax.random.normal(jax.random.PRNGKey(3), (H * hd, V)) * 6, np.float32)
 
-    def query_fn(session, tokens):
-        k = jax.random.fold_in(jax.random.PRNGKey(4), session * 997 + len(tokens))
-        return np.asarray(jax.random.normal(k, (len(tokens) + 1, H, hd)), np.float32)
+    def query_fn(session, tokens, k=None):
+        n = len(tokens) if k is None else k
+        draw = jax.random.fold_in(jax.random.PRNGKey(4), session * 997 + n)
+        return jax.random.normal(draw, (len(tokens) + 1, H, hd))
 
     clock = VirtualClock()
     pool = PagedKVPool(num_blocks=64, block_size=8, n_layers=1, n_kv_heads=H, head_dim=hd)
@@ -664,16 +672,17 @@ def test_verifier_traces_kv_synthesis_and_counts_launched_rows(monkeypatch):
     real = ops.spec_verify_fused
 
     def spy(q, *args, **kwargs):
-        launched.append(int(q.shape[0]))
+        launched.append(int(np.shape(args[-1])[0]))  # n_drafted [Bp]
         return real(q, *args, **kwargs)
 
     monkeypatch.setattr(ops, "spec_verify_fused", spy)
     H, hd, V = 2, 16, 256
     w = np.asarray(jax.random.normal(jax.random.PRNGKey(3), (H * hd, V)) * 6, np.float32)
 
-    def query_fn(session, tokens):
-        k = jax.random.fold_in(jax.random.PRNGKey(4), session * 997 + len(tokens))
-        return np.asarray(jax.random.normal(k, (len(tokens) + 1, H, hd)), np.float32)
+    def query_fn(session, tokens, k=None):
+        n = len(tokens) if k is None else k
+        draw = jax.random.fold_in(jax.random.PRNGKey(4), session * 997 + n)
+        return jax.random.normal(draw, (len(tokens) + 1, H, hd))
 
     clock = VirtualClock()
     tracer = Tracer()
@@ -702,6 +711,8 @@ def test_verifier_traces_kv_synthesis_and_counts_launched_rows(monkeypatch):
     clock.run(body)
     assert launched and server.stats["batched_calls"] == len(launched)
     assert backend.stats["kernel_rows"] == server.stats["kernel_rows"] == sum(launched)
+    # One query draw per fused dispatch, counted and traced once each.
+    assert backend.stats["query_launches"] == server.stats["query_launches"] == len(launched)
     assert any(rows > 1 for rows in launched)
     assert all(rows & (rows - 1) == 0 for rows in launched)  # pow2 buckets
 
@@ -715,3 +726,147 @@ def test_verifier_traces_kv_synthesis_and_counts_launched_rows(monkeypatch):
         assert verify.name == "verify"
         assert s.get("dispatch") == verify.get("dispatch") is not None
         assert verify.t0 <= s.t0 <= s.t1 <= verify.t1
+    queries = [by_sid[s.parent].get("dispatch") for s in spans if s.name == "query"]
+    assert sorted(queries) == sorted(s.get("dispatch") for s in spans if s.name == "verify")
+
+
+# --------------------------------------------------------------------------- #
+# The device query draw: one per fused round, one program for them all
+# --------------------------------------------------------------------------- #
+WIDTHS = {"gqa": (4, 2), "mha": (3, 3)}  # (query heads, KV heads)
+
+
+def _draw_backend(widths, impl="ref", seed=5):
+    """A fused backend at ``widths`` whose seeded ``query_fn`` is the launcher's."""
+    from repro.models.paged_kv import PagedKVPool
+    from repro.runtime import SpecVerifyBackend
+
+    H, Hkv = WIDTHS[widths]
+    hd, V = 8, 256
+    key = jax.random.PRNGKey(seed)
+    pool = PagedKVPool(num_blocks=64, block_size=4, n_layers=1, n_kv_heads=Hkv, head_dim=hd)
+    w = np.asarray(jax.random.normal(jax.random.fold_in(key, 77), (H * hd, V)) * 4, np.float32)
+
+    queries = jax.random.fold_in(key, 88)
+
+    def query_fn(session, tokens, k=None):
+        n = len(tokens) if k is None else k
+        draw = jax.random.fold_in(queries, session * 131 + n)
+        return jax.random.normal(draw, (len(tokens) + 1, H, hd))
+
+    backend = SpecVerifyBackend(
+        fused=True, kv_pool=pool, query_fn=query_fn, lm_head=w, impl=impl, block_v=256
+    )
+    return backend, pool
+
+
+def _round(pool, ks, base=20, first=0):
+    """Requests with ``ks`` drafts, each session at ``base`` committed tokens
+    plus the round's slots (the dispatcher's metadata append)."""
+    reqs = []
+    for i, k in enumerate(ks):
+        s = first + i
+        pool.create(s)
+        pool.append(s, base + k + 1)
+        reqs.append((s, [(7 * s + j) % 256 for j in range(k)], [0.9] * k))
+    return reqs
+
+
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+@pytest.mark.parametrize("ks", [[8, 5, 1, 8, 3], [3]], ids=["mixed-bp8", "solo"])
+def test_query_draw_matches_query_fn_per_row(widths, ks):
+    """The batched draw holds ``query_fn(s, tokens)`` in each row's first
+    K+1 positions, bit for bit, and zeros after them and in pad rows."""
+    from repro.runtime.server import DRAW_DRAFTS, DRAW_ROWS
+
+    backend, pool = _draw_backend(widths)
+    reqs = [(100 + 3 * i, [(5 * i + j) % 256 for j in range(k)], None) for i, k in enumerate(ks)]
+    q = backend.draw_queries(reqs)
+    assert isinstance(q, jax.Array)  # drawn on the device
+    q = np.asarray(q)
+    H, _ = WIDTHS[widths]
+    assert q.shape == (DRAW_ROWS, DRAW_DRAFTS + 1, H, 8) and q.dtype == np.float32
+    for i, (s, toks, _) in enumerate(reqs):
+        np.testing.assert_array_equal(q[i, : len(toks) + 1], np.asarray(backend.query_fn(s, toks)))
+        assert not q[i, len(toks) + 1 :].any()
+    assert not q[len(reqs) :].any()
+    assert backend.stats["query_launches"] == 1
+
+
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+@pytest.mark.parametrize("impl", ["ref", "interpret"])
+def test_fused_backend_draw_matches_per_session_queries(impl, widths):
+    """A round through the backend (queries drawn on the device) gives the
+    ``(n_accepted, correction, logp)`` of the same launch fed each
+    session's eager ``query_fn`` queries, padded on the host."""
+    from repro.kernels.spec_verify import spec_verify_fused_batched
+
+    backend, pool = _draw_backend(widths, impl=impl)
+    reqs = _round(pool, [4, 1, 3])
+    got = backend.fused_verify(reqs)
+    inputs = backend.fused_inputs(reqs)  # pages already filled: no writes
+    inputs["q"] = pad_queries([backend.query_fn(s, t) for (s, t, _) in reqs])
+    want = spec_verify_fused_batched(**inputs, impl=impl, block_v=backend.block_v)
+    assert_ragged_match(got, want, exact_logp=True, label=f"{impl}/{widths}")
+    assert backend.stats["query_launches"] == 2  # one per fused round: verify, then inputs
+
+
+def test_mixed_draft_counts_compile_nothing_new():
+    """Once every ``(Bp, Kp)`` has run with K = Kp, as the benchmark's
+    warm-up drives them, a round with mixed draft counts adds no entry to
+    the draw's or the launch's jit cache.  The draw compiles once for all
+    of them."""
+    import repro.kernels.spec_verify.ops as ops
+
+    backend, pool = _draw_backend("gqa")
+
+    def draw_programs():
+        return sum(draw._cache_size() for draw, _ in backend._draws.values())
+
+    first = 0
+    dispatches = 0
+    for bp in (1, 2, 4):
+        for kp in (1, 2, 4, 8):
+            reqs = _round(pool, [kp] * bp, first=first)
+            backend.fused_verify(reqs)
+            dispatches += 1
+            for s, _, _ in reqs:
+                pool.release(s)
+            first += bp
+    launches = ops.spec_verify_fused._cache_size()
+    assert list(backend._draws) == [8] and draw_programs() == 1
+    reqs = _round(pool, [8, 5, 3], first=first)
+    backend.fused_verify(reqs)
+    dispatches += 1
+    assert ops.spec_verify_fused._cache_size() == launches
+    assert list(backend._draws) == [8] and draw_programs() == 1
+    assert backend.stats["query_launches"] == dispatches
+
+
+def test_draw_program_is_the_same_for_every_seed():
+    """The arrays ``query_fn`` closes over (its seeded key) are arguments of
+    the draw, not constants in its program: two seeds lower to the same
+    program text, so one persistent compile-cache entry serves every seed."""
+    from repro.runtime.server import DRAW_DRAFTS, DRAW_ROWS
+
+    texts = []
+    for seed in (5, 2**31 + 11):
+        backend, pool = _draw_backend("gqa", seed=seed)
+        draw, consts = backend._draw(DRAW_DRAFTS)
+        ids = jax.ShapeDtypeStruct((DRAW_ROWS, DRAW_DRAFTS + 2), jnp.int32)
+        texts.append(draw.lower(consts, ids).as_text())
+    assert texts[0] == texts[1]
+
+
+def test_draw_refuses_non_partitionable_threefry():
+    """Without partitionable threefry a wider ``jax.random`` draw does not
+    start with the draw at the round's own K: the backend refuses."""
+    backend, pool = _draw_backend("gqa")
+    was = jax.config.jax_threefry_partitionable
+    jax.config.update("jax_threefry_partitionable", False)
+    try:
+        with pytest.raises(RuntimeError, match="jax_threefry_partitionable"):
+            backend.draw_queries([(0, [1, 2], None)])
+    finally:
+        jax.config.update("jax_threefry_partitionable", was)
+    assert backend.draw_queries([(0, [1, 2], None)]).shape[1] == 9  # DRAW_DRAFTS + 1

@@ -23,6 +23,7 @@ from strategies import (
     assert_triples_match as _assert_fused_matches,
     composed_verify as _composed,
     make_rect_case as _make_case,
+    pad_queries,
     rect_geometries,
 )
 
@@ -149,7 +150,7 @@ def test_fused_batched_ragged_from_pool():
         base.append(T - k)
     w = jax.random.normal(keys[-1], (H * hd, V))
     out = spec_verify_fused_batched(
-        q_seq, tok_seq, tables_seq, base,
+        pad_queries(q_seq), tok_seq, tables_seq, base,
         pool.k_pages[0], pool.v_pages[0], w,
         impl="interpret", block_v=256, pad_page_id=pool.sentinel_page,
     )
@@ -187,7 +188,7 @@ def test_fused_padded_lanes_only_touch_sentinel():
     tables_seq = [list(pool.table(0))]
     w = jax.random.normal(keys[3], (H * hd, V))
     clean = spec_verify_fused_batched(
-        q_seq, tok_seq, tables_seq, [4], pool.k_pages[0], pool.v_pages[0], w,
+        pad_queries(q_seq), tok_seq, tables_seq, [4], pool.k_pages[0], pool.v_pages[0], w,
         impl="interpret", block_v=256, pad_page_id=pool.sentinel_page,
     )
     owned = set(tables_seq[0]) | {pool.sentinel_page}
@@ -198,7 +199,7 @@ def test_fused_padded_lanes_only_touch_sentinel():
             kp[p] = np.nan
             vp[p] = np.nan
     poisoned = spec_verify_fused_batched(
-        q_seq, tok_seq, tables_seq, [4], jnp.asarray(kp), jnp.asarray(vp), w,
+        pad_queries(q_seq), tok_seq, tables_seq, [4], jnp.asarray(kp), jnp.asarray(vp), w,
         impl="interpret", block_v=256, pad_page_id=pool.sentinel_page,
     )
     assert clean[0][0] == poisoned[0][0] and clean[0][1] == poisoned[0][1]

@@ -10,7 +10,8 @@ The fused entry compiles at minicpm-2b widths too (36 query heads, all of
 them KV heads, vocab 122753), at the edge window's K+1 = 9 rows, where its
 LM-head tile needs more than the default scoped VMEM.  The paged pool's
 jitted writes are compiled at both 40-layer pools as well, to check that
-they update the donated buffers in place.
+they update the donated buffers in place, and the serving backend's query
+draw with the launch that reads it, at both widths.
 
 The topology is described inside a fixture, never at import time: only one
 process may load the TPU library, and every test worker imports this file.
@@ -168,3 +169,41 @@ def test_granite_launch_keeps_block_v_512_and_the_default_vmem(one_chip, quant):
     assert f"tensor<{H}x{HD}x{VP}xf32>" in texts["granite"]
     assert "scoped_memory_configs" not in texts["granite"]
     assert "scoped_memory_configs" in texts["minicpm"]
+
+
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+def test_query_draw_and_its_launch_compile_for_v5e(one_chip, widths):
+    """The serving backend's query draw (the launcher's seeded target,
+    ``DRAW_ROWS`` rows of 8 drafts) compiles for a v5e with the target's
+    key an argument, and the fused launch compiles reading the first 8 of
+    those rows."""
+    import numpy as np
+
+    from repro.models.paged_kv import PagedKVPool
+    from repro.runtime import SpecVerifyBackend
+    from repro.runtime.server import DRAW_ROWS
+
+    h, hkv, v = WIDTHS[widths]
+    key = jax.random.PRNGKey(3)
+    queries = jax.random.fold_in(key, 88)
+
+    def query_fn(session, tokens, k=None):
+        n = len(tokens) if k is None else k
+        draw = jax.random.fold_in(queries, session * 131 + n)
+        return jax.random.normal(draw, (len(tokens) + 1, h, HD))
+
+    pool = PagedKVPool(num_blocks=4, block_size=BS, n_layers=1, n_kv_heads=hkv, head_dim=HD)
+    backend = SpecVerifyBackend(fused=True, kv_pool=pool, query_fn=query_fn,
+                                lm_head=np.zeros((h * HD, 8), np.float32), impl="pallas")
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    draw, consts = backend._draw(WINDOW)
+    lowered = draw.lower(
+        tuple(sd(c.shape, c.dtype) for c in consts), sd((DRAW_ROWS, WINDOW + 2), jnp.int32)
+    )
+    a, b = (int(x) for x in np.asarray(jax.random.key_data(queries)).ravel())
+    assert f"dense<[{a}, {b}]>" not in lowered.as_text()  # the key is an argument
+    lowered.compile()
+    args, _ = _fused_args(sd, (h, hkv, v), WINDOW, False)
+    fused = functools.partial(spec_verify_fused, impl="pallas", block_v=DEFAULT_BV)
+    launch = jax.jit(fused).lower(sd((DRAW_ROWS, WINDOW + 1, h, HD), jnp.float32), *args[1:])
+    assert "tpu_custom_call" in launch.compile().as_text()

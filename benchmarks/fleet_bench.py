@@ -133,14 +133,15 @@ def _sharded_spec_backend(shards: int, seed: int):
 
     A tensor-mode ``PagedKVPool`` (pages partitioned per shard on the head
     axis) plus a seeded deterministic target (queries + LM head) behind
-    ``ShardedSpecVerifyBackend`` — the same geometry the serve launcher's
+    ``SpecVerifyBackend(mesh=...)`` — the same geometry the serve launcher's
     ``--backend spec`` uses, sized for the fleet's session counts.  The
     returned backend carries the synthetic ``verify_time`` so the batched
     coalescing window is identical to the simulated backends'.
     """
     import jax
 
-    from repro.runtime import ShardedSpecVerifyBackend
+    from repro.runtime import SpecVerifyBackend
+    from repro.sharding.shardctx import host_mesh
 
     H, hd, bs, V = 2, 8, 4, 256
     pool = PagedKVPool(
@@ -156,9 +157,9 @@ def _sharded_spec_backend(shards: int, seed: int):
         draw = jax.random.fold_in(queries, session * 131 + n)
         return jax.random.normal(draw, (len(tokens) + 1, H, hd))
 
-    backend = ShardedSpecVerifyBackend(
-        shards=shards, kv_pool=pool, query_fn=query_fn, lm_head=w,
-        impl="ref", block_v=256,
+    backend = SpecVerifyBackend(
+        kv_pool=pool, query_fn=query_fn, lm_head=w, impl="ref", block_v=256,
+        mesh=host_mesh(shards),
     )
     backend.verify_time = 0.080  # align the coalescing window with SyntheticBackend
     return backend, pool
@@ -215,7 +216,7 @@ def run_fleet(
     assert the committed streams are fault-invariant.
 
     ``shards=N`` swaps in the REAL sharded fused verifier
-    (``ShardedSpecVerifyBackend`` over an N-device host mesh, with a
+    (``SpecVerifyBackend`` over an N-device host mesh, with a
     tensor-mode paged KV pool partitioned on the head axis) instead of the
     simulated backend — the dispatcher, clients, and the rest of the
     harness run unchanged, so committed streams at different shard counts

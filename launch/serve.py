@@ -56,7 +56,7 @@ pool, with a seeded synthetic target at the widths of ``--arch`` (default
 changed behind your back: ``pallas`` (default) is the compiled TPU kernel,
 ``interpret`` runs it under the CPU interpreter, ``ref`` is the pure-JAX
 oracle.  ``--shards N`` shards the target forward across an N-device mesh
-(``ShardedSpecVerifyBackend``: head-parallel pages, one ``shard_map``
+(``SpecVerifyBackend(mesh=...)``: head-parallel pages, one ``shard_map``
 launch per dispatch, XLA code rather than Pallas, so it takes ``--impl
 ref``).  On a CPU host, export
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` first so the mesh
@@ -186,16 +186,17 @@ def _spec_backend(args):
     A tensor-mode paged KV pool (``n_layers`` deep, ``KV_BLOCKS`` pages of
     ``PAGE_SIZE`` tokens) and a seeded deterministic target
     (per-position queries + LM head ``[n_heads * head_dim, vocab]``) drive
-    ``SpecVerifyBackend(fused=True, impl=--impl)`` — one fused Pallas
-    launch per dispatch — or, with ``--shards N > 1``,
-    ``ShardedSpecVerifyBackend`` over N devices.  The dispatcher and the
-    wire protocol are oblivious to both choices.
+    ``SpecVerifyBackend(impl=--impl)`` — one fused Pallas launch per
+    dispatch — or, with ``--shards N > 1``, its sharded launch over a mesh
+    of N devices.  The dispatcher and the wire protocol are oblivious to
+    both choices.
     """
     import jax
 
     from repro.configs import get_config
     from repro.models.paged_kv import PagedKVPool
-    from repro.runtime import ShardedSpecVerifyBackend, SpecVerifyBackend
+    from repro.runtime import SpecVerifyBackend
+    from repro.sharding.shardctx import host_mesh
 
     cfg = get_config(args.arch)
     H, Hkv, hd, V = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.vocab_size
@@ -218,11 +219,8 @@ def _spec_backend(args):
         draw = jax.random.fold_in(queries, session * 131 + n)
         return jax.random.normal(draw, (len(tokens) + 1, H, hd))
 
-    kw = dict(kv_pool=pool, query_fn=query_fn, lm_head=w, impl=args.impl)
-    if args.shards > 1:
-        backend = ShardedSpecVerifyBackend(shards=args.shards, **kw)
-    else:
-        backend = SpecVerifyBackend(fused=True, **kw)
+    mesh = host_mesh(args.shards) if args.shards > 1 else None
+    backend = SpecVerifyBackend(kv_pool=pool, query_fn=query_fn, lm_head=w, impl=args.impl, mesh=mesh)
     return backend, {"kv_pool": pool}
 
 
@@ -282,7 +280,7 @@ def stream_session(args, session: int):
     """Dial ``args.connect`` as ``session``, stream ``--tokens`` tokens, detach.
 
     Returns ``(session id granted by the server, committed stream, client
-    stats)``; the edge role and ``chip_smoke.py`` both stream through it.
+    stats)``; the edge role streams through it.
     """
     host, port = args.connect
     transport = connect_transport(
@@ -339,7 +337,7 @@ def run_demo(args) -> int:
 
 
 def build_parser() -> argparse.ArgumentParser:
-    """The launcher's command line (shared with ``chip_smoke.py``)."""
+    """The launcher's command line."""
     p = argparse.ArgumentParser(description=__doc__, formatter_class=argparse.RawDescriptionHelpFormatter)
     role = p.add_mutually_exclusive_group(required=True)
     role.add_argument("--listen", type=_host_port, metavar="HOST:PORT", help="run the cloud verifier")

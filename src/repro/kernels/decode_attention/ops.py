@@ -9,7 +9,7 @@ TPU):
   per-lane block tables (``models/paged_kv.py``), the serving layout where
   sessions share prefix pages copy-on-write.  Ragged python block tables are
   padded through ``kernels.spec_verify.pad_block_tables`` (the same pow2
-  bucketing as the batched NAV entries, pad id 0 = valid page, masked by
+  rounding as the batched NAV entries, pad id 0 = valid page, masked by
   ``lengths``).
 """
 
@@ -99,7 +99,6 @@ def paged_decode_attention(
     *,
     window: int = 1 << 30,
     impl: str,
-    bucket: bool = True,
     quant=None,  # (k_scale, k_zero, v_scale, v_zero), each [P, bs, Hkv] f32
     pad_page_id: int = 0,
 ) -> jax.Array:
@@ -107,7 +106,7 @@ def paged_decode_attention(
 
     ``block_tables`` may be a rectangular ``[B, G]`` int32 array (e.g. from
     ``PagedKVPool.table(sid, pad_to=G)``) or ragged per-lane page-id lists,
-    which are padded here with the serving bucketing (``pad_block_tables``)
+    which are padded here as the serving entries pad them (``pad_block_tables``)
     using ``pad_page_id`` — pass the pool's ``sentinel_page`` so padded
     lanes never DMA another session's pages.  Bit-exact vs the flat entry on
     the same logical cache: ``impl='ref'`` by construction (page gather +
@@ -119,9 +118,7 @@ def paged_decode_attention(
     bounded per ``docs/kernels.md`` §7.
     """
     if isinstance(block_tables, (list, tuple)):
-        block_tables = pad_block_tables(
-            block_tables, batch_pad=len(block_tables), bucket=bucket, pad_id=pad_page_id
-        )
+        block_tables = pad_block_tables(block_tables, batch_pad=len(block_tables), pad_id=pad_page_id)
     block_tables = jnp.asarray(block_tables, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
     if quant is not None:

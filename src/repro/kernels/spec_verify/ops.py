@@ -1,12 +1,14 @@
-"""Jit'd wrappers for the fused NAV verify kernel.
+"""Jit'd wrappers for the NAV verify kernels.
 
 ``spec_verify`` is the rectangular entry ([B, K+1, V] with per-row
-``n_drafted``).  ``spec_verify_batched`` is the serving entry used by the
-continuous-batching cloud verifier (runtime/server.py): it takes **ragged**
-per-session requests (different draft lengths K_i), pads them into one
-[B', Kmax+1, V] launch, and unpacks per-session results.  Shapes are
-bucketed to powers of two so a serving process compiles a handful of
-variants instead of one per (B, Kmax) pair.
+``n_drafted``); ``spec_verify_batched`` takes **ragged** per-session logits
+(different draft lengths K_i), pads them into one [B', Kmax+1, V] launch,
+and unpacks per-session results.  ``spec_verify_fused`` folds the paged
+target attention and the LM head into the launch; the served backend
+(runtime/server.py) packs its ragged rounds with ``ragged_launch_args`` and
+unpacks them with ``ragged_results``.  Shapes round up to powers of two so
+a serving process compiles a handful of variants instead of one per
+(B, Kmax) pair.
 
 Padded rows/positions are provably inert (see kernel.py "padding
 invariants"): acceptance is masked by ``pos < n_drafted``, the correction
@@ -17,7 +19,7 @@ before returning.
 from __future__ import annotations
 
 import functools
-from typing import Callable, List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -45,16 +47,49 @@ def _next_pow2(n: int) -> int:
     return 1 << max(int(n) - 1, 0).bit_length()
 
 
-def batch_rows(n: int, bucket: bool = True) -> int:
+def batch_rows(n: int) -> int:
     """Rows a batched launch gives ``n`` sessions, pad rows included."""
-    return _next_pow2(n) if bucket else int(n)
+    return _next_pow2(n)
 
 
-def check_queries(q, draft_tokens) -> None:
-    """Raise unless ``q`` is ``[R, P, H, hd]``, ``R >= B``, ``P >= K+1``, for ``draft_tokens [B, K]``."""
+def padded_dims(ks: Sequence[int]) -> Tuple[int, int]:
+    """``(Bp, Kp)`` of the launch that carries sessions drafting ``ks`` tokens.
+
+    Pad rows and draft positions round up to powers of two, so a serving
+    process compiles a handful of launch shapes; a verify backend draws its
+    queries at least this wide.
+    """
+    kmax = max(max(ks, default=0), 1)
+    return batch_rows(len(ks)), _next_pow2(kmax)
+
+
+def vocab_tiles(V: int, block_v: int) -> Tuple[int, int]:
+    """``(bv, Vp)``: the vocab tile of a ``block_v`` launch over ``V`` ids,
+    and ``V`` padded up to a whole number of those tiles."""
+    bv = min(block_v, _next_pow2(V))
+    return bv, -(-V // bv) * bv
+
+
+def fused_operands(q, draft_tokens, k_pages, v_pages, quant=None):
+    """The fused verify's query and page operands for ``draft_tokens [B, K]``.
+
+    Raises unless ``q`` is ``[R, P, H, hd]`` with ``R >= B`` and ``P >=
+    K+1``, slices it to the round's ``[B, K+1]``, and repeats the KV heads
+    of the pages (and of the int8 planes in ``quant``) up to the query heads
+    (GQA).  Shared by ``spec_verify_fused`` and the sharded launch.
+    """
     B, K = draft_tokens.shape
     if q.ndim != 4 or q.shape[0] < B or q.shape[1] < K + 1:
         raise ValueError(f"queries must be [R>=B, P>=K+1, H, hd] with B={B}, K={K}; got {q.shape}")
+    q = q[:B, : K + 1]
+    H = q.shape[2]
+    n_kv = k_pages.shape[2]
+    if n_kv != H:
+        k_pages = jnp.repeat(k_pages, H // n_kv, axis=2)
+        v_pages = jnp.repeat(v_pages, H // n_kv, axis=2)
+        if quant is not None:
+            quant = tuple(jnp.repeat(p, H // n_kv, axis=2) for p in quant)
+    return q, k_pages, v_pages, quant
 
 
 @functools.partial(
@@ -91,20 +126,11 @@ def spec_verify_fused(
     fixed least shape): the launch reads ``q[:B, :K+1]``.  Bit-exact vs the
     unfused composition per ``tests/test_spec_verify_fused.py``.
     """
-    check_queries(q, draft_tokens)
-    q = q[: draft_tokens.shape[0], : draft_tokens.shape[1] + 1]
-    H = q.shape[2]
-    n_kv = k_pages.shape[2]
-    if n_kv != H:
-        k_pages = jnp.repeat(k_pages, H // n_kv, axis=2)
-        v_pages = jnp.repeat(v_pages, H // n_kv, axis=2)
-        if quant is not None:
-            quant = tuple(jnp.repeat(p, H // n_kv, axis=2) for p in quant)
+    q, k_pages, v_pages, quant = fused_operands(q, draft_tokens, k_pages, v_pages, quant)
     V = w.shape[1]
     if v_true is None:
         v_true = V
-    bv = min(block_v, _next_pow2(V))
-    Vp = -(-V // bv) * bv
+    bv, Vp = vocab_tiles(V, block_v)
     if Vp > V:  # zero columns; the kernels mask ids >= v_true to -1e30
         w = jnp.pad(w, ((0, 0), (0, Vp - V)))
     if impl == "ref":
@@ -127,14 +153,20 @@ def spec_verify_fused(
     )
 
 
-def padded_dims(ks: Sequence[int], bucket: bool = True) -> Tuple[int, int]:
-    """``(Bp, Kp)`` of the launch that carries sessions drafting ``ks`` tokens.
+def _pack_drafts(tokens_seq: Sequence) -> Tuple[List[int], np.ndarray, np.ndarray]:
+    """``(ks, tokens [Bp, Kp], n_drafted [Bp])`` of a ragged round.
 
-    Pad rows and draft positions follow the serving entries' pow2 bucketing;
-    a verify backend draws its queries at least this wide.
+    Session ``i`` fills row ``i``; pad rows and positions hold 0 and pad
+    rows draft nothing.
     """
-    kmax = max(max(ks, default=0), 1)
-    return batch_rows(len(ks), bucket), (_next_pow2(kmax) if bucket else kmax)
+    ks = [len(t) for t in tokens_seq]
+    Bp, Kp = padded_dims(ks)
+    tokens = np.zeros((Bp, Kp), np.int32)
+    for i, (tk, k) in enumerate(zip(tokens_seq, ks)):
+        tokens[i, :k] = np.asarray(tk, np.int32)
+    nd = np.zeros((Bp,), np.int32)
+    nd[: len(ks)] = ks
+    return ks, tokens, nd
 
 
 def ragged_launch_args(
@@ -142,33 +174,35 @@ def ragged_launch_args(
     block_tables_seq: Sequence,
     base_lengths: Sequence,
     *,
-    bucket: bool = True,
     pad_page_id: int = 0,
 ):
-    """Pad a ragged round's host-side arguments for one launch.
+    """Pad a ragged round's host-side arguments for one fused launch.
 
-    Shared by ``spec_verify_fused_batched`` and its sharded twin.  Returns
-    ``(ks, tables [Bp, Gp], lengths [Bp, Kp+1], tokens [Bp, Kp],
-    n_drafted [Bp])``; pad rows and positions see length 0 and draft
-    nothing, which makes them inert.
+    Returns ``(ks, tables [Bp, Gp], lengths [Bp, Kp+1], tokens [Bp, Kp],
+    n_drafted [Bp])``, the four arrays on the device in the order the
+    launches take them.  Position ``i`` of session ``s`` sees
+    ``base_lengths[s] + i``; pad rows and positions see length 0 and draft
+    nothing, which makes them inert.  Tables pad with ``pad_page_id``: pass
+    the pool's ``sentinel_page``.  ``ragged_results`` unpacks the launch.
     """
     if not (len(tokens_seq) == len(block_tables_seq) == len(base_lengths)):
         raise ValueError("need one (tokens, table, base_length) per session")
     if not len(tokens_seq):
         raise ValueError("need at least one session")
-    ks = [len(t) for t in tokens_seq]
-    Bp, Kp = padded_dims(ks, bucket)
-    tokens = np.zeros((Bp, Kp), np.int32)
-    nd = np.zeros((Bp,), np.int32)
+    ks, tokens, nd = _pack_drafts(tokens_seq)
+    Bp, Kp = tokens.shape
     lengths = np.zeros((Bp, Kp + 1), np.int32)
-    for i, (tk, k, base) in enumerate(zip(tokens_seq, ks, base_lengths)):
-        tokens[i, :k] = np.asarray(tk, np.int32)
-        nd[i] = k
+    for i, (k, base) in enumerate(zip(ks, base_lengths)):
         lengths[i, : k + 1] = int(base) + np.arange(k + 1)
-    tables = pad_block_tables(
-        block_tables_seq, batch_pad=Bp, bucket=bucket, pad_id=pad_page_id
-    )
-    return ks, tables, lengths, tokens, nd
+    tables = pad_block_tables(block_tables_seq, batch_pad=Bp, pad_id=pad_page_id)
+    return (ks, *(jnp.asarray(x) for x in (tables, lengths, tokens, nd)))
+
+
+def ragged_results(ks: Sequence[int], n_accepted, correction, logp) -> List[Tuple[int, int, np.ndarray]]:
+    """One padded launch's results as ``(n_accepted, correction, logp[:K_i])``
+    per session, in input order; pad rows are dropped."""
+    na, corr, logp = np.asarray(n_accepted), np.asarray(correction), np.asarray(logp)
+    return [(int(na[i, 0]), int(corr[i, 0]), logp[i, :k]) for i, k in enumerate(ks)]
 
 
 def spec_verify_fused_batched(
@@ -182,64 +216,47 @@ def spec_verify_fused_batched(
     *,
     impl: str,
     block_v: int = DEFAULT_BV,
-    bucket: bool = True,
     window: int = 1 << 30,
     pad_page_id: int = 0,
     quant=None,
 ) -> List[Tuple[int, int, np.ndarray]]:
-    """Ragged serving entry for the fused verify — one launch for B sessions.
+    """Ragged entry for the fused verify — one launch for B sessions.
 
-    The fused twin of ``spec_verify_batched``'s ``batched_logits_fn`` path,
-    with the forward folded INTO the verify launch.  The queries come
-    padded already, at least to the ``padded_dims`` of the round: ``[R, P,
-    H, hd]`` with ``R >= Bp`` and ``P >= Kp+1`` (session ``s`` in row
-    ``s``, its ``K_s+1`` positions first; pad rows and positions zero;
-    the launch reads ``[:Bp, :Kp+1]``).  The entry pads tokens, block tables (``pad_page_id`` — pass the
-    pool's ``sentinel_page``) and per-position lengths (position ``i`` of
-    session ``s`` sees ``base_lengths[s] + i``; pad rows/positions see 0,
-    making them inert) under the same pow2 bucketing, launches once, and unpacks
+    The queries come padded already, at least to the ``padded_dims`` of the
+    round: ``[R, P, H, hd]`` with ``R >= Bp`` and ``P >= Kp+1`` (session
+    ``s`` in row ``s``, its ``K_s+1`` positions first; pad rows and
+    positions zero; the launch reads ``[:Bp, :Kp+1]``).  The entry pads the
+    rest (``ragged_launch_args``; ``pad_page_id`` — pass the pool's
+    ``sentinel_page``), launches ``spec_verify_fused`` once, and unpacks
     ``(n_accepted, correction, logp[K_i])`` per session in input order.
     """
-    ks, tables, lengths, tokens, nd = ragged_launch_args(
-        tokens_seq, block_tables_seq, base_lengths, bucket=bucket, pad_page_id=pad_page_id
+    ks, *packed = ragged_launch_args(
+        tokens_seq, block_tables_seq, base_lengths, pad_page_id=pad_page_id
     )
-    na, corr, logp = spec_verify_fused(
-        q,
-        k_pages,
-        v_pages,
-        w,
-        jnp.asarray(tables),
-        jnp.asarray(lengths),
-        jnp.asarray(tokens),
-        jnp.asarray(nd),
-        impl=impl,
-        block_v=block_v,
-        window=window,
-        quant=quant,
+    out = spec_verify_fused(
+        q, k_pages, v_pages, w, *packed,
+        impl=impl, block_v=block_v, window=window, quant=quant,
     )
-    na, corr, logp = np.asarray(na), np.asarray(corr), np.asarray(logp)
-    return [(int(na[i, 0]), int(corr[i, 0]), logp[i, :k]) for i, k in enumerate(ks)]
+    return ragged_results(ks, *out)
 
 
-def pad_block_tables(
-    tables_seq: Sequence, *, batch_pad: int, bucket: bool = True, pad_id: int = 0
-) -> np.ndarray:
+def pad_block_tables(tables_seq: Sequence, *, batch_pad: int, pad_id: int = 0) -> np.ndarray:
     """Pad ragged per-session KV block tables into one ``[Bp, Gp]`` int32 array.
 
     The serving-side companion of the batched verify entries: a paged target
     forward (``kernels.decode_attention`` paged path) consumes one block
     table per admitted session, and those tables are ragged exactly like the
-    draft lengths.  They are padded with the SAME pow2 bucketing as the
-    logits batch (``batch_pad`` = the entry's ``Bp``) so a serving process
-    compiles one shape family for the fused forward+verify dispatch.  Pad
-    entries carry ``pad_id``; pass the pool's zero-filled ``sentinel_page``
-    (as the serving backend does) so padded lanes can only ever DMA the
-    sentinel — never a page owned by another session.  The legacy default 0
-    is a *live* page id and is only safe because attention masks pad
-    positions by ``lengths``; see ``docs/kernels.md``.
+    draft lengths.  ``Gp`` is the longest table rounded up to a power of two
+    and ``batch_pad`` rows (the launch's ``Bp``) are filled, so a serving
+    process compiles one shape family.  Pad entries carry ``pad_id``; pass
+    the pool's zero-filled ``sentinel_page`` (as the serving backend does)
+    so padded lanes can only ever DMA the sentinel — never a page owned by
+    another session.  The default 0 is a *live* page id and is only safe
+    because attention masks pad positions by ``lengths``; see
+    ``docs/kernels.md``.
     """
     gmax = max((len(t) for t in tables_seq), default=0)
-    Gp = max(_next_pow2(gmax) if bucket else gmax, 1)
+    Gp = max(_next_pow2(gmax), 1)
     out = np.full((batch_pad, Gp), pad_id, np.int32)
     for i, t in enumerate(tables_seq):
         if len(t):
@@ -247,88 +264,49 @@ def pad_block_tables(
     return out
 
 
+def _pack_logits(logits_seq: Sequence, ns: Sequence[int], rows: int, width: int, block_v: int):
+    """Per-session ``[n_i+1, V]`` logits in one ``[rows, width+1, Vp]`` array.
+
+    Returns it with the vocab tile ``bv``.  Pallas needs ``Vp % bv == 0``,
+    so the vocab pads with -1e30 lanes: inert, since they never win the
+    argmax, add 0 to the logsumexp, and no draft id can address them.
+    """
+    if len(logits_seq) != len(ns) or not len(ns):
+        raise ValueError("need equal, non-empty logits/tokens sequences")
+    for lg, n in zip(logits_seq, ns):
+        if lg.ndim != 2 or lg.shape[0] != n + 1:
+            raise ValueError(f"logits must be [K_i+1, V]; got {lg.shape} for K_i={n}")
+    V = logits_seq[0].shape[-1]
+    if any(lg.shape[-1] != V for lg in logits_seq):
+        raise ValueError("all sessions must share one (padded) vocab size")
+    bv, Vp = vocab_tiles(V, block_v)
+    logits = np.zeros((rows, width + 1, Vp), np.float32)
+    logits[:, :, V:] = -1e30
+    for i, (lg, n) in enumerate(zip(logits_seq, ns)):
+        logits[i, : n + 1, :V] = np.asarray(lg, np.float32)
+    return logits, bv
+
+
 def spec_verify_batched(
-    logits_seq: Optional[Sequence],  # B entries of [K_i+1, V]; None with batched_logits_fn
+    logits_seq: Sequence,  # B entries of [K_i+1, V]
     tokens_seq: Sequence,  # B entries of length-K_i int sequences
     *,
     impl: str,
     block_v: int = 2048,
-    bucket: bool = True,
-    block_tables_seq: Optional[Sequence] = None,  # B ragged KV block tables
-    batched_logits_fn: Optional[Callable] = None,
-    pad_page_id: int = 0,
 ) -> List[Tuple[int, int, np.ndarray]]:
     """Verify B sessions with ragged draft lengths in ONE launch.
 
     Returns a list of ``(n_accepted, correction_token, logp[K_i])`` in input
-    order.  With ``bucket=True`` the batch and draft dimensions are padded to
-    the next power of two (padding rows carry ``n_drafted = 0`` and are
-    discarded), bounding the number of compiled shapes under serving load.
-
-    **Paged target forward.**  With ``batched_logits_fn`` the entry owns the
-    whole fused dispatch: it pads tokens, per-session ``n_drafted``, and the
-    sessions' KV ``block_tables_seq`` (same ``Bp`` bucketing, via
-    ``pad_block_tables``), then calls
-    ``batched_logits_fn(tokens[Bp, Kp], n_drafted[Bp], tables[Bp, Gp]|None)``
-    for one batched ``[Bp, Kp+1, V]`` target forward (paged attention over
-    the block tables in a real deployment) before the NAV reduction —
-    instead of accepting per-session precomputed ``logits_seq``.
+    order.  The batch and draft dimensions are padded to the next power of
+    two (padding rows carry ``n_drafted = 0`` and are discarded), bounding
+    the number of compiled shapes under serving load.
     """
-    if batched_logits_fn is None:
-        if logits_seq is None or len(logits_seq) != len(tokens_seq) or not len(tokens_seq):
-            raise ValueError("need equal, non-empty logits/tokens sequences")
-    elif logits_seq is not None:
-        raise ValueError("pass logits_seq OR batched_logits_fn, not both")
-    if block_tables_seq is not None and len(block_tables_seq) != len(tokens_seq):
-        raise ValueError("need one block table per session")
-    ks = [len(t) for t in tokens_seq]
-    B = len(ks)
-    Bp, Kp = padded_dims(ks, bucket)
-    tokens = np.zeros((Bp, Kp), np.int32)
-    nd = np.zeros((Bp,), np.int32)
-    for i, (tk, k) in enumerate(zip(tokens_seq, ks)):
-        tokens[i, :k] = np.asarray(tk, np.int32)
-        nd[i] = k
-
-    if batched_logits_fn is not None:
-        tables = (
-            pad_block_tables(block_tables_seq, batch_pad=Bp, bucket=bucket, pad_id=pad_page_id)
-            if block_tables_seq is not None
-            else None
-        )
-        full = np.asarray(batched_logits_fn(tokens, nd, tables), np.float32)
-        if full.shape[:2] != (Bp, Kp + 1):
-            raise ValueError(f"batched_logits_fn must return [Bp, Kp+1, V]; got {full.shape}")
-        logits_rows = full
-        V = full.shape[-1]
-    else:
-        for lg, k in zip(logits_seq, ks):
-            if lg.ndim != 2 or lg.shape[0] != k + 1:
-                raise ValueError(f"logits must be [K_i+1, V]; got {lg.shape} for K_i={k}")
-        V = logits_seq[0].shape[-1]
-        if any(lg.shape[-1] != V for lg in logits_seq):
-            raise ValueError("all sessions must share one (padded) vocab size")
-        logits_rows = None
-
-    # Pallas needs V % block_v == 0: pad the vocab with -inf lanes (inert —
-    # they never win the argmax, add 0 to the logsumexp, and no draft token
-    # id can address them), keeping the documented VMEM tile budget.
-    bv = min(block_v, _next_pow2(V))
-    Vp = -(-V // bv) * bv
-    logits = np.zeros((Bp, Kp + 1, Vp), np.float32)
-    if Vp > V:
-        logits[:, :, V:] = -1e30  # only the pad lanes need the -inf sweep
-    if logits_rows is not None:
-        logits[:, :, :V] = logits_rows
-    else:
-        for i, (lg, k) in enumerate(zip(logits_seq, ks)):
-            logits[i, : k + 1, :V] = np.asarray(lg, np.float32)
-
-    na, corr, logp = spec_verify(
+    ks, tokens, nd = _pack_drafts(tokens_seq)
+    logits, bv = _pack_logits(logits_seq, ks, *tokens.shape, block_v)
+    out = spec_verify(
         jnp.asarray(logits), jnp.asarray(tokens), jnp.asarray(nd), impl=impl, block_v=bv
     )
-    na, corr, logp = np.asarray(na), np.asarray(corr), np.asarray(logp)
-    return [(int(na[i, 0]), int(corr[i, 0]), logp[i, : ks[i]]) for i in range(B)]
+    return ragged_results(ks, *out)
 
 
 # --------------------------------------------------------------------------- #
@@ -378,96 +356,39 @@ def spec_verify_tree(
 
 
 def spec_verify_tree_batched(
-    logits_seq: Optional[Sequence],  # B entries of [N_i+1, V]; None with batched_logits_fn
+    logits_seq: Sequence,  # B entries of [N_i+1, V]
     tokens_seq: Sequence,  # B entries of length-N_i int sequences
     parents_seq: Sequence,  # B entries of length-N_i int sequences
     *,
     impl: str,
     block_v: int = 2048,
-    bucket: bool = True,
-    block_tables_seq: Optional[Sequence] = None,  # B ragged KV block tables
-    batched_logits_fn: Optional[Callable] = None,
-    pad_page_id: int = 0,
 ) -> List[Tuple[int, List[int], int, np.ndarray]]:
     """Verify B sessions' ragged token TREES in ONE padded launch.
 
     Returns, per session in input order, ``(n_accepted, path, correction,
     logp[N_i])`` where ``path`` is the accepted root→leaf node-index list
     (length ``n_accepted``).  Trees are padded by NODE count with the same
-    pow2 bucketing as the chain entry; pad nodes carry ``parents = -1`` and
+    pow2 rounding as the chain entry; pad nodes carry ``parents = -1`` and
     pad rows ``n_nodes = 0``, both provably inert (kernel.py invariants).
-
-    Like the chain entry, ``batched_logits_fn`` replaces per-session
-    precomputed logits with ONE batched target forward over the padded
-    arrays: ``batched_logits_fn(tokens[Bp, Np], parents[Bp, Np],
-    n_nodes[Bp], tables[Bp, Gp]|None) -> [Bp, Np+1, V]`` — an
-    ancestor-masked paged-attention forward in a real deployment, with the
-    sessions' KV ``block_tables_seq`` padded by ``pad_block_tables`` under
-    the same ``Bp`` bucketing.
     """
-    if not (len(tokens_seq) == len(parents_seq)) or not len(tokens_seq):
+    if len(tokens_seq) != len(parents_seq) or not len(tokens_seq):
         raise ValueError("need equal, non-empty tokens/parents sequences")
-    if batched_logits_fn is None:
-        if logits_seq is None or len(logits_seq) != len(tokens_seq):
-            raise ValueError("need equal, non-empty logits/tokens/parents sequences")
-    elif logits_seq is not None:
-        raise ValueError("pass logits_seq OR batched_logits_fn, not both")
-    if block_tables_seq is not None and len(block_tables_seq) != len(tokens_seq):
-        raise ValueError("need one block table per session")
-    ns = [len(t) for t in tokens_seq]
-    for pr, n in zip(parents_seq, ns):
+    ns, tokens, nn = _pack_drafts(tokens_seq)
+    parents = np.full(tokens.shape, -1, np.int32)
+    for i, (pr, n) in enumerate(zip(parents_seq, ns)):
         if len(pr) != n:
             raise ValueError(f"parents length {len(pr)} != node count {n}")
-        for i, p in enumerate(pr):
-            if not (-1 <= int(p) < i):
-                raise ValueError(f"parents must be topologically packed; parents[{i}]={p}")
-    B = len(ns)
-    Bp, Np = padded_dims(ns, bucket)
-    tokens = np.zeros((Bp, Np), np.int32)
-    parents = np.full((Bp, Np), -1, np.int32)
-    nn = np.zeros((Bp,), np.int32)
-    for i, (tk, pr, n) in enumerate(zip(tokens_seq, parents_seq, ns)):
-        tokens[i, :n] = np.asarray(tk, np.int32)
+        for j, p in enumerate(pr):
+            if not (-1 <= int(p) < j):
+                raise ValueError(f"parents must be topologically packed; parents[{j}]={p}")
         parents[i, :n] = np.asarray(pr, np.int32)
-        nn[i] = n
-
-    if batched_logits_fn is not None:
-        tables = (
-            pad_block_tables(block_tables_seq, batch_pad=Bp, bucket=bucket, pad_id=pad_page_id)
-            if block_tables_seq is not None
-            else None
-        )
-        full = np.asarray(batched_logits_fn(tokens, parents, nn, tables), np.float32)
-        if full.shape[:2] != (Bp, Np + 1):
-            raise ValueError(f"batched_logits_fn must return [Bp, Np+1, V]; got {full.shape}")
-        V = full.shape[-1]
-    else:
-        for lg, n in zip(logits_seq, ns):
-            if lg.ndim != 2 or lg.shape[0] != n + 1:
-                raise ValueError(f"logits must be [N_i+1, V]; got {lg.shape} for N_i={n}")
-        V = logits_seq[0].shape[-1]
-        if any(lg.shape[-1] != V for lg in logits_seq):
-            raise ValueError("all sessions must share one (padded) vocab size")
-        full = None
-
-    bv = min(block_v, _next_pow2(V))
-    Vp = -(-V // bv) * bv
-    logits = np.zeros((Bp, Np + 1, Vp), np.float32)
-    if Vp > V:
-        logits[:, :, V:] = -1e30  # inert pad lanes (see chain entry)
-    if full is not None:
-        logits[:, :, :V] = full
-    else:
-        for i, (lg, n) in enumerate(zip(logits_seq, ns)):
-            logits[i, : n + 1, :V] = np.asarray(lg, np.float32)
-
+    logits, bv = _pack_logits(logits_seq, ns, *tokens.shape, block_v)
     na, best, corr, logp = spec_verify_tree(
         jnp.asarray(logits), jnp.asarray(tokens), jnp.asarray(parents), jnp.asarray(nn),
         impl=impl, block_v=bv,
     )
     na, best, corr, logp = (np.asarray(x) for x in (na, best, corr, logp))
-    out: List[Tuple[int, List[int], int, np.ndarray]] = []
-    for i in range(B):
-        path = tree_path(parents[i], int(best[i, 0]))
-        out.append((int(na[i, 0]), path, int(corr[i, 0]), logp[i, : ns[i]]))
-    return out
+    return [
+        (int(na[i, 0]), tree_path(parents[i], int(best[i, 0])), int(corr[i, 0]), logp[i, :n])
+        for i, n in enumerate(ns)
+    ]

@@ -1,7 +1,7 @@
 """Where JAX keeps its persistent compilation cache for this checkout.
 
-Called once by the serving entry points (``launch/serve.py``,
-``chip_smoke.py``) before their first compile:
+Called once by the serving entry point (``launch/serve.py``) before its
+first compile:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; nothing is set
   here, so the environment decides.

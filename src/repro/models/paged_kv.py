@@ -754,9 +754,8 @@ class PagedKVPool:
         The sentinel id is ``num_blocks``, one past the allocatable pool:
         it indexes the pool's own ``num_blocks + 1``-page tensor buffers,
         but any EXTERNAL page buffer gathered through a sentinel-padded
-        table (a ``batched_logits_fn`` consumer's arrays, or any buffer
-        paired with a metadata-mode pool, which has no tensor storage of
-        its own) must likewise be sized ``num_blocks + 1`` with a zeroed
+        table (any buffer paired with a metadata-mode pool, which has no
+        tensor storage of its own) must likewise be sized ``num_blocks + 1`` with a zeroed
         last page — a strict gather otherwise indexes out of bounds (and
         ``jnp`` indexing silently clamps to the last live page).  Callers
         that cannot resize their buffers must pass an in-range ``pad_id``

@@ -37,9 +37,10 @@ Per-dispatch batch size, queue depth, and KV-pool residency are fed to an
 occupancy/queue-depth/KV-residency into ``RunStats`` (core.pipeline).
 
 The backend is pluggable: ``SyntheticBackend`` (trace-driven acceptance, used
-by benchmarks), or ``SpecVerifyBackend`` running the real fused NAV kernel
-(Pallas on TPU, pure-JAX ``ref`` on CPU), optionally with a batched paged
-target forward (``batched_logits_fn`` + the sessions' KV block tables).
+by benchmarks), ``SpecVerifyBackend`` running the real fused NAV launch over
+the paged pool (Pallas on TPU, pure-JAX ``ref`` on CPU; sharded over a
+device mesh with ``mesh``), or ``LogitsVerifyBackend`` verifying chains and
+trees from per-session target logits.
 """
 
 from __future__ import annotations
@@ -54,10 +55,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.monitor import EnvironmentMonitor
+import repro.kernels.spec_verify.ops as verify_ops
 from repro.kernels.spec_verify.kernel import DEFAULT_BV
-from repro.kernels.spec_verify.ops import batch_rows, padded_dims
 from repro.models.paged_kv import BlockPoolExhausted, PagedKVPool
 from repro.obs.trace import NULL_TRACER
+from repro.sharding.spec_verify import spec_verify_sharded
 from .protocol import (
     Detach,
     DraftFragment,
@@ -77,8 +79,8 @@ from .transport import Transport
 __all__ = [
     "VerifyBackend",
     "SyntheticBackend",
+    "LogitsVerifyBackend",
     "SpecVerifyBackend",
-    "ShardedSpecVerifyBackend",
     "CloudVerifier",
     "VerifierDraining",
 ]
@@ -236,49 +238,101 @@ def _query_draw(jaxpr):
     return jax.jit(query_draw)
 
 
-class SpecVerifyBackend(VerifyBackend):
-    """Real NAV verification through the fused spec_verify kernel.
+class LogitsVerifyBackend(VerifyBackend):
+    """NAV verification from per-session target logits.
 
     ``logits_fn(session, tokens) -> [len(tokens)+1, V]`` produces the target
-    logits for one session (a model forward in a real deployment, a seeded
-    synthetic sampler in tests).  ``verify_batch`` pads the ragged requests
-    and runs them through ``spec_verify_batched`` in ONE launch.  ``impl``
-    is the caller's choice and is never changed behind its back: the
-    compiled Pallas kernel on a TPU (``'pallas'``), the same kernel under
-    the CPU interpreter (``'interpret'``), or the pure-JAX oracle
-    (``'ref'``).
+    logits for one session (a model forward, or a seeded synthetic sampler
+    in tests).  ``verify_batch`` pads the ragged chain requests and runs
+    them through ``spec_verify_batched`` in ONE launch;
+    ``verify_tree_batch`` does the same for token trees through
+    ``spec_verify_tree_batched``, with ``logits_fn`` returning rows in
+    packed-tree order (row 0 anchor, row 1+i node i — the contract
+    ``tree_target_logits`` produces).  ``impl`` is the caller's choice and
+    is never changed behind its back: the compiled Pallas kernel on a TPU
+    (``'pallas'``), the same kernel under the CPU interpreter
+    (``'interpret'``), or the pure-JAX oracle (``'ref'``).
+    """
 
-    **Paged target forward.**  With ``batched_logits_fn`` (and a ``kv_pool``
-    supplying per-session KV block tables) the per-session ``logits_fn``
-    calls are replaced by ONE batched forward over the padded
-    ``(tokens, n_drafted, block_tables)`` arrays — the fused
-    paged-attention + NAV dispatch shape a production verifier compiles
-    (see ``kernels.spec_verify.spec_verify_batched``).  Ragged tables pad
-    with the pool's zero-filled sentinel page (id ``num_blocks``), so a
-    padded lane can never prefetch KV owned by another session — a
-    ``batched_logits_fn`` gathering from its OWN page buffers must size
-    them ``num_blocks + 1`` with a zeroed last page to honour that pad id
-    (see ``PagedKVPool.table``).
+    def __init__(self, logits_fn: Callable, *, impl: str, block_v: int = DEFAULT_BV):
+        if impl not in IMPLS:
+            raise ValueError(f"impl must be one of {IMPLS}, got {impl!r}")
+        self.logits_fn = logits_fn
+        self.impl = impl
+        self.block_v = block_v
 
-    **Fused one-launch verify** (``fused=True``).  Requires a TENSOR-mode
-    ``kv_pool``, a traceable ``query_fn(session, tokens, k=None) -> [K+1,
-    H, hd]`` producing the target's per-position queries (below), and
-    ``lm_head [H*hd, V]``: chain
-    rounds then run ``spec_verify_fused_batched`` — paged attention over
-    the sessions' block tables + LM-head projection + NAV scan in ONE
-    Pallas launch instead of forward-then-verify.  The round's KV slots
-    (metadata-appended by the dispatcher's ``_kv_secure``) are materialized
-    through ``kv_fn(session, start, count) -> (k, v)`` just before the
-    launch, from the pool's per-session ``filled`` watermark (``ensure_kv``)
-    — so slots regrown after a rollback or eviction are always refilled,
-    never trusted to still hold this session's tensors.  The default
-    ``kv_fn`` synthesizes deterministic position-keyed tensors, so
-    re-prefills reproduce the original values bit-for-bit.  A shared-prefix
-    ``CloudVerifier`` materializes the prefix ONCE on its owner session
-    before any fork; children inherit the watermark and never fill shared
-    pages (``PagedKVPool.fill`` would CoW-copy them, forfeiting the
-    sharing).  An int8 pool (``quantize='int8'``) is picked up
-    automatically — the launch dequantizes pages in-kernel.
+    def verify(self, session: int, tokens: List[int], confs: List[float]):
+        """Verify one session through the batched path (batch of one)."""
+        return self.verify_batch([(session, tokens, confs)])[0]
+
+    def verify_batch(self, requests):
+        """Pad the ragged requests and run ONE NAV kernel launch."""
+        if not requests:
+            return []
+        logits = [self.logits_fn(s, t) for (s, t, _) in requests]
+        tokens = [t for (_, t, _) in requests]
+        out = verify_ops.spec_verify_batched(logits, tokens, impl=self.impl, block_v=self.block_v)
+        return [(n_acc, corr) for (n_acc, corr, _) in out]
+
+    def verify_tree(self, session, tokens, confs, parents):
+        """Verify one session's tree through the batched path (batch of one)."""
+        return self.verify_tree_batch([(session, tokens, confs, parents)])[0]
+
+    def verify_tree_batch(self, requests):
+        """One padded tree-NAV launch over the batch (pad by node count)."""
+        if not requests:
+            return []
+        logits = [self.logits_fn(s, t) for (s, t, _, _) in requests]
+        out = verify_ops.spec_verify_tree_batched(
+            logits, [t for (_, t, _, _) in requests], [p for (_, _, _, p) in requests],
+            impl=self.impl, block_v=self.block_v,
+        )
+        return [(n_acc, corr, path) for (n_acc, path, corr, _) in out]
+
+
+class SpecVerifyBackend(VerifyBackend):
+    """The served verifier: one fused launch per round over the paged KV pool.
+
+    Requires a TENSOR-mode ``kv_pool``, a traceable ``query_fn(session,
+    tokens, k=None) -> [K+1, H, hd]`` producing the target's per-position
+    queries (below), and ``lm_head [H*hd, V]``.  Chain rounds run paged
+    attention over the sessions' block tables + LM-head projection + NAV
+    scan in ONE launch (``fused_verify``): ``spec_verify_fused``, the Pallas
+    kernel, or with ``mesh`` the sharded launch
+    (``repro.sharding.spec_verify``) across a 1-D ``("model",)`` device
+    mesh.  Both launches are fed by the same ragged packing
+    (``ragged_launch_args``, tables padded with the pool's zero-filled
+    sentinel page) and unpacked by ``ragged_results``.  There is no fused
+    tree launch yet: a tree round raises ``ValueError`` (tree rounds verify
+    through ``LogitsVerifyBackend``).
+
+    ``impl`` is the caller's choice and is never changed behind its back:
+    the compiled Pallas kernel on a TPU (``'pallas'``), the same kernel
+    under the CPU interpreter (``'interpret'``), or the pure-JAX oracle
+    (``'ref'``).  The sharded launch is XLA ``jax.numpy`` code — the
+    stage-by-stage oracle of the fused kernel, partitioned — so with a
+    ``mesh`` only ``'ref'`` is accepted; any other value is refused rather
+    than silently ignored.  With a ``mesh`` the pool's page buffers are laid
+    out over it on construction (``PagedKVPool.place_on_mesh``: head axis
+    when divisible, replicated otherwise) and block tables are replicated
+    per device at launch, so the sentinel-page padding contract holds on
+    every shard; the dispatcher, router, and every protocol message stay
+    oblivious to the shard count (bit-exact against the unsharded launch,
+    ``tests/test_sharded_verify.py``).
+
+    The round's KV slots (metadata-appended by the dispatcher's
+    ``_kv_secure``) are materialized through ``kv_fn(session, start, count)
+    -> (k, v)`` just before the launch, from the pool's per-session
+    ``filled`` watermark (``ensure_kv``) — so slots regrown after a
+    rollback or eviction are always refilled, never trusted to still hold
+    this session's tensors.  The default ``kv_fn`` synthesizes
+    deterministic position-keyed tensors, so re-prefills reproduce the
+    original values bit-for-bit.  A shared-prefix ``CloudVerifier``
+    materializes the prefix ONCE on its owner session before any fork;
+    children inherit the watermark and never fill shared pages
+    (``PagedKVPool.fill`` would CoW-copy them, forfeiting the sharing).  An
+    int8 pool (``quantize='int8'``) is picked up automatically — the launch
+    dequantizes pages in-kernel.
 
     **The target's queries.**  ``query_fn(session, tokens, k=None)`` is the
     one definition of the target's per-position queries: called as
@@ -291,8 +345,8 @@ class SpecVerifyBackend(VerifyBackend):
     tokens[:k])``; the rest are zeroed.  A ``jax.random`` draw meets this
     only under JAX's partitionable threefry (``jax_threefry_partitionable``,
     on by default), which keys each element by its own index; the backend
-    refuses to draw with it off.  Each fused round draws all of its
-    sessions' queries as ONE jitted, vmapped evaluation over the rows
+    refuses to draw with it off.  Each round draws all of its sessions'
+    queries as ONE jitted, vmapped evaluation over the rows
     (``draw_queries``) and hands the device array to the launch: no call
     per session and no copy to the host.  The draw is ``[max(Bp,
     DRAW_ROWS), max(Kp, DRAW_DRAFTS)+1, H, hd]``, zero past the round's
@@ -306,62 +360,48 @@ class SpecVerifyBackend(VerifyBackend):
     ``tracer`` (a ``repro.obs`` tracer, ``NULL_TRACER`` unless a
     ``CloudVerifier`` hands down its own) times the host side of that draw
     (its ids and the draw's dispatch, no sync) as one ``query`` span per
-    fused round, and each ``kv_fn`` call of ``ensure_kv`` as ``kv.synth``.
-    ``stats["kernel_rows"]`` adds up the padded batch of each fused launch,
-    pad rows included; ``stats["query_launches"]`` counts the draws, one
-    per fused round.
+    round, and each ``kv_fn`` call of ``ensure_kv`` as ``kv.synth``.
+    ``stats["kernel_rows"]`` adds up the padded batch of each launch, pad
+    rows included; ``stats["query_launches"]`` counts the draws, one per
+    round.
     """
 
     def __init__(
         self,
-        logits_fn: Optional[Callable] = None,
         *,
-        impl: str,
-        block_v: int = DEFAULT_BV,
         kv_pool: Optional[PagedKVPool] = None,
-        batched_logits_fn: Optional[Callable] = None,
-        batched_tree_logits_fn: Optional[Callable] = None,
-        fused: bool = False,
         query_fn: Optional[Callable] = None,
         lm_head: Optional[Any] = None,
+        impl: str,
+        block_v: int = DEFAULT_BV,
         kv_fn: Optional[Callable] = None,
+        mesh: Any = None,
     ):
-        if fused:
-            if kv_pool is None or kv_pool.k_pages is None:
-                raise ValueError("fused=True needs a tensor-mode kv_pool")
-            if query_fn is None or lm_head is None:
-                raise ValueError("fused=True needs query_fn and lm_head")
-        elif logits_fn is None and batched_logits_fn is None:
-            raise ValueError("need logits_fn or batched_logits_fn")
         if impl not in IMPLS:
             raise ValueError(f"impl must be one of {IMPLS}, got {impl!r}")
-        self.logits_fn = logits_fn
+        if mesh is not None and impl != "ref":
+            raise ValueError(
+                f"the sharded launch is jax.numpy, not a Pallas kernel: "
+                f"impl must be 'ref', got {impl!r}"
+            )
+        if kv_pool is None or kv_pool.k_pages is None:
+            raise ValueError("SpecVerifyBackend needs a tensor-mode kv_pool")
+        if query_fn is None or lm_head is None:
+            raise ValueError("SpecVerifyBackend needs query_fn and lm_head")
         self.impl = impl
         self.block_v = block_v
         self.kv_pool = kv_pool
-        self.batched_logits_fn = batched_logits_fn
-        self.batched_tree_logits_fn = batched_tree_logits_fn
-        self.fused = fused
         self.query_fn = query_fn
+        self.mesh = mesh
+        if mesh is not None:
+            kv_pool.place_on_mesh(mesh)
         self._draws: Dict[int, tuple] = {}  # drafts -> (jitted draw, query_fn's arrays)
         # Device-resident once: a host LM head would cross to the device on
         # every dispatch.
-        self.lm_head = None if lm_head is None else jnp.asarray(lm_head, jnp.float32)
+        self.lm_head = jnp.asarray(lm_head, jnp.float32)
         self.kv_fn = kv_fn if kv_fn is not None else self._default_kv_fn
         self.tracer = NULL_TRACER
         self.stats = {"kernel_rows": 0, "query_launches": 0}
-
-    def _tables(self, sessions: Sequence[int]):
-        if self.kv_pool is None:
-            return None
-        return [
-            list(self.kv_pool.table(s)) if s in self.kv_pool.tables else []
-            for s in sessions
-        ]
-
-    @property
-    def _pad_page_id(self) -> int:
-        return self.kv_pool.sentinel_page if self.kv_pool is not None else 0
 
     def _default_kv_fn(self, session: int, start: int, count: int):
         """Deterministic position-keyed synthetic KV (the modeled target).
@@ -403,28 +443,16 @@ class SpecVerifyBackend(VerifyBackend):
         return self.verify_batch([(session, tokens, confs)])[0]
 
     def verify_batch(self, requests):
-        """Pad the ragged requests and run ONE fused NAV kernel launch."""
+        """ONE fused launch for the ragged requests: ``(n_accepted, correction)`` each."""
         if not requests:
             return []
-        from repro.kernels.spec_verify import spec_verify_batched
+        return [(n_acc, corr) for (n_acc, corr, _) in self.fused_verify(requests)]
 
-        if self.fused:
-            return [(int(n_acc), int(corr)) for (n_acc, corr, _) in self.fused_verify(requests)]
-        tokens = [t for (_, t, _) in requests]
-        if self.batched_logits_fn is not None:
-            out = spec_verify_batched(
-                None,
-                tokens,
-                impl=self.impl,
-                block_v=self.block_v,
-                block_tables_seq=self._tables([s for (s, _, _) in requests]),
-                batched_logits_fn=self.batched_logits_fn,
-                pad_page_id=self._pad_page_id,
-            )
-        else:
-            logits = [self.logits_fn(s, t) for (s, t, _) in requests]
-            out = spec_verify_batched(logits, tokens, impl=self.impl, block_v=self.block_v)
-        return [(int(n_acc), int(corr)) for (n_acc, corr, _) in out]
+    def verify_tree(self, session, tokens, confs, parents):
+        """Refused: there is no fused tree launch yet."""
+        raise ValueError(
+            "tree requests need a LogitsVerifyBackend: the fused launch verifies chains only"
+        )
 
     def draw_queries(self, requests) -> jax.Array:
         """The round's queries, ``[max(Bp, DRAW_ROWS), max(Kp, DRAW_DRAFTS)+1, H, hd]``.
@@ -435,7 +463,7 @@ class SpecVerifyBackend(VerifyBackend):
         to the device.
         """
         with self.tracer.span("query"):
-            Bp, Kp = padded_dims([len(t) for (_, t, _) in requests])
+            Bp, Kp = verify_ops.padded_dims([len(t) for (_, t, _) in requests])
             kd = max(Kp, DRAW_DRAFTS)
             ids = np.full((max(Bp, DRAW_ROWS), kd + 2), -1, np.int32)  # pad rows: K = -1
             for i, (s, t, _) in enumerate(requests):
@@ -466,14 +494,13 @@ class SpecVerifyBackend(VerifyBackend):
         return self._draws[kd]
 
     def fused_inputs(self, requests):
-        """The fused launch's arguments for one round, as keyword arguments.
+        """The launch's arguments for one round, as ``spec_verify_fused_batched`` keywords.
 
         Fills any unmaterialized KV slots first (the dispatcher appends page
         metadata in ``_kv_secure`` before we run), then draws the round's
         queries on the device (``draw_queries``) and gathers block tables,
         base lengths, the pool's layer-0 page tensors (+ int8 quant params
-        when the pool quantizes) and the LM head — the shared signature of
-        ``spec_verify_fused_batched`` and its sharded twin.
+        when the pool quantizes) and the LM head.
         """
         pool = self.kv_pool
         sessions = [s for (s, _, _) in requests]
@@ -485,7 +512,9 @@ class SpecVerifyBackend(VerifyBackend):
         return dict(
             q=self.draw_queries(requests),
             tokens_seq=[t for (_, t, _) in requests],
-            block_tables_seq=self._tables(sessions),
+            block_tables_seq=[
+                list(pool.table(s)) if s in pool.tables else [] for s in sessions
+            ],
             base_lengths=[max(pool.length(s) - len(t), 0) for (s, t, _) in requests],
             # Read after the fills: each donates the buffers it writes.
             k_pages=pool.k_pages[0],
@@ -498,105 +527,23 @@ class SpecVerifyBackend(VerifyBackend):
     def fused_verify(self, requests):
         """ONE launch for the whole round: attention + LM head + NAV scan.
 
-        Returns ``(n_accepted, correction, logp[K_i])`` per request.
+        Returns ``(n_accepted, correction, logp[K_i])`` per request.  The
+        launch is ``spec_verify_fused`` at ``impl``, or the sharded launch
+        over ``mesh`` when the backend has one.
         """
-        from repro.kernels.spec_verify import spec_verify_fused_batched
-
-        inputs = self.fused_inputs(requests)
-        self.stats["kernel_rows"] += batch_rows(len(requests))
-        return spec_verify_fused_batched(**inputs, impl=self.impl, block_v=self.block_v)
-
-    def verify_tree(self, session, tokens, confs, parents):
-        """Verify one session's tree through the batched path (batch of one)."""
-        return self.verify_tree_batch([(session, tokens, confs, parents)])[0]
-
-    def verify_tree_batch(self, requests):
-        """One padded tree-NAV launch over the batch (pad by node count).
-
-        ``logits_fn(session, tokens)`` must return ``[len(tokens)+1, V]`` rows
-        in packed-tree order (row 0 anchor, row 1+i node i) when the request
-        is a tree — the same contract ``tree_target_logits`` produces.
-        """
-        if not requests:
-            return []
-        from repro.kernels.spec_verify import spec_verify_tree_batched
-
-        tokens = [t for (_, t, _, _) in requests]
-        parents = [p for (_, _, _, p) in requests]
-        if self.batched_tree_logits_fn is not None:
-            out = spec_verify_tree_batched(
-                None,
-                tokens,
-                parents,
-                impl=self.impl,
-                block_v=self.block_v,
-                block_tables_seq=self._tables([s for (s, _, _, _) in requests]),
-                batched_logits_fn=self.batched_tree_logits_fn,
-                pad_page_id=self._pad_page_id,
-            )
-        elif self.logits_fn is None:
-            raise ValueError(
-                "tree requests need logits_fn or batched_tree_logits_fn "
-                "(this backend was built with only a chain batched_logits_fn)"
+        a = self.fused_inputs(requests)
+        self.stats["kernel_rows"] += verify_ops.batch_rows(len(requests))
+        ks, *packed = verify_ops.ragged_launch_args(
+            a["tokens_seq"], a["block_tables_seq"], a["base_lengths"], pad_page_id=a["pad_page_id"]
+        )
+        operands = (a["q"], a["k_pages"], a["v_pages"], a["w"], *packed)
+        if self.mesh is None:
+            out = verify_ops.spec_verify_fused(
+                *operands, impl=self.impl, block_v=self.block_v, quant=a["quant"]
             )
         else:
-            logits = [self.logits_fn(s, t) for (s, t, _, _) in requests]
-            out = spec_verify_tree_batched(
-                logits, tokens, parents, impl=self.impl, block_v=self.block_v
-            )
-        return [(int(n_acc), int(corr), list(path)) for (n_acc, path, corr, _) in out]
-
-
-class ShardedSpecVerifyBackend(SpecVerifyBackend):
-    """Tensor-parallel fused verify: the same one-launch contract, sharded.
-
-    Drop-in for ``SpecVerifyBackend(fused=True)``: chain rounds run the
-    SHARDED fused launch (``repro.sharding.spec_verify``) across a 1-D
-    ``("model",)`` device mesh — head-parallel paged attention,
-    vocab-parallel LM head, replicated NAV scan — while the dispatcher,
-    router, and every protocol message stay oblivious to the shard count.
-    The pool's page buffers are laid out over the mesh on construction
-    (``PagedKVPool.place_on_mesh``: head axis when divisible, replicated
-    otherwise) and block tables are replicated per device at launch, so the
-    sentinel-page padding contract holds on every shard.  Bit-exact against
-    the unsharded backend (``tests/test_sharded_verify.py``) for fp32 and
-    int8 pools, including GQA head counts that don't divide the mesh.
-
-    The sharded launch is XLA ``jax.numpy`` code — the stage-by-stage
-    oracle of the fused kernel, partitioned — not a Pallas kernel.  So
-    ``impl`` can only be ``'ref'``; any other value is refused rather than
-    silently ignored.
-
-    Pass either a prebuilt ``mesh`` or a ``shards`` count; the latter builds
-    a mesh over the first ``shards`` visible devices (``host_mesh``): the
-    chips of a TPU host, or on a CPU host the virtual devices that
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` creates.
-    """
-
-    def __init__(self, *, shards: int = 1, mesh: Any = None, impl: str = "ref", **kwargs: Any):
-        kwargs.setdefault("fused", True)
-        if not kwargs["fused"]:
-            raise ValueError("ShardedSpecVerifyBackend requires the fused path")
-        if impl != "ref":
-            raise ValueError(
-                f"ShardedSpecVerifyBackend runs the sharded jax.numpy verify, not a "
-                f"Pallas kernel: impl must be 'ref', got {impl!r}"
-            )
-        super().__init__(impl=impl, **kwargs)
-        from repro.sharding.shardctx import host_mesh
-
-        self.mesh = mesh if mesh is not None else host_mesh(int(shards))
-        self.shards = int(np.prod(list(self.mesh.shape.values())))
-        if self.kv_pool is not None:
-            self.kv_pool.place_on_mesh(self.mesh)
-
-    def fused_verify(self, requests):
-        """ONE SHARDED launch for the whole round (see the unsharded twin)."""
-        from repro.sharding.spec_verify import spec_verify_sharded_batched
-
-        inputs = self.fused_inputs(requests)
-        self.stats["kernel_rows"] += batch_rows(len(requests))
-        return spec_verify_sharded_batched(**inputs, mesh=self.mesh, block_v=self.block_v)
+            out = spec_verify_sharded(*operands, mesh=self.mesh, block_v=self.block_v, quant=a["quant"])
+        return verify_ops.ragged_results(ks, *out)
 
 
 @dataclass
@@ -716,10 +663,7 @@ class CloudVerifier:
             # filling through a forked table would CoW-copy every shared
             # prefix page (pool.fill diverges shared pages), forfeiting the
             # prefix-sharing win.
-            if (
-                getattr(backend, "fused", False)
-                and getattr(backend, "kv_pool", None) is kv_pool
-            ):
+            if isinstance(backend, SpecVerifyBackend) and backend.kv_pool is kv_pool:
                 backend.ensure_kv(self.KV_PREFIX_SESSION)
         # Default: batching only when a coalescing window was requested.
         # batch_window == 0 keeps strict per-session serving (one request per

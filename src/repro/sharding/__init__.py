@@ -5,9 +5,7 @@ from .spec_verify import (
     MODEL_AXIS,
     ShardPlan,
     plan_shards,
-    sharded_target_logits,
     spec_verify_sharded,
-    spec_verify_sharded_batched,
 )
 
 __all__ = [
@@ -23,8 +21,6 @@ __all__ = [
     "partition",
     "plan_shards",
     "shardctx",
-    "sharded_target_logits",
     "spec_verify",
     "spec_verify_sharded",
-    "spec_verify_sharded_batched",
 ]

@@ -44,7 +44,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import List, Optional, Sequence, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -53,18 +53,14 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.decode_attention.ref import dequantize_pages, paged_decode_attention_ref
-from repro.kernels.spec_verify.ops import _next_pow2, check_queries, ragged_launch_args
+from repro.kernels.spec_verify.ops import fused_operands, vocab_tiles
 from repro.kernels.spec_verify.ref import lm_head_logits, spec_verify_ref
-
-from .shardctx import host_mesh
 
 __all__ = [
     "MODEL_AXIS",
     "ShardPlan",
     "plan_shards",
-    "sharded_target_logits",
     "spec_verify_sharded",
-    "spec_verify_sharded_batched",
 ]
 
 MODEL_AXIS = "model"
@@ -130,8 +126,7 @@ def plan_shards(
         raise ValueError(f"shards must be >= 1, got {shards}")
     if n_heads % max(n_kv_heads, 1):
         raise ValueError(f"n_heads={n_heads} not a multiple of n_kv_heads={n_kv_heads}")
-    bv = min(block_v, _next_pow2(vocab))
-    vp = -(-vocab // bv) * bv
+    bv, vp = vocab_tiles(vocab, block_v)
     vs = -(-vp // (shards * bv)) * bv
     hp = -(-n_heads // shards) * shards
     return ShardPlan(
@@ -172,7 +167,6 @@ def _build_launch(
     block_v: int,
     window: int,
     quantized: bool,
-    with_scan: bool,
 ):
     """Jitted shard_map launch, cached per (mesh, static geometry).
 
@@ -180,7 +174,7 @@ def _build_launch(
     paged attention on the local head slice, head ``all_gather`` + slice to
     the true head count, per-shard ``block_v`` vocab tiles with the FULL
     contraction dim, global-id masking, vocab ``all_gather``, then the
-    replicated NAV scan (or the raw logits when ``with_scan`` is False).
+    replicated NAV scan.
     """
     H, hd, Vp, Vs, bv = heads, head_dim, padded_vocab, vocab_per_shard, block_v
 
@@ -203,10 +197,7 @@ def _build_launch(
         ids = shard * Vs + jnp.arange(Vs)[None, None, :]
         logits = jnp.where(ids >= v_true, -1e30, logits)
         logits = jax.lax.all_gather(logits, MODEL_AXIS, axis=2, tiled=True)
-        logits = logits[:, :, :Vp]
-        if not with_scan:
-            return logits
-        return spec_verify_ref(logits, tokens, nd)
+        return spec_verify_ref(logits[:, :, :Vp], tokens, nd)
 
     head4 = P(None, None, MODEL_AXIS, None)  # [*, *, heads, hd]
     quant_specs = (P(None, None, MODEL_AXIS),) * 4 if quantized else ()
@@ -220,43 +211,20 @@ def _build_launch(
         P(None, None),  # tokens
         P(None),  # n_drafted
     ) + quant_specs
-    out_specs = (
-        P(None, None, None)
-        if not with_scan
-        else (P(None, None), P(None, None), P(None, None))
-    )
+    out_specs = (P(None, None), P(None, None), P(None, None))
     return jax.jit(
         jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
     )
 
 
-def _prepare(
-    q: jax.Array,
-    k_pages: jax.Array,
-    v_pages: jax.Array,
-    w: jax.Array,
-    mesh: Mesh,
-    *,
-    v_true: Optional[int],
-    block_v: int,
-    quant,
-):
-    """GQA-expand, head-pad and vocab-pad the operands for the mesh."""
-    shards = int(np.prod(list(mesh.shape.values())))
-    H = q.shape[2]
-    n_kv = k_pages.shape[2]
-    if n_kv != H:  # GQA: expand KV (and quant planes) to the query heads
-        k_pages = jnp.repeat(k_pages, H // n_kv, axis=2)
-        v_pages = jnp.repeat(v_pages, H // n_kv, axis=2)
-        if quant is not None:
-            quant = tuple(jnp.repeat(p, H // n_kv, axis=2) for p in quant)
+def _prepare(q, draft_tokens, k_pages, v_pages, w, mesh: Mesh, *, v_true, block_v: int, quant):
+    """The fused operands (``fused_operands``), head-padded and vocab-padded for the mesh."""
     V = w.shape[1]
-    if v_true is None:
-        v_true = V
     plan = plan_shards(
-        shards=shards, n_heads=H, n_kv_heads=n_kv, head_dim=q.shape[3],
-        vocab=V, block_v=block_v,
+        shards=int(np.prod(list(mesh.shape.values()))), n_heads=q.shape[2],
+        n_kv_heads=k_pages.shape[2], head_dim=q.shape[3], vocab=V, block_v=block_v,
     )
+    q, k_pages, v_pages, quant = fused_operands(q, draft_tokens, k_pages, v_pages, quant)
     q = _pad_axis(q, 2, plan.padded_heads)
     k_pages = _pad_axis(k_pages, 2, plan.padded_heads)
     v_pages = _pad_axis(v_pages, 2, plan.padded_heads)
@@ -267,7 +235,7 @@ def _prepare(
     w = _pad_axis(
         _pad_axis(w.astype(jnp.float32), 1, plan.padded_vocab), 1, plan.launch_vocab
     )
-    return q, k_pages, v_pages, w, quant, plan, int(v_true)
+    return q, k_pages, v_pages, w, quant, plan, int(V if v_true is None else v_true)
 
 
 def spec_verify_sharded(
@@ -293,12 +261,8 @@ def spec_verify_sharded(
     Bit-exact against the jitted unsharded oracle for any shard count,
     including head counts that don't divide the mesh and int8 pools.
     """
-    check_queries(q, draft_tokens)
-    B, K = draft_tokens.shape
-    if q.shape[:2] != (B, K + 1):
-        q = q[:B, : K + 1]
     q, k_pages, v_pages, w, quant, plan, v_true = _prepare(
-        q, k_pages, v_pages, w, mesh, v_true=v_true, block_v=block_v, quant=quant
+        q, draft_tokens, k_pages, v_pages, w, mesh, v_true=v_true, block_v=block_v, quant=quant
     )
     fn = _build_launch(
         mesh,
@@ -310,7 +274,6 @@ def spec_verify_sharded(
         block_v=plan.block_v,
         window=window,
         quantized=quant is not None,
-        with_scan=True,
     )
     args = (q, k_pages, v_pages, w,
             jnp.asarray(block_tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
@@ -318,102 +281,3 @@ def spec_verify_sharded(
     if quant is not None:
         args += tuple(quant)
     return fn(*args)
-
-
-def sharded_target_logits(
-    q: jax.Array,  # [B, K+1, H, hd]
-    k_pages: jax.Array,
-    v_pages: jax.Array,
-    w: jax.Array,
-    block_tables: jax.Array,
-    lengths: jax.Array,
-    *,
-    mesh: Mesh,
-    v_true: Optional[int] = None,
-    block_v: int = 2048,
-    window: int = 1 << 30,
-    quant=None,
-) -> jax.Array:
-    """Sharded target forward WITHOUT the NAV scan: ``[B, K+1, Vp]`` logits.
-
-    The chain-path building block: wraps the same sharded launch but stops
-    after the vocab gather, so callers can feed ``spec_verify_batched``'s
-    ``batched_logits_fn`` contract from a tensor-parallel forward.  Padded
-    vocab lanes (``>= v_true``) carry ``-1e30``, matching
-    ``fused_target_logits``.
-    """
-    B = q.shape[0]
-    q, k_pages, v_pages, w, quant, plan, v_true = _prepare(
-        q, k_pages, v_pages, w, mesh, v_true=v_true, block_v=block_v, quant=quant
-    )
-    fn = _build_launch(
-        mesh,
-        heads=plan.heads,
-        head_dim=plan.head_dim,
-        v_true=v_true,
-        padded_vocab=plan.padded_vocab,
-        vocab_per_shard=plan.vocab_per_shard,
-        block_v=plan.block_v,
-        window=window,
-        quantized=quant is not None,
-        with_scan=False,
-    )
-    K1 = q.shape[1]
-    zeros_t = jnp.zeros((B, max(K1 - 1, 1)), jnp.int32)
-    args = (q, k_pages, v_pages, w,
-            jnp.asarray(block_tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
-            zeros_t, jnp.zeros((B,), jnp.int32))
-    if quant is not None:
-        args += tuple(quant)
-    return fn(*args)
-
-
-def spec_verify_sharded_batched(
-    q: jax.Array,  # [R, P, H, hd] padded queries, R >= Bp, P >= Kp+1 (see padded_dims)
-    tokens_seq: Sequence,  # B entries of length-K_i int sequences
-    block_tables_seq: Sequence,  # B ragged KV block tables
-    base_lengths: Sequence,  # B ints — KV length visible to query position 0
-    k_pages: jax.Array,
-    v_pages: jax.Array,
-    w: jax.Array,
-    *,
-    mesh: Optional[Mesh] = None,
-    shards: Optional[int] = None,
-    block_v: int = 2048,
-    bucket: bool = True,
-    window: int = 1 << 30,
-    pad_page_id: int = 0,
-    quant=None,
-) -> List[Tuple[int, int, np.ndarray]]:
-    """Ragged serving entry for the SHARDED fused verify — one launch.
-
-    The sharded twin of ``spec_verify_fused_batched``: the same padded
-    queries, identical pow2 bucketing, sentinel-page table padding, inert
-    pad rows, and per-session unpacking (``ragged_launch_args``) — only the
-    launch underneath runs ``shard_map`` across the mesh.  Pass either a
-    prebuilt 1-D ``mesh`` or a ``shards`` count (a host mesh over the first
-    ``shards`` devices is built for you).
-    """
-    if mesh is None:
-        if shards is None:
-            raise ValueError("pass mesh= or shards=")
-        mesh = host_mesh(shards)
-    ks, tables, lengths, tokens, nd = ragged_launch_args(
-        tokens_seq, block_tables_seq, base_lengths, bucket=bucket, pad_page_id=pad_page_id
-    )
-    na, corr, logp = spec_verify_sharded(
-        q,
-        k_pages,
-        v_pages,
-        w,
-        jnp.asarray(tables),
-        jnp.asarray(lengths),
-        jnp.asarray(tokens),
-        jnp.asarray(nd),
-        mesh=mesh,
-        block_v=block_v,
-        window=window,
-        quant=quant,
-    )
-    na, corr, logp = np.asarray(na), np.asarray(corr), np.asarray(logp)
-    return [(int(na[i, 0]), int(corr[i, 0]), logp[i, :k]) for i, k in enumerate(ks)]

@@ -42,7 +42,7 @@ from repro.kernels.spec_verify import (
     spec_verify_fused_batched,
     spec_verify_tree_batched,
 )
-from repro.kernels.spec_verify.ops import padded_dims
+from repro.kernels.spec_verify.ops import padded_dims, ragged_launch_args, ragged_results
 
 KEY = jax.random.PRNGKey(23)
 
@@ -136,13 +136,13 @@ class RaggedCase:
         return pad_queries(self.q_seq)
 
 
-def pad_queries(q_seq: Sequence, bucket: bool = True) -> np.ndarray:
+def pad_queries(q_seq: Sequence) -> np.ndarray:
     """Per-session ``[K_i+1, H, hd]`` queries -> the padded ``[Bp, Kp+1, H, hd]``.
 
     The host-side form of what a verify backend draws on the device: row
     ``i`` holds session ``i``'s positions first, zeros after; pad rows zero.
     """
-    Bp, Kp = padded_dims([len(q) - 1 for q in q_seq], bucket)
+    Bp, Kp = padded_dims([len(q) - 1 for q in q_seq])
     H, hd = q_seq[0].shape[1:]
     out = np.zeros((Bp, Kp + 1, H, hd), np.float32)
     for i, q in enumerate(q_seq):
@@ -272,14 +272,15 @@ def ragged_logits_requests(ks, V, seed=0):
 
 
 def fused_backend(quantize=None, impl="ref", num_blocks=16, shards=None):
-    """The serving fused backend over a real pool; sharded when ``shards``.
+    """The served backend over a real pool; over a ``shards``-device mesh when given.
 
     One fixed tiny geometry (H=2, hd=8, bs=4, V=256) with seeded LM head and
     queries, so unsharded and sharded backends built here are comparable
     request-for-request.  Returns ``(backend, pool, w, V)``.
     """
     from repro.models.paged_kv import PagedKVPool
-    from repro.runtime import ShardedSpecVerifyBackend, SpecVerifyBackend
+    from repro.runtime import SpecVerifyBackend
+    from repro.sharding import host_mesh
 
     H, hd, bs, V = 2, 8, 4, 256
     pool = PagedKVPool(
@@ -293,11 +294,10 @@ def fused_backend(quantize=None, impl="ref", num_blocks=16, shards=None):
         draw = jax.random.fold_in(jax.random.fold_in(KEY, 88), session * 131 + n)
         return jax.random.normal(draw, (len(tokens) + 1, H, hd))
 
-    kw = dict(kv_pool=pool, query_fn=query_fn, lm_head=w, impl=impl, block_v=256)
-    if shards is None:
-        backend = SpecVerifyBackend(fused=True, **kw)
-    else:
-        backend = ShardedSpecVerifyBackend(shards=shards, **kw)
+    backend = SpecVerifyBackend(
+        kv_pool=pool, query_fn=query_fn, lm_head=w, impl=impl, block_v=256,
+        mesh=None if shards is None else host_mesh(shards),
+    )
     return backend, pool, w, V
 
 
@@ -345,6 +345,21 @@ def session_logits(case: RaggedCase, *, impl: str = "ref", block_v: int = 256):
         )
         out.append(np.asarray(lg)[0])
     return out
+
+
+def sharded_round(case: RaggedCase, shards: int, *, block_v: int = 256):
+    """The ragged round through the sharded launch over ``shards`` devices,
+    packed and unpacked as the served backend does."""
+    from repro.sharding import host_mesh, spec_verify_sharded
+
+    ks, *packed = ragged_launch_args(
+        case.tok_seq, case.tables_seq, case.base, pad_page_id=case.sentinel_page
+    )
+    out = spec_verify_sharded(
+        case.q, case.k_pages, case.v_pages, case.w, *packed,
+        mesh=host_mesh(shards), block_v=block_v, quant=case.quant,
+    )
+    return ragged_results(ks, *out)
 
 
 def session_fused(case: RaggedCase, *, impl: str = "ref", block_v: int = 256):
@@ -452,12 +467,6 @@ def assert_paths_agree(
             # Chain topology: the accepted root->leaf path is node 0..na-1.
             assert list(path_t) == list(range(int(na_t))), f"tree path session {s}"
     for n in shards:
-        from repro.sharding.spec_verify import spec_verify_sharded_batched
-
-        sharded = spec_verify_sharded_batched(
-            case.q, case.tok_seq, case.tables_seq, case.base,
-            case.k_pages, case.v_pages, case.w,
-            shards=n, block_v=block_v, pad_page_id=case.sentinel_page, quant=case.quant,
-        )
+        sharded = sharded_round(case, n, block_v=block_v)
         assert_ragged_match(sharded, pivot, exact_logp=True, label=f"sharded@{n} vs fused-batched")
     return pivot

@@ -5,8 +5,9 @@ The tentpole contract: the tensor-parallel spec-verify launch
 ``assert_array_equal``-exact vs the unsharded one-launch entry for every
 shard count — fp32 and int8 pages, GQA head splits that don't divide
 evenly, non-pow2 vocabularies, ragged batches — and the dispatcher-facing
-backend (``ShardedSpecVerifyBackend``) must be indistinguishable from the
-unsharded fused backend through rollback/evict/CoW-fork traffic.
+backend over a mesh (``SpecVerifyBackend(mesh=...)``) must be
+indistinguishable from the unsharded backend through rollback/evict/CoW-fork
+traffic.
 
 All random cases come from the shared strategy module (``strategies.py``);
 ``assert_paths_agree`` is the cross-path differential harness.
@@ -22,19 +23,14 @@ from strategies import (
     assert_paths_agree,
     assert_ragged_match,
     assert_triples_match,
-    composed_logits,
+    composed_verify,
     make_ragged_case,
     make_rect_case,
     ragged_geometries,
+    sharded_round,
 )
 
-from repro.sharding import (
-    host_mesh,
-    plan_shards,
-    sharded_target_logits,
-    spec_verify_sharded,
-    spec_verify_sharded_batched,
-)
+from repro.sharding import host_mesh, plan_shards, spec_verify_sharded
 
 requires_mesh = pytest.mark.skipif(
     jax.device_count() < 4,
@@ -71,13 +67,15 @@ def test_plan_shards_rejects_bad_gqa():
 @requires_mesh
 @pytest.mark.parametrize("shards", [1, 2, 4])
 def test_sharded_logits_bitexact_vs_composition(shards):
-    """Sharded logits == jitted attention + blocked LM head, per logit."""
+    """The sharded launch == jitted attention + blocked LM head + verify scan,
+    bit for bit: every logit it scans is the composition's."""
     B, K, H, Hkv, hd, bs, G, P, V = 2, 3, 4, 2, 8, 4, 4, 16, 384
     q, kp, vp, w, tables, lengths, tokens, nd = make_rect_case(B, K, H, Hkv, hd, bs, G, P, V)
-    mesh = host_mesh(shards)
-    got = sharded_target_logits(q, kp, vp, w, tables, lengths, mesh=mesh, block_v=128)
-    want = composed_logits(q, kp, vp, w, tables, lengths, impl="ref", block_v=128)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    got = spec_verify_sharded(
+        q, kp, vp, w, tables, lengths, tokens, nd, mesh=host_mesh(shards), block_v=128
+    )
+    want = composed_verify(q, kp, vp, w, tables, lengths, tokens, nd, impl="ref", block_v=128)
+    assert_triples_match(got, want, ks=np.asarray(nd))
 
 
 @requires_mesh
@@ -139,11 +137,7 @@ def test_sharded_int8_planes_travel_with_kv():
         impl="ref", block_v=256, pad_page_id=case.sentinel_page, quant=case.quant,
     )
     for n in (2, 4):
-        got = spec_verify_sharded_batched(
-            case.q, case.tok_seq, case.tables_seq, case.base,
-            case.k_pages, case.v_pages, case.w,
-            shards=n, block_v=256, pad_page_id=case.sentinel_page, quant=case.quant,
-        )
+        got = sharded_round(case, n, block_v=256)
         assert_ragged_match(got, pivot, exact_logp=True, label=f"int8 sharded@{n}")
 
 
@@ -182,28 +176,25 @@ def test_backend_matches_unsharded(shards, quantize):
     assert sh.verify_batch(reqs) == ref.verify_batch(reqs)
 
 
-@requires_mesh
-def test_backend_rejects_unfused():
-    from repro.runtime import ShardedSpecVerifyBackend
-
-    with pytest.raises(ValueError, match="fused"):
-        ShardedSpecVerifyBackend(shards=2, fused=False, lm_head=np.ones((4, 8), np.float32))
-
-
 @pytest.mark.parametrize("impl", ["pallas", "interpret"])
 def test_backend_refuses_impl_it_would_ignore(impl):
-    """The sharded launch is jax.numpy: a Pallas impl is refused, not ignored."""
-    from repro.runtime import ShardedSpecVerifyBackend
+    """The sharded launch is jax.numpy: over a mesh a Pallas impl is refused,
+    not ignored; without one the same backend takes it."""
+    from strategies import fused_backend
 
     with pytest.raises(ValueError, match="impl must be 'ref'"):
-        ShardedSpecVerifyBackend(shards=1, lm_head=np.ones((4, 8), np.float32), impl=impl)
+        fused_backend(impl=impl, shards=1)
+    backend, _, _, _ = fused_backend(impl=impl)
+    assert backend.mesh is None and backend.impl == impl
 
 
-def test_spec_backend_refuses_unknown_impl():
-    from repro.runtime import SpecVerifyBackend
+@pytest.mark.parametrize("cls", ["SpecVerifyBackend", "LogitsVerifyBackend"])
+def test_spec_backend_refuses_unknown_impl(cls):
+    import repro.runtime as runtime
 
+    args = (lambda s, t: None,) if cls == "LogitsVerifyBackend" else ()
     with pytest.raises(ValueError, match="impl must be one of"):
-        SpecVerifyBackend(lambda s, t: None, impl="tpu")
+        getattr(runtime, cls)(*args, impl="tpu")
 
 
 def _launcher():
@@ -220,9 +211,9 @@ def _launcher():
 @pytest.mark.parametrize("shards", [1, 2])
 def test_launcher_spec_backend_takes_registry_geometry(monkeypatch, shards):
     """``serve.py --backend spec`` sizes pool and LM head from ``--arch``, runs
-    the fused backend with the named impl at one shard, the sharded one above."""
+    the backend with the named impl at one shard, over a mesh of that many
+    devices above."""
     from repro import configs
-    from repro.runtime import ShardedSpecVerifyBackend
 
     real = configs.get_config
     monkeypatch.setattr(configs, "get_config", lambda name: real(name, reduced=True))
@@ -235,8 +226,10 @@ def test_launcher_spec_backend_takes_registry_geometry(monkeypatch, shards):
         cfg.n_layers, cfg.n_kv_heads, cfg.head_dim, serve.PAGE_SIZE, serve.KV_BLOCKS,
     )
     assert backend.lm_head.shape == (cfg.n_heads * cfg.head_dim, cfg.vocab_size)
-    assert backend.fused and backend.impl == "ref" and backend.block_v == 512
-    assert isinstance(backend, ShardedSpecVerifyBackend) == (shards > 1)
+    assert backend.impl == "ref" and backend.block_v == 512
+    assert (backend.mesh is not None) == (shards > 1)
+    if shards > 1:
+        assert backend.mesh.devices.size == shards
     if shards > 1:  # the default --impl pallas is refused, not silently swapped
         with pytest.raises(ValueError, match="impl must be 'ref'"):
             serve._make_backend(serve.build_parser().parse_args(argv))
@@ -337,7 +330,7 @@ def test_serve_round_trip_stream_invariant_under_shards():
     committed token stream is identical at 1, 2, and 4 shards (the router
     and dispatcher cannot observe the shard count)."""
     from repro.models.paged_kv import PagedKVPool
-    from repro.runtime import ShardedSpecVerifyBackend
+    from repro.runtime import SpecVerifyBackend
     from repro.runtime.client import EdgeClient, EdgeConfig
     from repro.runtime.server import CloudVerifier
     from repro.runtime.simclock import VirtualClock
@@ -354,8 +347,9 @@ def test_serve_round_trip_stream_invariant_under_shards():
     def once(shards):
         clock = VirtualClock()
         pool = PagedKVPool(num_blocks=256, block_size=8, n_layers=1, n_kv_heads=H, head_dim=hd)
-        backend = ShardedSpecVerifyBackend(
-            shards=shards, kv_pool=pool, query_fn=query_fn, lm_head=w, impl="ref", block_v=512
+        backend = SpecVerifyBackend(
+            kv_pool=pool, query_fn=query_fn, lm_head=w, impl="ref", block_v=512,
+            mesh=host_mesh(shards),
         )
         server = CloudVerifier(backend, kv_pool=pool, clock=clock)
         up = Channel(ChannelConfig(alpha=0.02, beta=0.002), "up0", clock=clock)

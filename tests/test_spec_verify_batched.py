@@ -147,8 +147,8 @@ def test_batched_rejects_bad_inputs():
 
 
 def test_spec_verify_backend_no_cross_session_leakage():
-    """The server's kernel-backed backend: batched call == per-session calls."""
-    from repro.runtime import SpecVerifyBackend
+    """The logits-fed backend: batched call == per-session calls."""
+    from repro.runtime import LogitsVerifyBackend
 
     V = 512
 
@@ -156,7 +156,7 @@ def test_spec_verify_backend_no_cross_session_leakage():
         rng = np.random.default_rng(1000 + session)
         return rng.standard_normal((len(tokens) + 1, V)).astype(np.float32) * 2
 
-    backend = SpecVerifyBackend(logits_fn, impl="ref")
+    backend = LogitsVerifyBackend(logits_fn, impl="ref")
     reqs = [
         (0, [3, 99, 7], [0.9] * 3),
         (1, [5], [0.9]),
@@ -167,165 +167,13 @@ def test_spec_verify_backend_no_cross_session_leakage():
     assert batched == solo
 
 
-@pytest.mark.parametrize("impl", ["ref", "interpret"])
-def test_batched_paged_target_forward_parity(impl):
-    """``batched_logits_fn`` + block tables == precomputed per-session logits.
-
-    The paged dispatch hands the entry ONE padded batch (tokens, n_drafted,
-    pow2-bucketed block tables) and gets logits back from a single target
-    forward; results must match feeding the same logits per session.
-    """
-    ks = [3, 5, 1]
-    V = 512
-    logits_seq, tokens_seq = _ragged_requests(ks, V, seed=7)
-    tables_seq = [[4, 9], [2], [7, 1, 3]]  # ragged KV block tables
-    seen = {}
-
-    def batched_logits_fn(tokens, nd, tables):
-        # Padded shapes carry the same pow2 bucketing as the logits batch.
-        assert tokens.shape == (4, 8) and nd.shape == (4,)
-        assert tables.shape == (4, 4) and tables.dtype == np.int32
-        np.testing.assert_array_equal(tables[0, :2], [4, 9])
-        np.testing.assert_array_equal(tables[2], [7, 1, 3, 0])  # pad id 0
-        np.testing.assert_array_equal(tables[3], 0)  # pad row
-        seen["called"] = True
-        out = np.zeros((tokens.shape[0], tokens.shape[1] + 1, V), np.float32)
-        for i, k in enumerate(ks):
-            out[i, : k + 1] = logits_seq[i]
-        return out
-
-    paged = spec_verify_batched(
-        None,
-        tokens_seq,
-        impl=impl,
-        block_v=256,
-        block_tables_seq=tables_seq,
-        batched_logits_fn=batched_logits_fn,
-    )
-    assert seen.get("called")
-    plain = spec_verify_batched(logits_seq, tokens_seq, impl=impl, block_v=256)
-    for i in range(len(ks)):
-        assert paged[i][0] == plain[i][0] and paged[i][1] == plain[i][1]
-        np.testing.assert_allclose(paged[i][2], plain[i][2], atol=1e-4)
-    with pytest.raises(ValueError):
-        spec_verify_batched(logits_seq, tokens_seq, batched_logits_fn=batched_logits_fn, impl="ref")
-
-
-def test_spec_verify_backend_paged_batched_forward():
-    """SpecVerifyBackend with a kv_pool threads block tables into ONE
-    batched forward and matches the per-session logits path."""
-    from repro.models.paged_kv import PagedKVPool
-    from repro.runtime import SpecVerifyBackend
-
-    V = 256
-    rngs = {s: np.random.default_rng(500 + s) for s in range(3)}
-    cache = {}
-
-    def logits_for(session, n):
-        # Deterministic per (session, draft length): both paths agree.
-        key = (session, n)
-        if key not in cache:
-            cache[key] = rngs[session].standard_normal((n + 1, V)).astype(np.float32) * 2
-        return cache[key]
-
-    pool = PagedKVPool(num_blocks=16, block_size=4)
-    reqs = [(0, [3, 9, 7], [0.9] * 3), (1, [5], [0.9]), (2, [1, 2, 3, 4], [0.9] * 4)]
-    for s, toks, _ in reqs:
-        pool.create(s)
-        pool.append(s, 5 + s)  # distinct table sizes
-
-    def batched_logits_fn(tokens, nd, tables):
-        assert tables is not None and tables.shape[0] == tokens.shape[0]
-        out = np.zeros((tokens.shape[0], tokens.shape[1] + 1, V), np.float32)
-        for i, (s, toks, _) in enumerate(reqs):
-            out[i, : len(toks) + 1] = logits_for(s, len(toks))
-        return out
-
-    paged_backend = SpecVerifyBackend(
-        kv_pool=pool, batched_logits_fn=batched_logits_fn, impl="ref"
-    )
-    plain_backend = SpecVerifyBackend(lambda s, t: logits_for(s, len(t)), impl="ref")
-    assert paged_backend.verify_batch(reqs) == plain_backend.verify_batch(reqs)
-
-
-def test_tree_batched_paged_target_forward_parity():
-    """Tree entry: batched paged forward == precomputed per-session logits."""
-    from repro.kernels.spec_verify import spec_verify_tree_batched
-
-    V = 256
-    tokens_seq = [[3, 9, 7], [5, 1]]
-    parents_seq = [[-1, 0, 0], [-1, -1]]
-    logits_seq = [
-        np.asarray(jax.random.normal(jax.random.fold_in(KEY, 33 + i), (len(t) + 1, V)) * 3, np.float32)
-        for i, t in enumerate(tokens_seq)
-    ]
-    tables_seq = [[2, 8], [5]]
-
-    def batched_logits_fn(tokens, parents, nn, tables):
-        assert tokens.shape == parents.shape == (2, 4) and tables.shape == (2, 2)
-        assert parents[0, 3] == -1  # pad nodes carry -1
-        out = np.zeros((tokens.shape[0], tokens.shape[1] + 1, V), np.float32)
-        for i, t in enumerate(tokens_seq):
-            out[i, : len(t) + 1] = logits_seq[i]
-        return out
-
-    paged = spec_verify_tree_batched(
-        None, tokens_seq, parents_seq,
-        impl="ref", block_tables_seq=tables_seq, batched_logits_fn=batched_logits_fn,
-    )
-    plain = spec_verify_tree_batched(logits_seq, tokens_seq, parents_seq, impl="ref")
-    for p, q in zip(paged, plain):
-        assert p[0] == q[0] and p[1] == q[1] and p[2] == q[2]
-        np.testing.assert_allclose(p[3], q[3], atol=1e-4)
-
-
-def test_spec_verify_backend_paged_tree_forward():
-    """A paged-forward-only backend must serve tree requests through
-    batched_tree_logits_fn (and raise clearly when it lacks one)."""
-    from repro.models.paged_kv import PagedKVPool
-    from repro.runtime import SpecVerifyBackend
-
-    V = 128
-    tokens, parents = [7, 9, 3], [-1, 0, 0]
-    lg = np.asarray(jax.random.normal(jax.random.fold_in(KEY, 55), (4, V)) * 3, np.float32)
-
-    def batched_tree_logits_fn(toks, pars, nn, tables):
-        assert tables is not None
-        out = np.zeros((toks.shape[0], toks.shape[1] + 1, V), np.float32)
-        out[0, :4] = lg
-        return out
-
-    pool = PagedKVPool(num_blocks=8, block_size=4)
-    pool.create(0)
-    pool.append(0, 6)
-    backend = SpecVerifyBackend(
-        kv_pool=pool,
-        batched_logits_fn=lambda t, n, b: np.zeros((t.shape[0], t.shape[1] + 1, V), np.float32),
-        batched_tree_logits_fn=batched_tree_logits_fn,
-        impl="ref",
-    )
-    got = backend.verify_tree_batch([(0, tokens, [0.9] * 3, parents)])
-    from repro.kernels.spec_verify import spec_verify_tree_batched
-
-    (want,) = spec_verify_tree_batched([lg], [tokens], [parents], impl="ref")
-    assert got[0] == (int(want[0]), int(want[2]), list(want[1]))
-
-    chain_only = SpecVerifyBackend(
-        kv_pool=pool,
-        batched_logits_fn=lambda t, n, b: np.zeros((t.shape[0], t.shape[1] + 1, V), np.float32),
-        impl="ref",
-    )
-    with pytest.raises(ValueError, match="tree requests need"):
-        chain_only.verify_tree_batch([(0, tokens, [0.9] * 3, parents)])
-
-
 # Shared with the sharded differential suite (tests/strategies.py) so the
 # unsharded and sharded backends stay comparable request-for-request.
 _fused_backend = fused_backend
 
 
 def test_fused_backend_one_launch_matches_composition():
-    """fused=True backend == the unfused paged-attention + verify pipeline,
+    """The served backend == the unfused paged-attention + verify pipeline,
     with batched == per-session (no cross-session leakage through padding)."""
     from repro.kernels.decode_attention import paged_decode_attention
     from repro.kernels.spec_verify import fused_target_logits, spec_verify
@@ -472,7 +320,7 @@ def test_fused_backend_reused_session_id_refills_from_scratch():
         return x, x
 
     backend = SpecVerifyBackend(
-        fused=True, kv_pool=pool, kv_fn=kv_fn, lm_head=np.ones((H * hd, V), np.float32),
+        kv_pool=pool, kv_fn=kv_fn, lm_head=np.ones((H * hd, V), np.float32),
         query_fn=lambda s, t, k=None: np.zeros((len(t) + 1, H, hd), np.float32), impl="ref",
     )
     pool.create(7)
@@ -486,28 +334,69 @@ def test_fused_backend_reused_session_id_refills_from_scratch():
     assert calls == [(7, 0, 8), (7, 0, 8)]
 
 
-def test_unfused_paged_backend_pads_tables_with_sentinel():
-    """Satellite regression: the batched paged forward pads ragged tables
-    with the pool's sentinel page, never page 0 (a live page)."""
+@pytest.mark.parametrize("missing", ["tensor_pool", "query_fn", "lm_head"])
+def test_spec_backend_refuses_incomplete_inputs(missing):
+    """The served backend needs a tensor-mode pool, ``query_fn`` and
+    ``lm_head``; without any one of them it refuses at construction."""
     from repro.models.paged_kv import PagedKVPool
     from repro.runtime import SpecVerifyBackend
 
-    V = 128
-    pool = PagedKVPool(num_blocks=8, block_size=4)
-    seen = {}
+    H, hd, V = 2, 8, 256
+    kw = dict(
+        kv_pool=PagedKVPool(num_blocks=8, block_size=4, n_layers=1, n_kv_heads=H, head_dim=hd),
+        query_fn=lambda s, t, k=None: jnp.zeros((len(t) + 1, H, hd)),
+        lm_head=np.ones((H * hd, V), np.float32),
+        impl="ref",
+    )
+    SpecVerifyBackend(**kw)  # complete: accepted
+    if missing == "tensor_pool":
+        kw["kv_pool"] = PagedKVPool(num_blocks=8, block_size=4)  # metadata only
+    else:
+        del kw[missing]
+    with pytest.raises(ValueError, match="tensor-mode kv_pool" if missing == "tensor_pool" else missing):
+        SpecVerifyBackend(**kw)
 
-    def batched_logits_fn(tokens, nd, tables):
-        seen["tables"] = np.array(tables)
-        return np.zeros((tokens.shape[0], tokens.shape[1] + 1, V), np.float32)
 
-    backend = SpecVerifyBackend(kv_pool=pool, batched_logits_fn=batched_logits_fn, impl="ref")
+def test_spec_backend_refuses_tree_rounds():
+    """The served backend has no fused tree launch: a tree round raises
+    ``ValueError`` (the dispatcher's error), an empty one is a no-op."""
+    backend, pool, _, _ = _fused_backend()
     pool.create(0)
-    pool.append(0, 6)  # pages [0, 1]
-    backend.verify_batch([(0, [1, 2, 3], [0.9] * 3)])
-    tables = seen["tables"]
-    assert tables.shape[1] >= 2
-    np.testing.assert_array_equal(tables[0, 2:], pool.sentinel_page)
-    assert (tables[1:] == pool.sentinel_page).all()  # pad rows too
+    pool.append(0, 6)
+    with pytest.raises(ValueError, match="tree requests need"):
+        backend.verify_tree_batch([(0, [7, 9, 3], [0.9] * 3, [-1, 0, 0])])
+    assert backend.verify_tree_batch([]) == []
+    assert pool.filled(0) == 0  # refused before any KV was made
+
+
+def test_fused_backend_pads_tables_with_sentinel(monkeypatch):
+    """The served launch gets a round's ragged tables padded with the pool's
+    sentinel page, never page 0 (a live page of another session), in pad
+    entries and pad rows alike."""
+    import repro.kernels.spec_verify.ops as ops
+
+    seen = []
+    real = ops.spec_verify_fused
+
+    def spy(q, k_pages, v_pages, w, tables, *args, **kwargs):
+        seen.append(np.asarray(tables))
+        return real(q, k_pages, v_pages, w, tables, *args, **kwargs)
+
+    monkeypatch.setattr(ops, "spec_verify_fused", spy)
+    backend, pool, _, _ = _fused_backend()
+    reqs = [(0, [3, 9], [0.9] * 2), (1, [5], [0.9]), (2, [1, 2, 3], [0.9] * 3)]
+    for (s, toks, _), length in zip(reqs, (6, 15, 3)):
+        pool.create(s)
+        pool.append(s, length)
+    assert 0 in pool.table(0)  # page 0 is live
+    backend.fused_verify(reqs)
+    (tables,) = seen
+    assert tables.shape == (4, 4)  # Bp = 4 rows, Gp = 4 pages
+    for i, (s, _, _) in enumerate(reqs):
+        own = list(pool.table(s))
+        np.testing.assert_array_equal(tables[i, : len(own)], own)
+        assert (tables[i, len(own):] == pool.sentinel_page).all()
+    assert (tables[3] == pool.sentinel_page).all()  # the pad row
 
 
 def test_fused_backend_full_serve_round_trip():
@@ -534,7 +423,7 @@ def test_fused_backend_full_serve_round_trip():
         clock = VirtualClock()
         pool = PagedKVPool(num_blocks=256, block_size=8, n_layers=1, n_kv_heads=H,
                            head_dim=hd, quantize=quantize)
-        backend = SpecVerifyBackend(fused=True, kv_pool=pool, query_fn=query_fn,
+        backend = SpecVerifyBackend(kv_pool=pool, query_fn=query_fn,
                                     lm_head=w, impl="ref", block_v=512)
         server = CloudVerifier(backend, kv_pool=pool, clock=clock)
         up = Channel(ChannelConfig(alpha=0.02, beta=0.002), "up0", clock=clock)
@@ -582,7 +471,7 @@ def test_fused_serve_shared_prefix_materialized_once_and_stays_shared():
     clock = VirtualClock()
     pool = PagedKVPool(num_blocks=256, block_size=8, n_layers=1, n_kv_heads=H, head_dim=hd)
     backend = SpecVerifyBackend(
-        fused=True, kv_pool=pool, query_fn=query_fn, lm_head=w, impl="ref", block_v=512
+        kv_pool=pool, query_fn=query_fn, lm_head=w, impl="ref", block_v=512
     )
     server = CloudVerifier(backend, kv_pool=pool, kv_shared_prefix=32, clock=clock)
     assert pool.filled(CloudVerifier.KV_PREFIX_SESSION) == 32  # filled at init
@@ -632,7 +521,7 @@ def test_verifier_counts_page_writes_from_admission_through_verify():
     clock = VirtualClock()
     pool = PagedKVPool(num_blocks=64, block_size=8, n_layers=1, n_kv_heads=H, head_dim=hd)
     backend = SpecVerifyBackend(
-        fused=True, kv_pool=pool, query_fn=query_fn, lm_head=w, impl="ref", block_v=256
+        kv_pool=pool, query_fn=query_fn, lm_head=w, impl="ref", block_v=256
     )
     server = CloudVerifier(backend, kv_pool=pool, kv_shared_prefix=28, clock=clock)
     before = pool.stats["page_writes"]  # the prefix, materialized at init
@@ -688,7 +577,7 @@ def test_verifier_traces_kv_synthesis_and_counts_launched_rows(monkeypatch):
     tracer = Tracer()
     pool = PagedKVPool(num_blocks=64, block_size=8, n_layers=1, n_kv_heads=H, head_dim=hd)
     backend = SpecVerifyBackend(
-        fused=True, kv_pool=pool, query_fn=query_fn, lm_head=w, impl="ref", block_v=256
+        kv_pool=pool, query_fn=query_fn, lm_head=w, impl="ref", block_v=256
     )
     server = CloudVerifier(
         backend, kv_pool=pool, kv_shared_prefix=16, clock=clock, tracer=tracer,
@@ -755,7 +644,7 @@ def _draw_backend(widths, impl="ref", seed=5):
         return jax.random.normal(draw, (len(tokens) + 1, H, hd))
 
     backend = SpecVerifyBackend(
-        fused=True, kv_pool=pool, query_fn=query_fn, lm_head=w, impl=impl, block_v=256
+        kv_pool=pool, query_fn=query_fn, lm_head=w, impl=impl, block_v=256
     )
     return backend, pool
 

@@ -193,7 +193,7 @@ def test_query_draw_and_its_launch_compile_for_v5e(one_chip, widths):
         return jax.random.normal(draw, (len(tokens) + 1, h, HD))
 
     pool = PagedKVPool(num_blocks=4, block_size=BS, n_layers=1, n_kv_heads=hkv, head_dim=HD)
-    backend = SpecVerifyBackend(fused=True, kv_pool=pool, query_fn=query_fn,
+    backend = SpecVerifyBackend(kv_pool=pool, query_fn=query_fn,
                                 lm_head=np.zeros((h * HD, 8), np.float32), impl="pallas")
     sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
     draw, consts = backend._draw(WINDOW)

@@ -433,7 +433,7 @@ def test_cloud_verifier_dispatches_mixed_chain_and_tree():
 
 def test_spec_verify_backend_tree_batch_matches_solo():
     """Kernel-backed tree verify: batched call == per-session calls."""
-    from repro.runtime import SpecVerifyBackend
+    from repro.runtime import LogitsVerifyBackend
 
     V = 256
 
@@ -441,7 +441,7 @@ def test_spec_verify_backend_tree_batch_matches_solo():
         rng = np.random.default_rng(500 + session)
         return (rng.standard_normal((len(tokens) + 1, V)) * 2).astype(np.float32)
 
-    backend = SpecVerifyBackend(logits_fn, impl="ref")
+    backend = LogitsVerifyBackend(logits_fn, impl="ref")
     reqs = [
         (0, [3, 9, 7], [0.9] * 3, [-1, 0, 1]),
         (1, [5, 6], [0.9] * 2, [-1, -1]),
